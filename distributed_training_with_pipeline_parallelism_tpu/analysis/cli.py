@@ -337,10 +337,6 @@ def run_jaxpr_audits() -> Dict[str, Any]:
 
     from ..models.transformer import layer_init, mlp_block
     from .jaxpr_audit import collective_matmul_ppermutes
-    try:
-        from jax.shard_map import shard_map as _shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as _shard_map
     T = 4
     tp_mesh = _Mesh(np.array(jax.devices()[:T]), ("model",))
     tp_cfg = _dc.replace(cfg, arch="gpt2", tp_overlap="ring")
@@ -349,10 +345,10 @@ def run_jaxpr_audits() -> Dict[str, Any]:
                  "lin2": {"w": _P("model", None), "b": _P(None)}}
     specs = {k: mlp_specs.get(k, jax.tree.map(lambda _: _P(), lp[k]))
              for k in lp}
-    ring_fwd = _shard_map(
+    ring_fwd = jax.shard_map(
         lambda p, x: mlp_block(tp_cfg, p, x, tp_axis="model", tp_size=T),
         mesh=tp_mesh, in_specs=(specs, _P()), out_specs=_P(),
-        check_rep=False)
+        check_vma=False)
     # gpt2 ring MLP: all_gather_matmul + matmul_reduce_scatter +
     # seq_all_gather = 3 ring collectives
     expected_tp = collective_matmul_ppermutes(T, n_gathers=2, n_scatters=1)

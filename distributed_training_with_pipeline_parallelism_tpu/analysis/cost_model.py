@@ -124,29 +124,28 @@ def hardware_spec_for(device_kind: str) -> HardwareSpec:
     """Map a ``device_kind``/platform string to a preset.
 
     Substring match over the TPU presets (same rule as
-    ``bench.chip_peak_flops``); anything CPU-ish gets the labelled
-    :data:`CPU_PROXY`; an unrecognized accelerator defaults to the v5e
-    preset (the fleet default, matching bench's fallback)."""
-    kind = (device_kind or "").lower()
+    ``bench.chip_peak_flops``); a caller that asks for the CPU gets the
+    labelled :data:`CPU_PROXY`. A device that is not in the table is an
+    error, not a default: a roofline against another chip's peaks is not a
+    prediction."""
+    kind = device_kind.lower()
     for key, spec in TPU_PRESETS.items():
         if key in kind:
             return spec
-    if "cpu" in kind or kind == "":
+    if "cpu" in kind:
         return CPU_PROXY
-    return TPU_PRESETS["v5e"]
+    raise ValueError(f"no hardware preset for device kind {device_kind!r}: "
+                     f"add it to TPU_PRESETS with its source")
 
 
 def detect_hardware() -> HardwareSpec:
-    """Spec for the first visible device; :data:`CPU_PROXY` when the
-    backend is CPU or unavailable."""
-    try:
-        import jax
-        dev = jax.devices()[0]
-        if dev.platform != "tpu":
-            return CPU_PROXY
-        return hardware_spec_for(getattr(dev, "device_kind", "tpu"))
-    except Exception:
-        return CPU_PROXY
+    """Spec for the first visible device: :data:`CPU_PROXY` on the CPU
+    backend (the test suite, the simulated mesh), the preset of its
+    ``device_kind`` otherwise — an unknown kind raises."""
+    import jax
+    dev = jax.devices()[0]
+    return hardware_spec_for(
+        "cpu" if dev.platform == "cpu" else dev.device_kind)
 
 
 def dtype_bytes(dtype: str) -> int:

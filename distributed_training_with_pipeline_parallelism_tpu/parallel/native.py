@@ -4,8 +4,9 @@
 Python compiler in :mod:`.schedules` (tables are asserted bit-identical in
 tests); the Python path is the executable specification, this is the fast
 production path. The shared library is built on first use with the repo's
-``csrc/Makefile`` (plain g++, no external deps); if no compiler is available
-the caller should fall back to the Python compiler.
+``csrc/Makefile`` (plain g++, no external deps) from the sources in the
+checkout; where that build cannot run, nothing is loaded and the caller
+falls back to the Python compiler.
 """
 
 from __future__ import annotations
@@ -25,16 +26,17 @@ class NativeLib:
     """Lazy, cached loader for one csrc/ shared library.
 
     First use invokes make (mtime-incremental: a no-op when the .so is
-    fresh, a rebuild when the source changed — a stale .so would silently
-    misbehave). If no build toolchain is available but a prebuilt and
-    source-fresh .so exists, it is loaded anyway. ``configure`` receives the
-    CDLL to declare restype/argtypes. Load failure is cached; ``get()``
-    then returns None so callers can fall back to their Python twin.
+    fresh, a rebuild when the source changed) and loads what make vouches
+    for. Only that: a .so lying in ``csrc/`` (untracked — ``.gitignore``)
+    that make could not check against the sources is never loaded, so what
+    runs is always built from the ``.cpp`` files of this checkout.
+    ``configure`` receives the CDLL to declare restype/argtypes. Build or
+    load failure is cached; ``get()`` then returns None so callers can fall
+    back to their Python twin.
     """
 
-    def __init__(self, so_name: str, src_name: str, configure):
+    def __init__(self, so_name: str, configure):
         self._so = os.path.abspath(os.path.join(_CSRC, so_name))
-        self._src = os.path.join(_CSRC, src_name)
         self._configure = configure
         self._lock = threading.Lock()
         self._lib: Optional[ctypes.CDLL] = None
@@ -45,21 +47,13 @@ class NativeLib:
             if self._lib is not None or self._failed:
                 return self._lib
             try:
-                try:
-                    subprocess.run(["make", "-C", os.path.abspath(_CSRC)],
-                                   check=True, capture_output=True)
-                except (OSError, subprocess.CalledProcessError):
-                    if not os.path.exists(self._so):
-                        raise
-                    if (os.path.exists(self._src)
-                            and os.path.getmtime(self._so)
-                            < os.path.getmtime(self._src)):
-                        raise  # stale .so relative to source; don't trust it
+                subprocess.run(["make", "-C", os.path.abspath(_CSRC)],
+                               check=True, capture_output=True)
                 lib = ctypes.CDLL(self._so)
                 self._configure(lib)
                 self._lib = lib
-            except Exception:
-                self._failed = True
+            except (OSError, subprocess.CalledProcessError, AttributeError):
+                self._failed = True  # no toolchain, failed build, bad symbols
             return self._lib
 
 
@@ -73,8 +67,7 @@ def _configure_schedule_engine(lib: ctypes.CDLL) -> None:
     ]
 
 
-_engine = NativeLib("libschedule_engine.so", "schedule_engine.cpp",
-                    _configure_schedule_engine)
+_engine = NativeLib("libschedule_engine.so", _configure_schedule_engine)
 
 
 def _load() -> Optional[ctypes.CDLL]:

@@ -66,13 +66,9 @@ from .schedules import (BANK_BEFORE_B, BANK_BEFORE_F, BANK_BEFORE_W,
 
 
 def _shard_map(fn, mesh, in_specs, out_specs):
-    try:  # jax >= 0.6 exposes shard_map at top level (check_vma kwarg)
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    except AttributeError:  # pragma: no cover - older jax
-        from jax.experimental.shard_map import shard_map as esm
-        return esm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
+
 
 Pytree = Any
 
@@ -148,8 +144,8 @@ def _merge_fsdp_into_stacked(base: Pytree, fsdp_dims: Pytree) -> Pytree:
 
 
 def _compile(name: str, D: int, V: int, M: int) -> CompiledSchedule:
-    """Compile via the native C++ engine when available (bit-identical to the
-    Python compiler — see tests/test_native_engine.py), else in Python.
+    """Compile via the native C++ engine where it could be built (bit-identical
+    to the Python compiler — see tests/test_native_engine.py), else in Python.
     Custom registered schedules always compile in Python (their order
     functions are Python). With ``DTPP_VERIFY_TABLES`` set, the compiled
     table additionally passes the static hazard verifier
@@ -163,16 +159,9 @@ def _compile(name: str, D: int, V: int, M: int) -> CompiledSchedule:
         cs = compile_schedule(name, D, V, M)
         maybe_verify_schedule(cs)
         return cs
-    cs = None
     if native.native_available():
-        from .schedules import ScheduleError
-        try:
-            cs = native.compile_schedule_native(name, D, V, M)
-        except ScheduleError:
-            raise
-        except Exception:
-            pass  # fall through to the Python reference implementation
-    if cs is None:
+        cs = native.compile_schedule_native(name, D, V, M)
+    else:  # no C++ toolchain here: the Python reference implementation
         cs = compile_schedule(name, D, V, M)
     # Artifact-backed names always take the is_custom path above (their
     # order fns are Python), but re-check the pin here too so a native
@@ -416,6 +405,99 @@ def _resolve_fsdp_dims(cfg: ModelConfig, moe, n_data: int, T: int,
     if moe is not None:
         return _moe_fsdp_shard_dims(cfg, moe, n_data, T, n_ep)
     return _fsdp_shard_dims(cfg, n_data, T)
+
+
+def _stage_param_specs(cfg: ModelConfig, moe, T: int, n_ep: int, fsdp: bool,
+                       fsdp_dims, tp_vocab_parallel: bool):
+    """``(layer_spec, head_spec)`` — where the executors' shard_maps take the
+    parameters in and hand the gradients out, so also where parameters and
+    optimizer moments REST between steps (:func:`param_shardings`). Shared
+    by the training executor and the forward-only eval program.
+
+    Layers ride the stacked [D, V, lps, ...] layout: 'pipe' on the device
+    dim; Megatron 'model' placement (heads and FFN hidden column-split,
+    o/down row-split) and the per-leaf fsdp 'data' dims merged in — pp x
+    tp, pp x fsdp and pp x fsdp x tp all come from the one helper. The
+    embedding is replicated (``P()`` at every call site)."""
+    if moe is not None:
+        layer_spec = _moe_layer_specs(cfg, moe, T, n_ep)
+        if fsdp_dims is not None:
+            layer_spec = _merge_fsdp_into_stacked(layer_spec, fsdp_dims)
+    elif T > 1 or fsdp:
+        layer_spec = _dense_layer_specs(cfg, T, fsdp_dims)
+    else:
+        layer_spec = P(PIPE_AXIS)
+    if tp_vocab_parallel and not cfg.tie_embeddings:
+        # vocab-sharded head: out.w [dim, V] column-split, bias (ref arch)
+        # split with it; the norm stays replicated
+        out_spec = ({"w": P(None, MODEL_AXIS), "b": P(MODEL_AXIS)}
+                    if cfg.arch == "ref_decoder"
+                    else {"w": P(None, MODEL_AXIS)})
+        head_spec = {"norm": P(), "out": out_spec}
+    else:
+        # tied + vocab-parallel: the head is only the norm; the vocab split
+        # is a row-slice of the replicated embedding inside the objective
+        head_spec = P()
+    return layer_spec, head_spec
+
+
+def _batch_spec(n_seq: int, n_ep: int) -> P:
+    if n_seq > 1:
+        # with an expert axis too (MoE x seq, round 5) the batch shards
+        # over data x expert while the sequence shards over seq
+        lead = (DATA_AXIS, EXPERT_AXIS) if n_ep > 1 else DATA_AXIS
+        return P(lead, SEQ_AXIS)
+    if n_ep > 1:
+        return P((DATA_AXIS, EXPERT_AXIS))  # batch over data x expert
+    return P(DATA_AXIS)
+
+
+def model_init(cfg: ModelConfig, moe=None) -> Callable[[jax.Array], Pytree]:
+    """``key -> params``: the init of the full-model pytree the executors
+    take — ``moe_lm_init`` with a MoEConfig, else ``transformer_init``."""
+    if moe is not None:
+        from ..models.moe import moe_lm_init
+        return lambda key: moe_lm_init(key, cfg, moe)
+    from ..models.transformer import transformer_init
+    return lambda key: transformer_init(key, cfg)
+
+
+def param_shardings(cfg: ModelConfig, mesh: Mesh, moe=None,
+                    fsdp: bool = False,
+                    tp_vocab_parallel: bool = False) -> Pytree:
+    """Where the full-model pytree rests on ``mesh`` between steps: one
+    ``NamedSharding`` per leaf of ``transformer_init`` (``moe_lm_init`` with
+    ``moe``), read off the executor's own in/out specs
+    (:func:`_stage_param_specs`) with the stacked [D, V, lps, ...] layer
+    dims folded back onto the ``[L, ...]`` layer axis — layer leaves
+    'pipe'-sharded there (plus their 'model'/'data'/'expert' dims),
+    embedding replicated, head as the executor takes it. Initialise INTO
+    this layout (``jax.jit(init, out_shardings=...)``) and each device only
+    ever holds its stages' share; gradients leave the shard_map in the same
+    layout, so the elementwise optimizer update keeps it. With
+    ``n_virtual > 1`` the wrap placement's strided stage->device map makes
+    the per-step stacking a (sharded) permute; with V=1 it is movement-free."""
+    from jax.sharding import NamedSharding
+    n_data = mesh.shape.get(DATA_AXIS, 1)
+    T = mesh.shape.get(MODEL_AXIS, 1)
+    n_ep = mesh.shape.get(EXPERT_AXIS, 1)
+    layer_spec, head_spec = _stage_param_specs(
+        cfg, moe, T, n_ep, fsdp,
+        _resolve_fsdp_dims(cfg, moe, n_data, T, n_ep, fsdp),
+        tp_vocab_parallel)
+    shapes = jax.eval_shape(model_init(cfg, moe), jax.random.key(0))
+
+    def is_spec(x):
+        return isinstance(x, P)
+
+    specs = {"embed": P(), "head": head_spec,
+             "layers": jax.tree.map(  # [D, V, lps, w...] -> [L, w...]
+                 lambda sp: P(*(tuple(sp)[:1] + tuple(sp)[3:])), layer_spec,
+                 is_leaf=is_spec)}
+    # each spec may stand for a whole subtree (P() for the embedding)
+    return jax.tree.map(
+        lambda sp, sub: jax.tree.map(lambda _: NamedSharding(mesh, sp), sub),
+        specs, shapes, is_leaf=is_spec)
 
 
 def _check_moe_mesh(cfg: ModelConfig, moe, T: int, n_seq: int,
@@ -1743,38 +1825,9 @@ def make_pipeline_grad_fn(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig,
             return loss, g_layers, g_embed, g_head, sq_mb
         return loss, g_layers, g_embed, g_head
 
-    if moe is not None:
-        layer_spec = _moe_layer_specs(cfg, moe, T, n_ep)
-        if fsdp_dims is not None:
-            layer_spec = _merge_fsdp_into_stacked(layer_spec, fsdp_dims)
-    elif T > 1 or fsdp:
-        # Per-leaf placement for the stacked layer pytree: Megatron 'model'
-        # placement (heads and FFN hidden column-split, o/down row-split)
-        # merged with the per-leaf fsdp 'data' dims — pp x tp, pp x fsdp,
-        # and pp x fsdp x tp all come from the one helper
-        layer_spec = _dense_layer_specs(cfg, T, fsdp_dims)
-    else:
-        layer_spec = P(PIPE_AXIS)
-    if n_seq > 1:
-        # with an expert axis too (MoE x seq, round 5) the batch shards
-        # over data x expert while the sequence shards over seq
-        lead = (DATA_AXIS, EXPERT_AXIS) if n_ep > 1 else DATA_AXIS
-        batch_spec = P(lead, SEQ_AXIS)
-    elif n_ep > 1:
-        batch_spec = P((DATA_AXIS, EXPERT_AXIS))  # batch over data x expert
-    else:
-        batch_spec = P(DATA_AXIS)
-    if tp_vocab_parallel and not cfg.tie_embeddings:
-        # vocab-sharded head: out.w [dim, V] column-split, bias (ref arch)
-        # split with it; the norm stays replicated
-        out_spec = ({"w": P(None, MODEL_AXIS), "b": P(MODEL_AXIS)}
-                    if cfg.arch == "ref_decoder"
-                    else {"w": P(None, MODEL_AXIS)})
-        head_spec = {"norm": P(), "out": out_spec}
-    else:
-        # tied + vocab-parallel: the head is only the norm; the vocab split
-        # is a row-slice of the replicated embedding inside the objective
-        head_spec = P()
+    layer_spec, head_spec = _stage_param_specs(cfg, moe, T, n_ep, fsdp,
+                                               fsdp_dims, tp_vocab_parallel)
+    batch_spec = _batch_spec(n_seq, n_ep)
     in_specs = (layer_spec, P(), head_spec, batch_spec, batch_spec)
     if use_dropout:
         in_specs = in_specs + (P(),)  # step rng: replicated raw key data
@@ -1900,63 +1953,25 @@ def aot_memory_analysis(step, *args) -> Dict[str, Any]:
             "alias_bytes": int(ma.alias_size_in_bytes),
             "generated_code_bytes": int(ma.generated_code_size_in_bytes),
         }
-    except Exception as e:  # AOT paths vary by backend/jax version
+    except Exception as e:  # a backend may expose no AOT memory analysis
         return {"error": str(e)}
 
 
 def fsdp_shard_params(params: Pytree, cfg: ModelConfig, mesh: Mesh,
                       moe=None) -> Pytree:
-    """Place a full-model pytree for pp x fsdp: layer leaves sharded over
-    'pipe' on the layer dim (each pipe device keeps only its stages) AND
-    over 'data' on the first weight dim for matrix leaves — the placement
-    the executor's grads come back in, so params, grads, and optimizer
-    state all rest at ~1/(D * n_data) of the model's layer weights per
-    device. Embed/head stay replicated (O(vocab*dim), a few percent of a
-    Llama-class model). With n_virtual > 1 the wrap placement's strided
-    stage->device map makes the per-step stacking a (small, sharded)
-    permute; with V=1 stacking is movement-free."""
-    from jax.sharding import NamedSharding
-    n_data = mesh.shape.get(DATA_AXIS, 1)
-    if n_data <= 1:
+    """Place a full-model pytree for pp x fsdp: :func:`param_shardings`'
+    resting layout with ``fsdp=True`` — layer leaves sharded over 'pipe' on
+    the layer dim (each pipe device keeps only its stages) AND over 'data'
+    on the first free weight dim for matrix leaves — the placement the
+    executor's grads come back in, so params, grads, and optimizer state
+    all rest at ~1/(D * n_data) of the model's layer weights per device.
+    Embed/head stay replicated (O(vocab*dim), a few percent of a
+    Llama-class model)."""
+    if mesh.shape.get(DATA_AXIS, 1) <= 1:
         raise ValueError("fsdp_shard_params needs a 'data' mesh axis to "
                          "shard parameters over (make_mesh(n_data=...))")
-    T = mesh.shape.get(MODEL_AXIS, 1)
-    n_ep = mesh.shape.get(EXPERT_AXIS, 1)
-    dims = _resolve_fsdp_dims(cfg, moe, n_data, T, n_ep, True)
-    if moe is not None:
-        # MoE resting layout (pp x fsdp x MoE): expert stacks over
-        # 'expert', Megatron dims over 'model', fsdp 'data' on the
-        # remaining free matrix dim — same per-leaf map the executor's
-        # in/out specs use
-        base = _moe_template_specs(cfg, moe, T, n_ep)
-    elif T > 1:
-        from .tensor_parallel import _layer_specs
-        base = _layer_specs(cfg)
-    else:
-        base = jax.tree.map(lambda _: P(), dims)
-
-    def put_layer(x, spec, dm):
-        # full-model layer leaves are [L, w0, ...]: 'pipe' on the layer
-        # dim, 'model' per the Megatron spec (T > 1), 'data' on the fsdp
-        # dim — the same resting layout the executor's in/out specs name
-        e = list(tuple(spec))
-        e += [None] * (x.ndim - len(e))
-        e[0] = PIPE_AXIS
-        if dm >= 0:
-            assert e[dm] is None, (spec, dm)
-            e[dm] = DATA_AXIS
-        return jax.device_put(x, NamedSharding(mesh, P(*e)))
-
-    return {
-        "embed": jax.tree.map(
-            lambda x: jax.device_put(x, NamedSharding(mesh, P())),
-            params["embed"]),
-        "layers": jax.tree.map(put_layer, params["layers"], base, dims,
-                               is_leaf=lambda x: isinstance(x, P)),
-        "head": jax.tree.map(
-            lambda x: jax.device_put(x, NamedSharding(mesh, P())),
-            params["head"]),
-    }
+    return jax.device_put(params,
+                          param_shardings(cfg, mesh, moe=moe, fsdp=True))
 
 
 def _fwd_tick_table(D: int, V: int, M: int):
@@ -2297,30 +2312,9 @@ def _build_forward_program(cfg: ModelConfig, mesh: Mesh,
         (_, _, loss) = carry
         return loss / M  # per-device partial (non-last stages: 0)
 
-    if moe is not None:
-        layer_spec = _moe_layer_specs(cfg, moe, T, n_ep)
-        if fsdp_dims is not None:
-            layer_spec = _merge_fsdp_into_stacked(layer_spec, fsdp_dims)
-    elif T > 1 or fsdp:
-        layer_spec = _dense_layer_specs(cfg, T, fsdp_dims)
-    else:
-        layer_spec = P(PIPE_AXIS)
-    if tp_vocab_parallel and not cfg.tie_embeddings:
-        out_spec = ({"w": P(None, MODEL_AXIS), "b": P(MODEL_AXIS)}
-                    if cfg.arch == "ref_decoder"
-                    else {"w": P(None, MODEL_AXIS)})
-        head_spec = {"norm": P(), "out": out_spec}
-    else:
-        head_spec = P()
-    if n_seq > 1:
-        # with an expert axis too (MoE x seq, round 5) the batch shards
-        # over data x expert while the sequence shards over seq
-        lead = (DATA_AXIS, EXPERT_AXIS) if n_ep > 1 else DATA_AXIS
-        batch_spec = P(lead, SEQ_AXIS)
-    elif n_ep > 1:
-        batch_spec = P((DATA_AXIS, EXPERT_AXIS))  # batch over data x expert
-    else:
-        batch_spec = P(DATA_AXIS)
+    layer_spec, head_spec = _stage_param_specs(cfg, moe, T, n_ep, fsdp,
+                                               fsdp_dims, tp_vocab_parallel)
+    batch_spec = _batch_spec(n_seq, n_ep)
     in_specs = (layer_spec, P(), head_spec, batch_spec, batch_spec)
     return spmd_fn, in_specs, D, V
 

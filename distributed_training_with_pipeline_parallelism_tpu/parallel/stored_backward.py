@@ -55,11 +55,7 @@ from typing import Callable, List, Sequence, Tuple
 
 import jax
 import numpy as np
-
-try:  # jax >= 0.5 moved the public jaxpr types
-    from jax.extend.core import Var
-except Exception:  # pragma: no cover - older jax
-    from jax.core import Var  # type: ignore
+from jax.extend.core import Var
 
 
 def _eqn_out_taint(eqn, in_taint: List[bool]) -> List[bool]:

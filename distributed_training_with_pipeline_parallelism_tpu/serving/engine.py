@@ -963,8 +963,14 @@ def make_serving_step_fn(cfg: ModelConfig, mesh: Mesh, *, n_slots: int,
                          out_specs=state_spec)
 
     # donate the state (caches included): the block is state -> state', so
-    # XLA reuses the cache buffers instead of double-allocating them
-    step = jax.jit(sharded, donate_argnums=(donate,))
+    # XLA reuses the cache buffers instead of double-allocating them. The
+    # new state is pinned to the specs the old one came in with: left to
+    # itself jit drops a size-1 'pipe' axis from the outputs' specs, and on
+    # a one-device mesh the second block then compiled a second time.
+    from jax.sharding import NamedSharding
+    step = jax.jit(sharded, donate_argnums=(donate,),
+                   out_shardings={k: NamedSharding(mesh, spec)
+                                  for k, spec in state_spec.items()})
 
     return ServingProgram(cfg, mesh, n_slots=M, max_len=max_len,
                           prompt_max=prompt_max, out_max=out_max,

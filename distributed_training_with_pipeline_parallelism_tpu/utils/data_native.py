@@ -42,7 +42,7 @@ def _configure(lib: ctypes.CDLL) -> None:
     lib.dtpp_dl_close.argtypes = [ctypes.c_void_p]
 
 
-_loader_lib = NativeLib("libdata_loader.so", "data_loader.cpp", _configure)
+_loader_lib = NativeLib("libdata_loader.so", _configure)
 
 
 def _load():

@@ -64,7 +64,7 @@ def annotate(name: str):
 
 
 def _time_fn(fn, *args, iters: int = 5, warmup: int = 2) -> float:
-    from .metrics import force_completion  # host fetch: see metrics.py note
+    from .metrics import force_completion
     out = None
     for _ in range(warmup):
         out = fn(*args)

@@ -25,12 +25,21 @@ import optax
 from jax.sharding import Mesh
 
 from ..parallel.mesh import PIPE_AXIS
-from ..parallel.pipeline import make_pipeline_grad_fn
+from ..parallel.pipeline import (make_pipeline_grad_fn, model_init,
+                                 param_shardings)
 from .checkpoint import restore_checkpoint, save_checkpoint
 from .config import ModelConfig, ScheduleConfig
 from .dynamics import as_dynamics_config, nonfinite_per_stage, stage_stats
 
 Pytree = Any
+
+# The update is in place: params and opt_state are donated to the step, so
+# the program holds ONE copy of weights + moments, not input and output
+# side by side (gpt2-medium bs8 seq1024 on a v5e, by the chip compiler's
+# count: 17.1 GB without the aliasing, 15.4 GB with it — XLA spends some of
+# the freed room on temp — against 15.75 GB of HBM). A caller that wants
+# the state it passed in must copy it first.
+_jit_step = functools.partial(jax.jit, donate_argnums=(0, 1))
 
 
 def make_train_step(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig,
@@ -44,7 +53,12 @@ def make_train_step(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig,
                                   Tuple[Pytree, Any, jax.Array]]:
     """Jitted ``(params, opt_state, tokens, targets) ->
     (params, opt_state, loss)``: pipeline grads + optax update in one XLA
-    program (so the update fuses with the grad psum epilogue). ``moe``
+    program (so the update fuses with the grad psum epilogue). The
+    incoming ``params`` and ``opt_state`` are DONATED — the update happens
+    in their buffers and the arrays passed in are dead afterwards — and
+    the new params are pinned to :func:`..parallel.pipeline.param_shardings`'
+    resting layout, so state born in it (:func:`init_train_state`) stays
+    in it step after step. ``moe``
     (a MoEConfig) selects MoE pipeline stages — see
     :func:`..parallel.pipeline.make_pipeline_grad_fn`. ``fsdp`` runs
     ZeRO-3 inside the pipeline (params placed via ``fsdp_shard_params``;
@@ -101,6 +115,13 @@ def make_train_step(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig,
                                     telemetry=telemetry,
                                     dynamics=want_gns)
     n_stages = mesh.shape[PIPE_AXIS] * sched.n_virtual
+    resting = param_shardings(cfg, mesh, moe=moe, fsdp=fsdp,
+                              tp_vocab_parallel=tp_vocab_parallel)
+
+    def apply_updates(params, updates):
+        return jax.lax.with_sharding_constraint(
+            optax.apply_updates(params, updates), resting)
+
     nan_steps = tuple(getattr(fault_plan, "nan_grad_steps", ()) or ())
     nan_stage = getattr(fault_plan, "nan_grad_stage", None)
     if nan_steps and guard is None:
@@ -131,7 +152,7 @@ def make_train_step(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig,
         if cfg.dropout > 0.0:
             # train-mode dropout: the step takes a per-step PRNG key
             if dcfg is not None:
-                @jax.jit
+                @_jit_step
                 def train_step_dropout_dyn(params, opt_state, tokens,
                                            targets, rng):
                     loss, grads, sq_mb = run_grads(params, tokens, targets,
@@ -139,38 +160,38 @@ def make_train_step(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig,
                     updates, opt_state = optimizer.update(grads, opt_state,
                                                           params)
                     dyn = dyn_stats(grads, params, updates, sq_mb)
-                    params = optax.apply_updates(params, updates)
+                    params = apply_updates(params, updates)
                     return params, opt_state, loss, dyn
 
                 return train_step_dropout_dyn
 
-            @jax.jit
+            @_jit_step
             def train_step_dropout(params, opt_state, tokens, targets, rng):
                 loss, grads = grad_fn(params, tokens, targets, rng)
                 updates, opt_state = optimizer.update(grads, opt_state,
                                                       params)
-                params = optax.apply_updates(params, updates)
+                params = apply_updates(params, updates)
                 return params, opt_state, loss
 
             return train_step_dropout
 
         if dcfg is not None:
-            @jax.jit
+            @_jit_step
             def train_step_dyn(params, opt_state, tokens, targets):
                 loss, grads, sq_mb = run_grads(params, tokens, targets, None)
                 updates, opt_state = optimizer.update(grads, opt_state,
                                                       params)
                 dyn = dyn_stats(grads, params, updates, sq_mb)
-                params = optax.apply_updates(params, updates)
+                params = apply_updates(params, updates)
                 return params, opt_state, loss, dyn
 
             return train_step_dyn
 
-        @jax.jit
+        @_jit_step
         def train_step(params, opt_state, tokens, targets):
             loss, grads = grad_fn(params, tokens, targets)
             updates, opt_state = optimizer.update(grads, opt_state, params)
-            params = optax.apply_updates(params, updates)
+            params = apply_updates(params, updates)
             return params, opt_state, loss
 
         return train_step
@@ -222,7 +243,7 @@ def make_train_step(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig,
             jnp.where(loss_ok, jnp.int32(-1), jnp.int32(-2)),
             jnp.argmax(~stage_ok).astype(jnp.int32))
         updates, new_opt = optimizer.update(grads, opt_state, params)
-        new_params = optax.apply_updates(params, updates)
+        new_params = apply_updates(params, updates)
         dyn = (dyn_stats(grads, params, updates, sq_mb)
                if dcfg is not None else None)
 
@@ -246,7 +267,7 @@ def make_train_step(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig,
         return params, opt_state, loss, guard_state
 
     if cfg.dropout > 0.0:
-        @jax.jit
+        @_jit_step
         def guarded_step_dropout(params, opt_state, tokens, targets, rng,
                                  guard_state):
             return guarded(params, opt_state, tokens, targets, guard_state,
@@ -254,32 +275,70 @@ def make_train_step(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig,
 
         return guarded_step_dropout
 
-    @jax.jit
+    @_jit_step
     def guarded_step(params, opt_state, tokens, targets, guard_state):
         return guarded(params, opt_state, tokens, targets, guard_state)
 
     return guarded_step
 
 
-def init_sharded_opt_state(optimizer: optax.GradientTransformation,
-                           params: Pytree, mesh: Mesh) -> Pytree:
-    """ZeRO-1 init without the replicated peak: compute the state's shape
-    tree abstractly, derive FSDP placements, and jit ``optimizer.init``
-    with those out_shardings so the moments are born sharded."""
-    from jax.sharding import NamedSharding
+def init_params(cfg: ModelConfig, mesh: Mesh, key: jax.Array, moe=None,
+                fsdp: bool = False,
+                tp_vocab_parallel: bool = False) -> Pytree:
+    """``transformer_init`` (``moe_lm_init`` with ``moe``) born in the
+    resting layout (:func:`..parallel.pipeline.param_shardings`): the init
+    is jitted with those ``out_shardings``, so each device only ever
+    materializes its own stages' weights — the whole model never sits on
+    the first device, which for gpt2-xl (6.2 GB fp32) plus its Adam
+    moments would not fit a 16 GB chip."""
+    return jax.jit(model_init(cfg, moe), out_shardings=param_shardings(
+        cfg, mesh, moe=moe, fsdp=fsdp,
+        tp_vocab_parallel=tp_vocab_parallel))(key)
+
+
+def opt_state_shardings(optimizer: optax.GradientTransformation,
+                        params: Pytree, mesh: Mesh,
+                        zero1: bool = False) -> Pytree:
+    """Where ``optimizer.init(params)`` rests: one ``NamedSharding`` per
+    state leaf. Every leaf that mirrors a parameter — its tree path ends in
+    that parameter's path and the shapes agree: Adam's mu/nu,
+    ``MultiSteps``' accumulated grads — rests in that parameter's sharding;
+    leaves that belong to no parameter (step counts) are replicated.
+    ``zero1`` with a 'data' axis instead shards every leaf over 'data' on
+    its largest divisible dim (the FSDP placement rule). ``params`` may be
+    arrays or ``ShapeDtypeStruct``s carrying shardings."""
+    from jax.sharding import NamedSharding, PartitionSpec
 
     from ..parallel.fsdp import fsdp_specs
     from ..parallel.mesh import DATA_AXIS
 
-    n = mesh.shape.get(DATA_AXIS, 1)
-    if n <= 1:
-        return optimizer.init(params)
     shapes = jax.eval_shape(optimizer.init, params)
-    shardings = jax.tree.map(lambda s: NamedSharding(mesh, s),
-                             fsdp_specs(shapes, n),
-                             is_leaf=lambda x: not isinstance(
-                                 x, (dict, list, tuple)))
-    return jax.jit(optimizer.init, out_shardings=shardings)(params)
+    n_data = mesh.shape.get(DATA_AXIS, 1)
+    if zero1 and n_data > 1:
+        return jax.tree.map(lambda s: NamedSharding(mesh, s),
+                            fsdp_specs(shapes, n_data),
+                            is_leaf=lambda x: not isinstance(
+                                x, (dict, list, tuple)))
+    of_param = dict(jax.tree_util.tree_flatten_with_path(params)[0])
+    replicated = NamedSharding(mesh, PartitionSpec())
+
+    def rest(path, leaf):
+        for n in range(len(path)):
+            p = of_param.get(path[n:])
+            if p is not None and p.shape == leaf.shape:
+                return p.sharding
+        return replicated
+
+    return jax.tree_util.tree_map_with_path(rest, shapes)
+
+
+def init_opt_state(optimizer: optax.GradientTransformation, params: Pytree,
+                   mesh: Mesh, zero1: bool = False) -> Pytree:
+    """``optimizer.init`` jitted INTO :func:`opt_state_shardings`: the
+    state is born where it rests, so no replicated (or first-device) peak
+    ever materializes."""
+    return jax.jit(optimizer.init, out_shardings=opt_state_shardings(
+        optimizer, params, mesh, zero1=zero1))(params)
 
 
 def shard_opt_state(opt_state: Pytree, mesh: Mesh) -> Pytree:
@@ -413,7 +472,11 @@ def fit(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig, params: Pytree,
         dynamics=None):
     """Training loop over a ``(tokens, targets)`` iterator.
 
-    Returns (params, list of (step, loss)). The data contract matches the
+    Returns (params, list of (step, loss)). ``params`` is CONSUMED: it is
+    placed in the resting layout (:func:`..parallel.pipeline.
+    param_shardings` — build it there with :func:`init_params`) and donated
+    to the step, so use the returned tree; a caller that still needs the
+    tree it passed in copies it first. The data contract matches the
     reference's synthetic setup (random token batches,
     ``LLMsDistributedTrainingHelper.py:191-194``) but accepts any iterator.
 
@@ -534,19 +597,13 @@ def fit(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig, params: Pytree,
     if fsdp and zero1:
         raise ValueError("fsdp already shards optimizer state (ZeRO-3 "
                          "subsumes ZeRO-1) — drop --zero1")
-    if fsdp:
-        # pp x fsdp (ZeRO-3 in-pipeline): params rest pipe x data sharded;
-        # the elementwise optax init/update inherits that layout through
-        # jit, so moments are born sharded with no extra machinery
-        from ..parallel.pipeline import fsdp_shard_params
-        params = fsdp_shard_params(params, cfg, mesh, moe=moe)
-        opt_state = jax.jit(optimizer.init)(params)
-    elif zero1:
-        # init directly INTO the sharded layout: the replicated moments
-        # never materialize, so the ZeRO-1 memory ceiling holds at init too
-        opt_state = init_sharded_opt_state(optimizer, params, mesh)
-    else:
-        opt_state = optimizer.init(params)
+    # Params rest where the executor takes them (a no-op for params born
+    # there by init_params; with fsdp that is pipe x data sharded) and the
+    # moments are born in their parameter's layout — or, zero1, sharded
+    # over 'data' — so neither ever materializes whole on one device.
+    params = jax.device_put(params, param_shardings(
+        cfg, mesh, moe=moe, fsdp=fsdp, tp_vocab_parallel=tp_vocab_parallel))
+    opt_state = init_opt_state(optimizer, params, mesh, zero1=zero1)
 
     mgr = None
     if checkpoint_dir:

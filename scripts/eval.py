@@ -56,6 +56,9 @@ def main():
         from distributed_training_with_pipeline_parallelism_tpu.parallel.mesh import (
             simulate_cpu_devices)
         simulate_cpu_devices(args.simulate_devices)
+    from distributed_training_with_pipeline_parallelism_tpu.utils.compile_cache import (
+        enable_compile_cache)
+    enable_compile_cache()
     import jax
 
     import distributed_training_with_pipeline_parallelism_tpu as dtpp

@@ -17,8 +17,8 @@ The measured leg of the calibration observatory (docs/observability.md
   hard failure on real hardware, a warning on the CPU proxy (tier-1
   runs it warn-only; a sim mesh measures the host, not the model).
 - **Backfill** (``--backfill``): ingest the pre-ledger history —
-  ``BENCH_r01..r05.json`` and ``results/history.jsonl`` — into the
-  ledger. Rows that carry a measurement but no prediction are kept
+  ``results/history.jsonl`` — into the ledger. Rows that carry a
+  measurement but no prediction are kept
   with ``predicted: null``; rows that carry nothing calibratable are
   skipped with a printed, per-row reason. Nothing is dropped silently,
   and re-running is idempotent (exact duplicate lines are skipped).
@@ -59,8 +59,8 @@ def parse_args(argv):
                    help="gate: corrected must beat raw error (warn-only "
                         "on the cpu backend), artifact must byte-roundtrip")
     p.add_argument("--backfill", action="store_true",
-                   help="ingest BENCH_r*.json + results/history.jsonl "
-                        "into the ledger instead of probing")
+                   help="ingest results/history.jsonl into the ledger "
+                        "instead of probing")
     return p.parse_args(argv)
 
 
@@ -97,20 +97,6 @@ def run_backfill(args) -> int:
         else:
             seen.add(cal.canonical_row_line(row))
             rows.append(row)
-
-    for i in range(1, 6):
-        label = f"BENCH_r{i:02d}"
-        path = os.path.join(ROOT, label + ".json")
-        if not os.path.exists(path):
-            print(f"probe --backfill: skip {label}: no such file")
-            n_skipped += 1
-            continue
-        with open(path) as fh:
-            blob = json.load(fh)
-        reason = ("bench run failed (rc != 0) or nothing parsed"
-                  if blob.get("rc") or not blob.get("parsed")
-                  else "no derivable step time (unit/batch/seq missing)")
-        keep(cal.backfill_row_from_bench(blob, label=label), label, reason)
 
     hist = os.path.join(ROOT, "results", "history.jsonl")
     if os.path.exists(hist):

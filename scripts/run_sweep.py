@@ -40,6 +40,9 @@ def main():
         from distributed_training_with_pipeline_parallelism_tpu.parallel.mesh import (
             simulate_cpu_devices)
         simulate_cpu_devices(args.simulate_devices)
+    from distributed_training_with_pipeline_parallelism_tpu.utils.compile_cache import (
+        enable_compile_cache)
+    enable_compile_cache()
 
     from distributed_training_with_pipeline_parallelism_tpu.utils.plotting import (
         plot_speedup_and_efficiency, plot_throughput_grid)

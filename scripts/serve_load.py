@@ -87,6 +87,9 @@ def main(argv=None) -> int:
                     help="fraction of detected HBM the preflight may "
                          "budget (paged only)")
     args = ap.parse_args(argv)
+    from distributed_training_with_pipeline_parallelism_tpu.utils.compile_cache import (
+        enable_compile_cache)
+    enable_compile_cache()
     out_dir = args.out_dir
     loads = [float(x) for x in args.loads.split(",")]
 

@@ -47,6 +47,9 @@ jax.config.update("jax_platforms", "cpu")
 
 def main() -> int:
     out_dir = sys.argv[1] if len(sys.argv) > 1 else "/tmp/serve_smoke"
+    from distributed_training_with_pipeline_parallelism_tpu.utils.compile_cache import (
+        enable_compile_cache)
+    enable_compile_cache()
 
     import numpy as np
 

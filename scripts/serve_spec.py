@@ -63,6 +63,9 @@ def main(argv=None) -> int:
                     help="run the pair through the paged-KV engine "
                          "(page pool + committed-frontier rollback)")
     args = ap.parse_args(argv)
+    from distributed_training_with_pipeline_parallelism_tpu.utils.compile_cache import (
+        enable_compile_cache)
+    enable_compile_cache()
     out_dir = args.out_dir
     loads = (None if args.loads == "none"
              else [float(x) for x in args.loads.split(",")])

@@ -21,7 +21,10 @@ from distributed_training_with_pipeline_parallelism_tpu.utils.config import (  #
     SCHEDULE_NAMES)
 
 
-def main():
+def main(argv=None):
+    """Parse ``argv`` (default ``sys.argv[1:]``), train, and return
+    ``(params, history)`` so in-process callers (``chip_smoke.py``) drive
+    exactly the command-line path."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--model", default="gpt2-small",
                     help="gpt2-{small,medium,large,xl}, llama2-7b, llama3-8b, "
@@ -173,7 +176,7 @@ def main():
     ap.add_argument("--ep", type=int, default=1,
                     help="expert-parallel (expert-axis) size; requires "
                          "--moe-experts divisible by it")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.native_loader and not args.data_file:
         ap.error("--native-loader requires --data-file")
     if args.ep > 1 and not args.moe_experts:
@@ -200,10 +203,12 @@ def main():
         from distributed_training_with_pipeline_parallelism_tpu.parallel.mesh import (
             simulate_cpu_devices)
         simulate_cpu_devices(args.simulate_devices)
+    from distributed_training_with_pipeline_parallelism_tpu.utils.compile_cache import (
+        enable_compile_cache)
+    enable_compile_cache()
     import jax
 
     import distributed_training_with_pipeline_parallelism_tpu as dtpp
-    from distributed_training_with_pipeline_parallelism_tpu.models import transformer as tfm
     from distributed_training_with_pipeline_parallelism_tpu.models.gpt2 import gpt2_config
     from distributed_training_with_pipeline_parallelism_tpu.models.llama import llama_config
     from distributed_training_with_pipeline_parallelism_tpu.parallel.mesh import make_mesh
@@ -249,7 +254,7 @@ def main():
     moe = None
     if args.moe_experts:
         from distributed_training_with_pipeline_parallelism_tpu.models.moe import (
-            MoEConfig, moe_lm_init)
+            MoEConfig)
         moe = MoEConfig(n_experts=args.moe_experts, top_k=args.moe_topk,
                         capacity_factor=args.moe_capacity,
                         aux_loss_weight=args.moe_aux)
@@ -271,13 +276,13 @@ def main():
         total_steps=max(1, args.steps // args.grad_accum))
 
     def init_params(key):
-        if moe is not None:
-            return moe_lm_init(key, cfg, moe)
-        return tfm.transformer_init(key, cfg)
+        # born in the layout they rest in: no device holds the whole model
+        return train.init_params(cfg, mesh, key, moe=moe, fsdp=args.fsdp,
+                                 tp_vocab_parallel=args.vocab_parallel)
 
     if args.resume:
         import jax.numpy as jnp
-        params_t = jax.eval_shape(lambda: init_params(jax.random.key(args.seed)))
+        params_t = jax.eval_shape(init_params, jax.random.key(args.seed))
         # Accept either layout: a fit()-style dir of step_N/ trees
         # ({'params','opt_state','step'}), a single step_N dir, or a bare
         # params checkpoint (e.g. converted HF weights).
@@ -367,6 +372,7 @@ def main():
         print(f"checkpoints in {args.ckpt}", flush=True)
     if history:
         print(f"final loss: {history[-1][1]:.4f}", flush=True)
+    return params, history
 
 
 if __name__ == "__main__":

@@ -20,8 +20,9 @@ The contract under test (docs/observability.md "Cost model & MFU"):
 - ``scripts/regress.py`` fails on a regression, warn-only on CPU proxy;
 - ``scripts/profile_breakdown.py --from-report`` degrades gracefully on
   reports missing sections;
-- ``bench._init_backend`` survives a backend that raises UNAVAILABLE at
-  ``jax.devices()`` — device discovery stays inside the guard.
+- ``bench.py`` measures a TPU or nothing: no TPU is a non-zero exit
+  with no result, a backend that fails to come up fails the run, and an
+  unknown device kind has no peak.
 """
 
 import importlib.util
@@ -112,15 +113,25 @@ def test_policy_resolution_matches_executor_rules():
     assert resolve_backward_policy(gp, n_devices=1) == "stored"
 
 
-def test_hardware_presets_match_bench_peaks():
+def test_hardware_presets_match_bench_peaks(monkeypatch):
     import bench
     for key, peak in bench._PEAK_FLOPS.items():
         assert hardware_spec_for(key).peak_flops == peak
     assert hardware_spec_for("cpu") is CPU_PROXY
-    assert hardware_spec_for("") is CPU_PROXY
     assert hardware_spec_for("TPU v5 lite").peak_flops == 197e12
-    # unknown accelerators fall back to the fleet default, like bench
-    assert hardware_spec_for("tpu v99").peak_flops == 197e12
+    # a device that is not in the table is an error, not a default —
+    # here and in bench alike
+    for unknown in ("tpu v99", ""):
+        with pytest.raises(ValueError, match="no hardware preset"):
+            hardware_spec_for(unknown)
+
+    class FakeDevice:
+        platform = "tpu"
+        device_kind = "TPU v99"
+
+    monkeypatch.setattr(jax, "devices", lambda *a, **kw: [FakeDevice()])
+    with pytest.raises(ValueError, match="no bf16 peak on record"):
+        bench.chip_peak_flops()
 
 
 def test_bench_flops_delegates_to_cost_model():
@@ -396,43 +407,33 @@ def test_profile_breakdown_renders_full_report(tmp_path, capsys):
 
 
 # ---------------------------------------------------------------------------
-# bench backend guard (satellite a): a transient UNAVAILABLE at
-# jax.devices() must fall back to CPU, not kill the bench with rc=1
+# bench measures a TPU or nothing: no CPU fallback, no swallowed init error
 # ---------------------------------------------------------------------------
 
 
-def test_bench_backend_fallback_survives_unavailable(monkeypatch):
+def test_bench_without_tpu_exits_nonzero_with_no_result(capsys):
+    """The suite runs on the CPU backend — exactly the case that used to
+    switch to a proxy headline and exit 0."""
     import bench
-    real_devices = jax.devices  # bound before patching
-    calls = {"n": 0}
-
-    def flaky_devices(*a, **kw):
-        calls["n"] += 1
-        if calls["n"] == 1:
-            raise RuntimeError("UNAVAILABLE: TPU backend setup/compile "
-                               "error (transient)")
-        return real_devices(*a, **kw)
-
-    monkeypatch.setattr(jax, "devices", flaky_devices)
-    # clear_backends would invalidate every live array in this test
-    # process; the fallback path only needs it on a real failed backend
-    from jax.extend import backend as jex_backend
-    monkeypatch.setattr(jex_backend, "clear_backends", lambda: None)
-
-    info = bench._init_backend(max_retries=1, backoff_s=0)
-    assert info["backend_fallback"] == "cpu"
-    assert info["backend"] == "cpu"
-    assert info["n_devices"] >= 1
-    assert "UNAVAILABLE" in info["backend_error"]
-    assert calls["n"] == 2  # failed once, recovered inside the guard
+    assert jax.devices()[0].platform == "cpu"
+    for mode in (bench.run, bench.run_serve):
+        with pytest.raises(SystemExit) as exit_info:
+            mode()
+        assert exit_info.value.code not in (0, None)
+        assert "needs a TPU" in str(exit_info.value.code)
+    assert capsys.readouterr().out == ""  # no result line
 
 
-def test_bench_backend_noninit_errors_reraise(monkeypatch):
+def test_bench_backend_init_errors_reraise(monkeypatch):
+    """A backend that fails to come up fails the run, whatever it says —
+    UNAVAILABLE used to be retried and then answered from the CPU."""
     import bench
 
-    def broken_devices(*a, **kw):
-        raise RuntimeError("something unrelated exploded")
+    for msg in ("UNAVAILABLE: TPU backend setup/compile error (transient)",
+                "something unrelated exploded"):
+        def broken_devices(*a, msg=msg, **kw):
+            raise RuntimeError(msg)
 
-    monkeypatch.setattr(jax, "devices", broken_devices)
-    with pytest.raises(RuntimeError, match="unrelated"):
-        bench._init_backend(max_retries=1, backoff_s=0)
+        monkeypatch.setattr(jax, "devices", broken_devices)
+        with pytest.raises(RuntimeError, match=msg.split(":")[0]):
+            bench.run()

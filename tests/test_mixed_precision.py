@@ -105,8 +105,10 @@ def test_grad_accum_equals_big_batch():
         yield big[:4], big[:4]
         yield big[4:], big[4:]
 
-    accum_params, _ = fit(cfg, mesh, sched, params0, halves(), num_steps=2,
-                          optimizer=opt, verbose=False, grad_accum=2)
+    # fit consumes the params it is given: the first run trains a copy
+    accum_params, _ = fit(cfg, mesh, sched, jax.tree.map(jnp.copy, params0),
+                          halves(), num_steps=2, optimizer=opt,
+                          verbose=False, grad_accum=2)
 
     def whole():
         yield big, big

@@ -46,11 +46,6 @@ from distributed_training_with_pipeline_parallelism_tpu.parallel.schedules impor
 from distributed_training_with_pipeline_parallelism_tpu.parallel.tensor_parallel import (
     resolve_tp_overlap)
 
-try:
-    from jax.shard_map import shard_map
-except ImportError:  # pragma: no cover - jax version dependent
-    from jax.experimental.shard_map import shard_map
-
 CFG = dtpp.ModelConfig(dim=32, n_layers=8, n_heads=4, vocab_size=50,
                        ffn_dim=64)
 
@@ -212,8 +207,8 @@ def _tp_problem(arch):
 def _tp_loss_fn(cfg, full_specs, mesh):
     def inner(p, x):
         return tfm.mlp_block(cfg, p, x, tp_axis="model", tp_size=_TP)
-    f = shard_map(inner, mesh=mesh, in_specs=(full_specs, P()),
-                  out_specs=P(), check_rep=False)
+    f = jax.shard_map(inner, mesh=mesh, in_specs=(full_specs, P()),
+                      out_specs=P(), check_vma=False)
     return lambda p, x: jnp.sum(f(p, x) ** 2)
 
 
@@ -239,9 +234,9 @@ def test_collective_matmul_census():
     cfg, params, h, full = _tp_problem("gpt2")
     mesh = Mesh(np.array(jax.devices()[:_TP]), ("model",))
     rcfg = dataclasses.replace(cfg, tp_overlap="ring")
-    fwd = shard_map(
+    fwd = jax.shard_map(
         lambda p, x: tfm.mlp_block(rcfg, p, x, tp_axis="model", tp_size=_TP),
-        mesh=mesh, in_specs=(full, P()), out_specs=P(), check_rep=False)
+        mesh=mesh, in_specs=(full, P()), out_specs=P(), check_vma=False)
     # gpt2 ring MLP: up-proj gather-matmul + down-proj matmul-scatter +
     # the residual's seq_all_gather = 2 gathers + 1 scatter
     expected = collective_matmul_ppermutes(_TP, n_gathers=2, n_scatters=1)

@@ -240,8 +240,10 @@ def test_nan_step_skipped_bitwise():
     step = train.make_train_step(cfg, mesh, sched, opt, guard=AnomalyGuard(),
                                  fault_plan=FaultPlan(nan_grad_steps=(2,)))
 
-    # run A: batches 0..3, step 2 poisoned -> skipped
-    p, s, gs = params0, opt.init(params0), init_guard_state(0)
+    # run A: batches 0..3, step 2 poisoned -> skipped (the step donates
+    # its state, so run A trains a copy and run B gets params0 itself)
+    p, s, gs = (jax.tree.map(jnp.copy, params0), opt.init(params0),
+                init_guard_state(0))
     losses_a = []
     for tok, tgt in data[:4]:
         p, s, loss, gs = step(p, s, tok, tgt, gs)

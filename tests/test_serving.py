@@ -62,11 +62,14 @@ def _assert_oracle_parity(cfg, params, program, completions, budgets):
     ("gpt2", {}),
     ("llama", dict(n_kv_heads=2)),
 ])
-@pytest.mark.parametrize("D,M,C", [(2, 3, 2), (2, 2, 1)])
+@pytest.mark.parametrize("D,M,C", [(2, 3, 2), (2, 2, 1), (1, 2, 2)])
 def test_serving_oracle_parity_recycled_slots(arch, kw, D, M, C):
     """More requests than slots with staggered arrivals: retired slots
     are recycled mid-flight, and every request still bit-matches the
-    single-device oracle (chunked prefill included)."""
+    single-device oracle (chunked prefill included). D=1 is the one-chip
+    mesh ``chip_smoke.py`` serves on; on any mesh the block compiles
+    once (jit used to drop the size-1 pipe axis from the new state's
+    specs, and the second block then compiled again)."""
     cfg = _cfg(arch, **kw)
     params = tfm.transformer_init(jax.random.key(0), cfg)
     program = make_serving_step_fn(cfg, make_mesh(n_pipe=D), n_slots=M,
@@ -76,6 +79,7 @@ def test_serving_oracle_parity_recycled_slots(arch, kw, D, M, C):
     requests = _requests(cfg, 2 * M + 1, seed=3)
     res = engine.run(requests, policy="continuous")
     assert len(res.completions) == len(requests)
+    assert program.step._cache_size() == 1
     by_slot = {}
     for c in res.completions:
         by_slot.setdefault(c.slot, []).append(c.rid)

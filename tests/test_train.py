@@ -1,5 +1,7 @@
 """Training-loop, optimizer, model-registry, and checkpoint tests."""
 
+import os
+
 import numpy as np
 import pytest
 
@@ -92,13 +94,17 @@ def test_fit_checkpoint_resume_and_metrics(tmp_path):
     ckdir = str(tmp_path / "ck")
     metrics = str(tmp_path / "metrics.jsonl")
 
-    # uninterrupted 6-step run (fresh data iterator each time: deterministic)
-    full_params, _ = train.fit(cfg, mesh, sched, params,
+    # uninterrupted 6-step run (fresh data iterator each time: deterministic;
+    # fit consumes the params it is given, so each run gets its own copy)
+    def copy(tree):
+        return jax.tree.map(jnp.copy, tree)
+
+    full_params, _ = train.fit(cfg, mesh, sched, copy(params),
                                train.synthetic_data(cfg, 4, 8, seed=3),
                                num_steps=6, optimizer=opt, verbose=False)
 
     # interrupted: run to a checkpoint at step 3 by stopping at num_steps=4...
-    train.fit(cfg, mesh, sched, params,
+    train.fit(cfg, mesh, sched, copy(params),
               train.synthetic_data(cfg, 4, 8, seed=3), num_steps=4,
               optimizer=opt, verbose=False, checkpoint_dir=ckdir,
               checkpoint_every=4, log_every=2, metrics_path=metrics)
@@ -158,3 +164,63 @@ def test_adamw_decay_set_matches_golden_list():
         "['layers']['lin1']['w']", "['layers']['lin2']['w']",
         "['head']['out']['w']",
     }, sorted(decayed)
+
+
+def test_compile_cache_is_placed_from_outside(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR places the entry points' compile cache and
+    then nothing is set in code; unset, it is the fixed <repo>/.jax_cache.
+    (The real config is never touched here: the suite runs without a
+    persistent cache — see conftest.py.)"""
+    from distributed_training_with_pipeline_parallelism_tpu.utils import (
+        compile_cache)
+    updates = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda name, value: updates.append((name, value)))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/kept")
+    assert compile_cache.enable_compile_cache() == "/somewhere/kept"
+    assert updates == []
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert compile_cache.enable_compile_cache() == os.path.join(
+        repo, ".jax_cache")
+    assert updates == [("jax_compilation_cache_dir",
+                        os.path.join(repo, ".jax_cache"))]
+
+
+def test_state_is_born_and_stays_in_the_resting_layout():
+    """Params and AdamW moments are initialised INTO the layout the
+    executor takes them in — layer leaves 'pipe'-sharded on the layer
+    axis, embedding and head replicated, never whole on one device — and
+    the donated step hands them back in it, in their own buffers."""
+    cfg = dtpp.ModelConfig(dim=16, n_layers=4, n_heads=2, vocab_size=32,
+                           ffn_dim=32, arch="gpt2", max_seq_len=8)
+    mesh = make_mesh(n_pipe=2)
+    sched = dtpp.ScheduleConfig(name="GPipe", n_microbatches=2)
+    opt = train.adamw(total_steps=4, warmup_steps=1)
+    params = train.init_params(cfg, mesh, jax.random.key(0))
+    opt_state = train.init_opt_state(opt, params, mesh)
+    # the plain init's values, only placed (to an ulp: under jit the
+    # embeddings' scale multiply fuses with the draw)
+    plain = tfm.transformer_init(jax.random.key(0), cfg)
+    for a, b in zip(jax.tree.leaves(params), jax.tree.leaves(plain)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6)
+
+    def check(params, opt_state):
+        adam = opt_state[1][0]
+        for tree in (params, adam.mu, adam.nu):
+            for leaf in jax.tree.leaves(tree["layers"]):
+                assert leaf.sharding.spec[0] == "pipe", leaf.sharding
+                assert leaf.addressable_shards[0].data.shape[0] == 2
+            for leaf in jax.tree.leaves((tree["embed"], tree["head"])):
+                assert leaf.sharding.is_fully_replicated
+                assert len(leaf.sharding.device_set) == 2
+        assert adam.count.sharding.is_fully_replicated
+
+    check(params, opt_state)
+    step = train.make_train_step(cfg, mesh, sched, opt)
+    tokens, targets = next(train.synthetic_data(cfg, 4, 8))
+    old = jax.tree.leaves((params, opt_state))
+    params, opt_state, loss = step(params, opt_state, tokens, targets)
+    assert np.isfinite(float(loss))
+    check(params, opt_state)
+    assert all(x.is_deleted() for x in old)  # donated: updated in place
