@@ -1,0 +1,16 @@
+"""Share of the device's busy time spent in the backward pass (phase
+``backward``: ops whose ``op_name`` holds ``transpose(``, the transposed half
+of a ``jvp``). Union seconds over the planes' summed busy seconds
+(``harness/scopes.py``). A place to look, not a verdict: only
+``train.tokens_per_s`` says a change helped."""
+
+LAYER = "optimizer step"
+UNIT = "%"
+BETTER = "lower"
+MOVES = "train.tokens_per_s"
+SOURCE = "device_trace"
+
+
+def read(run):
+    from benchmark.harness.scopes import share_pct
+    return share_pct(run, "phases", "backward")

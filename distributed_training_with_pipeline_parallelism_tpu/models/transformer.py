@@ -13,6 +13,12 @@ Three block families selected by ``ModelConfig.arch``:
 - ``llama`` — pre-RMSNorm, RoPE, grouped-query causal attention, SwiGLU MLP,
   no biases.
 
+Every block names itself for the profiler with ``jax.named_scope`` —
+``model/embed``, ``model/layers``, ``model/attn``, ``model/mlp``,
+``model/head_loss``: the vocabulary of ``utils/profiling.py:REGIONS`` — at
+the one function every path shares (fused step, tick-executor stage body,
+serving forward), so a trace of any of them splits the same way.
+
 Parameters are organized for pipeline stage-slicing (SURVEY.md §7: the C3
 ``manual_model_split`` equivalent is a pytree partition, not module deletion):
 
@@ -97,39 +103,49 @@ def layer_apply(cfg: ModelConfig, params: Dict, h: jax.Array,
 
     if cfg.arch == "ref_decoder":
         mem = h  # the reference calls layer(h, h): memory is the layer's input
-        sa = mha_apply(params["self_attn"], h, h, heads, flash=fl,
-                       tp_axis=tp_axis, tp_size=tp_size, dropout_rate=p,
-                       dropout_rng=site(0))
-        x = layer_norm_apply(params["ln1"], h + dropout_apply(sa, p, site(1)))
-        ca = mha_apply(params["cross_attn"], x, mem, heads, flash=fl,
-                       tp_axis=tp_axis, tp_size=tp_size, dropout_rate=p,
-                       dropout_rng=site(2))
-        x = layer_norm_apply(params["ln2"], x + dropout_apply(ca, p, site(3)))
-        # the FFN-inner activation is a column-parallel local shard under
-        # TP: its mask is the global mask's local slice (oracle-exact)
-        ff = _ffn_out(params["lin2"],
-                      sharded_dropout_apply(
-                          jax.checkpoint(jax.nn.relu)(
-                              linear_apply(params["lin1"],
-                                           _tp_in(x, tp_axis))),
-                          p, site(4), axis=tp_axis, n_shards=tp_size,
-                          shard_dim=-1),
-                      tp_axis)
-        return layer_norm_apply(params["ln3"], x + dropout_apply(ff, p, site(5)))
+        # post-LN: each block's norm follows its residual add
+        with jax.named_scope("model/attn"):
+            sa = mha_apply(params["self_attn"], h, h, heads, flash=fl,
+                           tp_axis=tp_axis, tp_size=tp_size, dropout_rate=p,
+                           dropout_rng=site(0))
+            x = layer_norm_apply(params["ln1"],
+                                 h + dropout_apply(sa, p, site(1)))
+            ca = mha_apply(params["cross_attn"], x, mem, heads, flash=fl,
+                           tp_axis=tp_axis, tp_size=tp_size, dropout_rate=p,
+                           dropout_rng=site(2))
+            x = layer_norm_apply(params["ln2"],
+                                 x + dropout_apply(ca, p, site(3)))
+        with jax.named_scope("model/mlp"):
+            # the FFN-inner activation is a column-parallel local shard
+            # under TP: its mask is the global mask's local slice
+            # (oracle-exact)
+            ff = _ffn_out(params["lin2"],
+                          sharded_dropout_apply(
+                              jax.checkpoint(jax.nn.relu)(
+                                  linear_apply(params["lin1"],
+                                               _tp_in(x, tp_axis))),
+                              p, site(4), axis=tp_axis, n_shards=tp_size,
+                              shard_dim=-1),
+                          tp_axis)
+            return layer_norm_apply(params["ln3"],
+                                    x + dropout_apply(ff, p, site(5)))
     if cfg.arch == "gpt2":
-        a = layer_norm_apply(params["ln1"], h)
-        attn = mha_apply(params["attn"], a, a, heads, causal=cfg.causal,
-                         flash=fl, tp_axis=tp_axis, tp_size=tp_size,
-                         dropout_rate=p, dropout_rng=site(0))
+        with jax.named_scope("model/attn"):
+            a = layer_norm_apply(params["ln1"], h)
+            attn = mha_apply(params["attn"], a, a, heads, causal=cfg.causal,
+                             flash=fl, tp_axis=tp_axis, tp_size=tp_size,
+                             dropout_rate=p, dropout_rng=site(0))
         h = h + dropout_apply(attn, p, site(1))
         return mlp_block(cfg, params, h, tp_axis=tp_axis, tp_size=tp_size,
                          rng=site(2), dropout=p)
     if cfg.arch == "llama":
-        a = rms_norm_apply(params["rms1"], h, cfg.rms_eps)
-        attn = mha_apply(params["attn"], a, a, heads, causal=cfg.causal,
-                         rope_angles=rope_angles, flash=fl, tp_axis=tp_axis,
-                         tp_size=tp_size, window=cfg.sliding_window,
-                         dropout_rate=p, dropout_rng=site(0))
+        with jax.named_scope("model/attn"):
+            a = rms_norm_apply(params["rms1"], h, cfg.rms_eps)
+            attn = mha_apply(params["attn"], a, a, heads, causal=cfg.causal,
+                             rope_angles=rope_angles, flash=fl,
+                             tp_axis=tp_axis, tp_size=tp_size,
+                             window=cfg.sliding_window, dropout_rate=p,
+                             dropout_rng=site(0))
         h = h + dropout_apply(attn, p, site(1))
         return mlp_block(cfg, params, h, tp_axis=tp_axis, tp_size=tp_size,
                          rng=site(2), dropout=p)
@@ -150,6 +166,7 @@ def _ffn_out(params: Dict, z: jax.Array, tp_axis: Optional[str]) -> jax.Array:
     return row_parallel_linear(params, z, tp_axis)
 
 
+@jax.named_scope("model/mlp")
 def mlp_block(cfg: ModelConfig, params: Dict, h: jax.Array,
               tp_axis: Optional[str] = None, tp_size: int = 1,
               rng: Optional[jax.Array] = None, dropout: float = 0.0) -> jax.Array:
@@ -270,6 +287,7 @@ def compute_cast(cfg: ModelConfig, tree: Dict) -> Dict:
     return jax.tree.map(lambda x: x.astype(dtype), tree)
 
 
+@jax.named_scope("model/embed")
 def embed_apply(cfg: ModelConfig, embed: Dict, tokens: jax.Array,
                 rng: Optional[jax.Array] = None) -> jax.Array:
     h = embedding_apply(embed["tok"], tokens)
@@ -290,6 +308,10 @@ def _rope(cfg: ModelConfig, seq_len: int) -> Optional[jax.Array]:
                             cfg.rope_scaling)
 
 
+# model/layers names the stack's own work — slicing a layer's weights out of
+# the stacked leaves, writing and reading the scan's stacked residuals, the
+# residual adds; the blocks inside name themselves
+@jax.named_scope("model/layers")
 def body_apply(cfg: ModelConfig, layers: Dict, h: jax.Array,
                tp_axis: Optional[str] = None, tp_size: int = 1,
                rng: Optional[jax.Array] = None,
@@ -346,6 +368,7 @@ def head_norm_apply(cfg: ModelConfig, head: Dict, h: jax.Array) -> jax.Array:
     return layer_norm_apply(head["norm"], h)
 
 
+@jax.named_scope("model/head_loss")
 def head_apply(cfg: ModelConfig, head: Dict, h: jax.Array,
                embed: Optional[Dict] = None) -> jax.Array:
     hn = head_norm_apply(cfg, head, h)
@@ -386,9 +409,10 @@ def transformer_loss(cfg: ModelConfig, params: Dict, tokens: jax.Array,
     SURVEY.md §4). With ``cfg.pad_token_id`` set, pad targets are ignored
     and the mean divides by the valid count."""
     logits = transformer_apply(cfg, params, tokens, rng=rng)
-    if cfg.pad_token_id is not None:
-        from ..ops.layers import select_masked_xent_sum
-        s, n = select_masked_xent_sum(cfg.use_fused_xent)(
-            logits, targets, cfg.pad_token_id)
-        return s / jnp.maximum(n, 1)
-    return select_xent(cfg.use_fused_xent)(logits, targets)
+    with jax.named_scope("model/head_loss"):  # the head's half: head_apply
+        if cfg.pad_token_id is not None:
+            from ..ops.layers import select_masked_xent_sum
+            s, n = select_masked_xent_sum(cfg.use_fused_xent)(
+                logits, targets, cfg.pad_token_id)
+            return s / jnp.maximum(n, 1)
+        return select_xent(cfg.use_fused_xent)(logits, targets)

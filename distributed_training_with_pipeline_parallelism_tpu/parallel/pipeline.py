@@ -262,30 +262,38 @@ def _stage_ce_impl(cfg, head_p, embed_p, y, tgt, *, tp_axis, T,
     stage-0 lookup grad stays unwrapped (it is computed replicated, so a
     psum would T-fold it)."""
     if tp_vocab_parallel:
-        # Megatron parallel CE: head matmul column-split over 'model'; the
-        # [mb, s, V] logits never materialize.
-        from ..ops.collectives import (tp_copy, vocab_parallel_masked_xent_sum,
-                                       vocab_parallel_xent)
-        yn = tp_copy(head_norm_apply(cfg, head_p, y), tp_axis)
-        if cfg.tie_embeddings:
-            v_loc = cfg.vocab_size // T
-            my = jax.lax.axis_index(tp_axis)
-            tok = tp_copy(embed_p["tok"], tp_axis)
-            w_loc = jax.lax.dynamic_slice_in_dim(tok, my * v_loc, v_loc, 0)
-            logits_local = yn @ w_loc.T
-        else:
-            logits_local = linear_apply(head_p["out"], yn)
+        with jax.named_scope("model/head_loss"):
+            return _vocab_parallel_ce(cfg, head_p, embed_p, y, tgt, tp_axis,
+                                      T, pad_scale, loss_norm)
+    logits = head_apply(cfg, head_p, y, embed=embed_p)  # sets the scope too
+    with jax.named_scope("model/head_loss"):
         if cfg.pad_token_id is not None:
-            s, _ = vocab_parallel_masked_xent_sum(
-                logits_local, tgt, tp_axis, cfg.pad_token_id)
+            s, _ = select_masked_xent_sum(cfg.use_fused_xent)(
+                logits, tgt, cfg.pad_token_id)
             return s * pad_scale  # scale absorbs loss_norm
-        return vocab_parallel_xent(logits_local, tgt, tp_axis) / loss_norm
-    logits = head_apply(cfg, head_p, y, embed=embed_p)
+        return select_xent(cfg.use_fused_xent)(logits, tgt) / loss_norm
+
+
+def _vocab_parallel_ce(cfg, head_p, embed_p, y, tgt, tp_axis, T, pad_scale,
+                       loss_norm):
+    """Megatron parallel CE: head matmul column-split over 'model'; the
+    [mb, s, V] logits never materialize."""
+    from ..ops.collectives import (tp_copy, vocab_parallel_masked_xent_sum,
+                                   vocab_parallel_xent)
+    yn = tp_copy(head_norm_apply(cfg, head_p, y), tp_axis)
+    if cfg.tie_embeddings:
+        v_loc = cfg.vocab_size // T
+        my = jax.lax.axis_index(tp_axis)
+        tok = tp_copy(embed_p["tok"], tp_axis)
+        w_loc = jax.lax.dynamic_slice_in_dim(tok, my * v_loc, v_loc, 0)
+        logits_local = yn @ w_loc.T
+    else:
+        logits_local = linear_apply(head_p["out"], yn)
     if cfg.pad_token_id is not None:
-        s, _ = select_masked_xent_sum(cfg.use_fused_xent)(
-            logits, tgt, cfg.pad_token_id)
+        s, _ = vocab_parallel_masked_xent_sum(
+            logits_local, tgt, tp_axis, cfg.pad_token_id)
         return s * pad_scale  # scale absorbs loss_norm
-    return select_xent(cfg.use_fused_xent)(logits, tgt) / loss_norm
+    return vocab_parallel_xent(logits_local, tgt, tp_axis) / loss_norm
 
 
 def _check_tp_divisibility(cfg: ModelConfig, T: int) -> None:
@@ -1889,9 +1897,7 @@ def make_pipeline_step(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig,
     schedule's microbatch program; WHICH executor formulation runs it is
     chosen by ``remat_backward`` (see :func:`make_pipeline_grad_fn` — at
     D == 1 the default is the unrolled stored program; pass
-    ``remat_backward=True`` for the rematerializing tick scan, as
-    ``utils.profiling.measure_bubble`` does for its cost-matched
-    comparator).
+    ``remat_backward=True`` for the rematerializing tick scan).
 
     ``unroll_ticks`` picks the tick-loop form (full detail and measured
     compile-time economics in :func:`make_pipeline_grad_fn`): ``True``

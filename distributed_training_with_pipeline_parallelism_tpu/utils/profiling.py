@@ -1,4 +1,5 @@
-"""Profiling and pipeline-bubble measurement.
+"""Profiling: the profiler's context managers, and the names of the step's
+regions.
 
 The reference's only instrumentation is ``time.time()`` around the timed loop
 (SURVEY.md §5 tracing row; upstream's ``record_function`` blocks are never
@@ -6,33 +7,25 @@ collected). Here:
 
 - :func:`trace` wraps ``jax.profiler.trace`` — traces open in
   XProf/TensorBoard with per-op device timelines (the honest way to see
-  bubbles on real hardware).
-- :func:`measure_bubble` derives an end-to-end *measured* bubble fraction
-  from wall-clocks, no profiler needed: a perfectly pipelined D-stage run
-  would take ``t_single / D`` per step (same total FLOPs, spread over D
-  chips); the measured bubble is the shortfall from that ideal,
-  ``1 - t_single / (D * t_pipe)``. Comparable to the analytic
-  ``(D-1)/(M+D-1)`` and the tick-simulated fraction
-  (:func:`..parallel.schedules.simulated_bubble`) — the BASELINE.json
-  north-star asks for measured-vs-analytic agreement.
-
-Note the measured number also absorbs communication and remat overhead, so
-it upper-bounds the pure schedule bubble; the gap between measured and
-simulated (w_b=3) is the transport+overhead cost.
-
-Caveat for simulated (CPU) meshes: the measurement assumes the D mesh
-devices actually run in parallel. On a host with fewer cores than devices
-the "parallel" ticks serialize and ``bubble_measured`` degenerates toward
-``1 - 1/D`` regardless of schedule (docs/performance.md §bubbles) — use
-the tick simulation for schedule comparisons there, and reserve this
-function for real multi-chip slices.
+  bubbles on real hardware); :func:`annotate` puts a host span on the same
+  clock, :func:`annotated_steps` a step number around a loop's iterations.
+- :data:`REGIONS` is the fixed vocabulary of ``jax.named_scope`` names the
+  program sets where the work is written (``models/transformer.py``,
+  ``utils/train.py``; the executors' ``pp/...`` beside them), and
+  :func:`classify` reads an instruction's ``op_name`` — as the compiled
+  step's text carries it in ``metadata={op_name="..."}`` — back into
+  ``(phase, region)``. A device-trace event is named by its instruction, so
+  instruction -> compiled text -> ``op_name`` -> :func:`classify` splits a
+  trace of any step by forward / backward / recompute / optimizer and by
+  attention / MLP / head (``benchmark/harness/scopes.py`` does the join;
+  docs/observability.md has the reading guide).
 """
 
 from __future__ import annotations
 
 import contextlib
-import time
-from typing import Dict, Optional
+import re
+from typing import Iterable, Iterator, Tuple
 
 import jax
 
@@ -63,64 +56,88 @@ def annotate(name: str):
         yield
 
 
-def _time_fn(fn, *args, iters: int = 5, warmup: int = 2) -> float:
-    from .metrics import force_completion
-    out = None
-    for _ in range(warmup):
-        out = fn(*args)
-    force_completion(out)
-    t0 = time.perf_counter()
-    for _ in range(iters):
-        out = fn(*args)
-    force_completion(out)
-    return (time.perf_counter() - t0) / iters
+def annotated_steps(steps: Iterable[int],
+                    name: str = "train") -> Iterator[int]:
+    """Iterate ``steps`` with the body of each iteration under
+    ``jax.profiler.StepTraceAnnotation(name, step_num=i)``, so a trace's
+    host plane (and XProf's step view) knows which step issued what. The
+    annotation closes when the loop asks for the next step, breaks, or drops
+    the iterator. No-op cost when no profiler session is active."""
+    for i in steps:
+        with jax.profiler.StepTraceAnnotation(name, step_num=i):
+            yield i
 
 
-def measure_bubble(cfg, mesh, sched, batch_size: int = 32,
-                   seq_length: int = 128, iters: int = 5,
-                   seed: int = 0) -> Dict[str, float]:
-    """Measured vs analytic vs simulated bubble for one config.
+# --------------------------------------------------------------------------
+# The step's regions, and how an op_name is read back into one
 
-    Runs the pipeline step on the mesh and an equivalent single-device step
-    (same model, same microbatch gradient accumulation via a GPipe program on
-    a 1-device mesh, so remat costs cancel out of the comparison), then
-    reports ``bubble_measured = 1 - t_single / (D * t_pipe)``.
-    """
-    from ..models.transformer import transformer_init
-    from ..parallel.mesh import make_mesh
-    from ..parallel.pipeline import make_pipeline_step
-    from ..parallel.schedules import (analytic_bubble_fraction,
-                                      compile_schedule, simulated_bubble)
-    from ..utils.config import ScheduleConfig
+#: ``jax.named_scope`` names set where the work is written:
+#: ``models/transformer.py`` (the ``model/`` ones, at the function every
+#: path shares: ``embed_apply``, ``body_apply`` — the layer stack's own
+#: weight slices, stacked residuals and residual adds —, ``layer_apply``'s
+#: attention half, ``mlp_block``, ``head_apply`` and the loss) and
+#: ``utils/train.py:make_train_step`` (the optax update). The executors'
+#: ``pp/...`` scopes (``parallel/pipeline.py``) stay as they are; they are
+#: regions too, wherever no name of this list is further in.
+REGIONS = ("model/embed", "model/layers", "model/attn", "model/mlp",
+           "model/head_loss", "train/optimizer")
+UNSCOPED = "unscoped"
 
-    D = mesh.shape["pipe"]
-    params = transformer_init(jax.random.key(seed), cfg)
-    kx, ky = jax.random.split(jax.random.key(seed + 1))
-    tokens = jax.random.randint(kx, (batch_size, seq_length), 0, cfg.vocab_size)
-    targets = jax.random.randint(ky, (batch_size, seq_length), 0, cfg.vocab_size)
+#: In this order, the first mark an op_name holds gives its phase. JAX writes
+#: the last two itself (``jax.checkpoint``'s second run of a function, and
+#: the transposed half of a ``jvp``); anything else under a region is
+#: ``forward``.
+PHASE_MARKS = (("train/optimizer", "optimizer"),
+               ("rematted_computation", "recompute"),
+               ("transpose(", "backward"))
 
-    pipe_step = make_pipeline_step(cfg, mesh, sched)
-    t_pipe = _time_fn(pipe_step, params, tokens, targets, iters=iters)
+_REGION = re.compile("|".join(re.escape(r) for r in REGIONS))
+_PP = re.compile(r"pp/[A-Za-z_]+")  # pp/tick003 -> pp/tick, pp/phase2 -> pp/phase
+# The tick executors' backward units: each re-runs its stage's forward by
+# hand (``jax.vjp`` inside the tick), which JAX marks ``jvp(``, not
+# ``rematted_computation``. Read off the D=2 executor's compiled text: under
+# these scopes ``jvp(pp/stage_body)/..`` and ``jvp(pp/embed)/..`` are the
+# second forward, ``transpose(jvp(..))`` the backward. The head and its loss
+# are the exception: their ONLY forward run is inside the last stage's
+# backward unit (``stage_objective``), so it stays ``forward``.
+_BACKWARD_UNIT = re.compile(r"pp/(bwd_dgrad|bwd|wgrad)(?![A-Za-z_])")
 
-    single_mesh = make_mesh(n_pipe=1, devices=list(mesh.devices.flat)[:1])
-    single_sched = ScheduleConfig(name="GPipe",
-                                  n_microbatches=sched.n_microbatches)
-    # force the tick executor AND the rematerializing backward so the
-    # comparator pays the same per-unit costs as the D-device pipeline run
-    # (the degenerate fast path skips remat entirely, and the D=1 default
-    # is the unrolled stored program — either would skew the ratio)
-    single_step = make_pipeline_step(cfg, single_mesh, single_sched,
-                                     force_tick_executor=True,
-                                     remat_backward=True)
-    t_single = _time_fn(single_step, params, tokens, targets, iters=iters)
 
-    cs = compile_schedule(sched.name, D, sched.n_virtual, sched.n_microbatches)
-    return {
-        "t_pipeline": t_pipe,
-        "t_single_device": t_single,
-        "bubble_measured": 1.0 - t_single / (D * t_pipe),
-        "bubble_analytic": analytic_bubble_fraction(
-            sched.name, D, sched.n_virtual, sched.n_microbatches, cs=cs),
-        "bubble_simulated": simulated_bubble(cs, w_f=1.0, w_b=3.0)[
-            "bubble_fraction"],
-    }
+def _region(part: str):
+    found = _REGION.findall(part)
+    if found:
+        return found[-1]  # the innermost
+    found = _PP.findall(part)
+    return found[-1] if found else None
+
+
+def _phase(part: str, region) -> str:
+    for mark, phase in PHASE_MARKS:
+        if mark in part:
+            return phase
+    if region != "model/head_loss" and _BACKWARD_UNIT.search(part):
+        return "recompute"
+    if region is not None or "jvp(" in part:
+        return "forward"
+    return "other"
+
+
+def classify(op_name: str) -> Tuple[str, str]:
+    """``(phase, region)`` of one instruction, from the ``op_name`` its
+    compiled HLO carries (``jit(train_step)/transpose(jvp())/while/body/
+    closed_call/checkpoint/model/mlp/dot_general``).
+
+    ``region`` is the innermost name of :data:`REGIONS` in it, else the
+    innermost ``pp/...`` scope (tick and phase numbers dropped), else
+    ``"unscoped"``. ``phase`` is ``optimizer``, ``recompute``, ``backward``
+    or ``forward`` by :data:`PHASE_MARKS` and the rule for the executors'
+    backward units above; an op outside every region still gets the phase
+    JAX's own marks give it (``jvp(`` alone is forward), and ``other`` only
+    where the name holds no mark at all. A fusion that merged several
+    sources carries ``a;b``: the first part that has a region is read."""
+    parts = op_name.split(";")
+    for part in parts:
+        region = _region(part)
+        if region is not None:
+            return _phase(part, region), region
+    return _phase(parts[0], None), UNSCOPED

@@ -30,6 +30,7 @@ from ..parallel.pipeline import (make_pipeline_grad_fn, model_init,
 from .checkpoint import restore_checkpoint, save_checkpoint
 from .config import ModelConfig, ScheduleConfig
 from .dynamics import as_dynamics_config, nonfinite_per_stage, stage_stats
+from .profiling import annotate, annotated_steps
 
 Pytree = Any
 
@@ -118,9 +119,14 @@ def make_train_step(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig,
     resting = param_shardings(cfg, mesh, moe=moe, fsdp=fsdp,
                               tp_vocab_parallel=tp_vocab_parallel)
 
-    def apply_updates(params, updates):
-        return jax.lax.with_sharding_constraint(
-            optax.apply_updates(params, updates), resting)
+    def update(grads, opt_state, params):
+        """(new params, new opt_state, updates): the optax update and its
+        application, the step's ``train/optimizer`` region."""
+        with jax.named_scope("train/optimizer"):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            new_params = jax.lax.with_sharding_constraint(
+                optax.apply_updates(params, updates), resting)
+        return new_params, opt_state, updates
 
     nan_steps = tuple(getattr(fault_plan, "nan_grad_steps", ()) or ())
     nan_stage = getattr(fault_plan, "nan_grad_stage", None)
@@ -157,20 +163,17 @@ def make_train_step(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig,
                                            targets, rng):
                     loss, grads, sq_mb = run_grads(params, tokens, targets,
                                                    rng)
-                    updates, opt_state = optimizer.update(grads, opt_state,
-                                                          params)
+                    new_params, opt_state, updates = update(
+                        grads, opt_state, params)
                     dyn = dyn_stats(grads, params, updates, sq_mb)
-                    params = apply_updates(params, updates)
-                    return params, opt_state, loss, dyn
+                    return new_params, opt_state, loss, dyn
 
                 return train_step_dropout_dyn
 
             @_jit_step
             def train_step_dropout(params, opt_state, tokens, targets, rng):
                 loss, grads = grad_fn(params, tokens, targets, rng)
-                updates, opt_state = optimizer.update(grads, opt_state,
-                                                      params)
-                params = apply_updates(params, updates)
+                params, opt_state, _ = update(grads, opt_state, params)
                 return params, opt_state, loss
 
             return train_step_dropout
@@ -179,19 +182,17 @@ def make_train_step(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig,
             @_jit_step
             def train_step_dyn(params, opt_state, tokens, targets):
                 loss, grads, sq_mb = run_grads(params, tokens, targets, None)
-                updates, opt_state = optimizer.update(grads, opt_state,
-                                                      params)
+                new_params, opt_state, updates = update(grads, opt_state,
+                                                        params)
                 dyn = dyn_stats(grads, params, updates, sq_mb)
-                params = apply_updates(params, updates)
-                return params, opt_state, loss, dyn
+                return new_params, opt_state, loss, dyn
 
             return train_step_dyn
 
         @_jit_step
         def train_step(params, opt_state, tokens, targets):
             loss, grads = grad_fn(params, tokens, targets)
-            updates, opt_state = optimizer.update(grads, opt_state, params)
-            params = apply_updates(params, updates)
+            params, opt_state, _ = update(grads, opt_state, params)
             return params, opt_state, loss
 
         return train_step
@@ -242,8 +243,7 @@ def make_train_step(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig,
             grads_ok,
             jnp.where(loss_ok, jnp.int32(-1), jnp.int32(-2)),
             jnp.argmax(~stage_ok).astype(jnp.int32))
-        updates, new_opt = optimizer.update(grads, opt_state, params)
-        new_params = apply_updates(params, updates)
+        new_params, new_opt, updates = update(grads, opt_state, params)
         dyn = (dyn_stats(grads, params, updates, sq_mb)
                if dcfg is not None else None)
 
@@ -635,8 +635,9 @@ def fit(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig, params: Pytree,
                 report.event("resumed", step=n, path=path)
 
     def _save(i, wait=True):
-        mgr.save(i, {"params": params, "opt_state": opt_state,
-                     "step": jnp.asarray(i)}, wait=wait)
+        with annotate("checkpoint_save"):
+            mgr.save(i, {"params": params, "opt_state": opt_state,
+                         "step": jnp.asarray(i)}, wait=wait)
 
     guard_state = init_guard_state(start_step) if guard is not None else None
     guard_seen = 0  # anomalies already surfaced (host high-water mark)
@@ -675,7 +676,8 @@ def fit(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig, params: Pytree,
                                fsdp=fsdp)
 
     def _eval(i):
-        m = evaluate(eval_fn, params, eval_data(), eval_batches)
+        with annotate("eval"):
+            m = evaluate(eval_fn, params, eval_data(), eval_batches)
         if verbose:
             print(f"step {i}: eval_loss {m['eval_loss']:.4f} "
                   f"ppl {m['perplexity']:.2f}", flush=True)
@@ -794,7 +796,13 @@ def fit(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig, params: Pytree,
     prof_stop = start_step + max(profile_steps[1], profile_steps[0] + 1)
     try:
         with preempt:
-            for i in range(start_step, num_steps):
+            # Host spans for a --profile-dir trace, on the device trace's
+            # clock: input_wait / dispatch / wait_loss here (the names the
+            # benchmark's own loop uses, so an idle gap of the device is
+            # named the same way in either), eval and checkpoint_save above;
+            # each iteration under a StepTraceAnnotation. All no-ops
+            # without a profiler session.
+            for i in annotated_steps(range(start_step, num_steps)):
                 if fault_plan is not None and fault_plan.preempt_at_step == i:
                     preempt.trigger()  # deterministic stand-in for SIGTERM
                 if profile_dir is not None:
@@ -807,7 +815,8 @@ def fit(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig, params: Pytree,
                         if verbose:
                             print(f"profile trace written to {profile_dir}",
                                   flush=True)
-                tokens, targets = next(data)
+                with annotate("input_wait"):
+                    tokens, targets = next(data)
                 if data_shape is None:
                     data_shape = (int(tokens.shape[0]), int(tokens.shape[1]))
                 if recorder is not None:
@@ -817,7 +826,7 @@ def fit(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig, params: Pytree,
                 # compile_s timer brackets it (forced, so the timer is honest)
                 first = report is not None and i == start_step
                 with (report.timer("compile_s") if first
-                      else contextlib.nullcontext()):
+                      else contextlib.nullcontext()), annotate("dispatch"):
                     args = (params, opt_state, tokens, targets)
                     if drop_key is not None:
                         args += (jax.random.fold_in(drop_key, i),)
@@ -838,7 +847,9 @@ def fit(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig, params: Pytree,
                     watchdog.beat(i)
                 window_tokens += tokens.shape[0] * tokens.shape[1]
                 if i % log_every == 0 or i == num_steps - 1:
-                    loss_f = float(loss)  # device sync: closes the timing window
+                    with annotate("wait_loss"):
+                        # device sync: closes the timing window
+                        loss_f = float(loss)
                     elapsed = time.perf_counter() - window_start
                     history.append((i, loss_f))
                     if verbose:
