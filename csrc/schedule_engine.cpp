@@ -1,9 +1,10 @@
 // Native schedule-compilation engine.
 //
 // C++ twin of parallel/schedules.py: per-device action-order generation for
-// GPipe / 1F1B / Interleaved-1F1B / ZB-H1, ASAP tick scheduling with one-hop
-// ppermute latency, greedy buffer-slot allocation from activation lifetimes,
-// and emission of the executor tick table [T, D, 13] (column layout
+// GPipe / 1F1B / Interleaved-1F1B / ZB-H1, ASAP tick scheduling (a forward
+// and a full backward may share a device's tick) with one-hop ppermute latency,
+// greedy buffer-slot allocation from activation lifetimes, and emission of
+// the executor tick table [T, D, 17] (column layout
 // documented in schedules.py). Semantics must match the Python implementation
 // exactly — tests assert bit-identical tables — so the Python path remains
 // the executable specification and this library is the fast production path
@@ -56,10 +57,13 @@ std::vector<Order> gpipe_order(int D, int M) {
   return orders;
 }
 
+// Warm-up 2 * (D-1-d): a hop costs one tick each way, so that many forwards
+// are in flight before B(d, 0) can run, and every steady-state F, B pair
+// shares a tick (schedules.one_f_one_b_order says why).
 std::vector<Order> one_f_one_b_order(int D, int M) {
   std::vector<Order> orders(D);
   for (int d = 0; d < D; ++d) {
-    int warmup = std::min(M, D - 1 - d);
+    int warmup = std::min(M, 2 * (D - 1 - d));
     int nf = 0, nb = 0;
     for (; nf < warmup; ++nf) orders[d].push_back({d, OP_F, nf});
     while (nf < M) {
@@ -284,7 +288,13 @@ int dtpp_compile_schedule(const char* name, int D, int V, int M,
   std::map<Action, int> done;
   std::vector<size_t> ptr(D, 0);
   int n_actions = 0;
-  for (const auto& o : orders) n_actions += o.size();
+  // a split-backward order (any W) keeps one unit per device per tick: its
+  // synthesis fills every unit tick already (schedules.schedule_ticks)
+  bool split = false;
+  for (const auto& o : orders) {
+    n_actions += o.size();
+    for (const Action& a : o) split = split || a.op == OP_W;
+  }
   const int limit = 4 * n_actions + 4 * S + 16;
   int t = 0;
   auto pending = [&]() {
@@ -295,36 +305,43 @@ int dtpp_compile_schedule(const char* name, int D, int V, int M,
   while (pending()) {
     if (t > limit) return fail(err, errlen, "schedule deadlocked");
     for (int d = 0; d < D; ++d) {
-      if (ptr[d] >= orders[d].size()) continue;
-      const Action& a = orders[d][ptr[d]];
-      bool ready;
-      if (a.op == OP_F) {
-        if (a.stage == 0) {
-          ready = true;
-        } else {
-          auto it = done.find({a.stage - 1, OP_F, a.mb});
-          ready = it != done.end() && it->second + 1 <= t;
-        }
-      } else if (a.op == OP_W) {
-        ready = done.count({a.stage, OP_F, a.mb}) > 0;
-        if (ready) {
+      // a device takes, in one tick, its next forward and its next full
+      // backward, in list order: the F and B slots of a table row (a
+      // packed tick)
+      bool taken[3] = {false, false, false};
+      while (ptr[d] < orders[d].size()) {
+        const Action& a = orders[d][ptr[d]];
+        if (taken[a.op]) break;
+        bool ready;
+        if (a.op == OP_F) {
           if (a.stage == 0) {
-            auto it = done.find({1, OP_B, a.mb});
+            ready = true;
+          } else {
+            auto it = done.find({a.stage - 1, OP_F, a.mb});
             ready = it != done.end() && it->second + 1 <= t;
-          } else if (a.stage != S - 1) {
-            ready = done.count({a.stage, OP_B, a.mb}) > 0;
+          }
+        } else if (a.op == OP_W) {
+          ready = done.count({a.stage, OP_F, a.mb}) > 0;
+          if (ready) {
+            if (a.stage == 0) {
+              auto it = done.find({1, OP_B, a.mb});
+              ready = it != done.end() && it->second + 1 <= t;
+            } else if (a.stage != S - 1) {
+              ready = done.count({a.stage, OP_B, a.mb}) > 0;
+            }
+          }
+        } else {  // OP_B
+          ready = done.count({a.stage, OP_F, a.mb}) > 0;
+          if (ready && a.stage != S - 1) {
+            auto it = done.find({a.stage + 1, OP_B, a.mb});
+            ready = it != done.end() && it->second + 1 <= t;
           }
         }
-      } else {  // OP_B
-        ready = done.count({a.stage, OP_F, a.mb}) > 0;
-        if (ready && a.stage != S - 1) {
-          auto it = done.find({a.stage + 1, OP_B, a.mb});
-          ready = it != done.end() && it->second + 1 <= t;
-        }
-      }
-      if (ready) {
+        if (!ready) break;
         done[a] = t;
+        taken[a.op] = true;
         ++ptr[d];
+        if (split) break;
       }
     }
     ++t;

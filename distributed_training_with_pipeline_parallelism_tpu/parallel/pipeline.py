@@ -729,10 +729,14 @@ def make_pipeline_grad_fn(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig,
       other schedules bank the stage body's ``jax.vjp`` residuals in
       slot-addressed buffers (x-independent residuals — weights, casts,
       RoPE — are re-derived live instead of stored, see
-      :mod:`.stored_backward`). Raises on configurations that cannot
-      support it (split-backward schedules, whose W units re-derive
-      parameter grads by design; ``fsdp=True``, where residuals would pin
-      the just-in-time-gathered full weights).
+      :mod:`.stored_backward`). Those banks have ``cs.n_act_slots``
+      entries, and 1F1B's packed table keeps ``min(M, 2D-1)`` microbatches
+      in flight on stage 0 (7 at D = 4, where the unpacked table of before
+      PR 29 kept 4): the stored backward's memory grows with it, the
+      default's only by that many stage inputs. Raises on configurations
+      that cannot support it (split-backward schedules, whose W units
+      re-derive parameter grads by design; ``fsdp=True``, where residuals
+      would pin the just-in-time-gathered full weights).
 
     ``unroll_ticks`` selects the tick-executor formulation (docs/
     performance.md "Executor formulations"); it changes the loop form
@@ -861,6 +865,7 @@ def make_pipeline_grad_fn(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig,
     # schedule units; Ulysses' all_to_all is grouped, so its units may keep
     # the efficient cond dispatch.
     uniform_units = sp_axis is not None and sp_attn_impl == "ring"
+    units_hold_collectives = T > 1 or n_seq > 1 or n_ep > 1 or fsdp
     _check_tp_divisibility(cfg, T)
     ep_axis = EXPERT_AXIS if n_ep > 1 else None
     if n_ep > 1 and moe is None:
@@ -1005,6 +1010,11 @@ def make_pipeline_grad_fn(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig,
             return out
 
         return instrumented
+    n_rows = cs.table.shape[0]
+    logger.info(
+        "pipeline: %s D=%d V=%d M=%d tick table: %d rows, %d of %d cells "
+        "work, %d of them packed (more than one unit in the tick)",
+        cs.name, D, V, M, n_rows, cs.work_cells, n_rows * D, cs.packed)
     if unroll_ticks is None:
         # auto: unroll small tables (straight-line specialization, ~2.2 s
         # compile per row); beyond the budget the PHASE-COMPRESSED form —
@@ -1345,6 +1355,21 @@ def make_pipeline_grad_fn(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig,
             def ccol(col):
                 return None if concrete is None else concrete[:, col]
 
+            # What the concrete row says of each unit across the pipe axis
+            # (True: every device takes it, so its cond goes). A packed
+            # tick can hold one unit every device takes beside one only
+            # some take; where units hold collectives of their own (tensor,
+            # sequence or expert axes, fsdp's gathers) the first then keeps
+            # its cond as well: XLA:CPU runs a conditional whose branch
+            # holds collectives concurrently with top-level collectives
+            # that do not depend on it, and its in-process rendezvous then
+            # deadlocks (every Ulysses 1F1B test of tests/test_sp_pipeline.py
+            # did, most runs). One-unit-a-tick tables never mixed the two.
+            knows = {c: _concrete_know(ccol(c))
+                     for c in (COL_FWD_M, COL_BWD_M, COL_W_M)}
+            if units_hold_collectives and None in knows.values():
+                knows = {c: None if k else k for c, k in knows.items()}
+
             def store(buf, val, col):
                 # unrolled: a row block that banks nowhere skips the
                 # masked dynamic-update-slice entirely
@@ -1422,7 +1447,7 @@ def make_pipeline_grad_fn(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig,
                     (act_buf, res_bufs, loss_acc), fwd_send = run_unit(
                         fm >= 0, fwd_unit, fwd_noop,
                         (act_buf, res_bufs, loss_acc),
-                        know=_concrete_know(ccol(COL_FWD_M)))
+                        know=knows[COL_FWD_M])
             else:
                 def fwd_unit(act_buf):
                     vv, mm = jnp.maximum(fv, 0), jnp.maximum(fm, 0)
@@ -1441,12 +1466,28 @@ def make_pipeline_grad_fn(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig,
                 with jax.named_scope("pp/fwd"):
                     act_buf, fwd_send = run_unit(
                         fm >= 0, fwd_unit, fwd_noop, act_buf,
-                        know=_concrete_know(ccol(COL_FWD_M)))
+                        know=knows[COL_FWD_M])
             if reverse_routes:
                 # same-device hop (vshape's V turning point): the output IS
                 # the next chunk's input — bank it locally, no ring transit
                 act_buf = store(act_buf, fwd_send, COL_FWD_LOCAL_SLOT)
             act_buf, grad_buf = bank_now(BANK_BEFORE_B, act_buf, grad_buf)
+            if (knows[COL_FWD_M] is not False
+                    and knows[COL_BWD_M] is not False):
+                # A packed tick runs its forward, THEN its backward: the two
+                # units are independent (F(m+k) beside B(m)), and where
+                # neither sits in a cond the compiler interleaves them and
+                # keeps both units' temporaries live together — gpt2-xl
+                # D=4 then needs 16.53 GB of a v5e's 15.75 and is refused
+                # (14.88 GB with the fence). The gradient accumulators every
+                # backward-side unit adds into pass the fence with the
+                # forward's outputs, so no such unit starts before the
+                # forward has finished.
+                ((act_buf, fwd_send, res_bufs),
+                 (g_layers, g_embed, g_head, loss_acc)) = (
+                    jax.lax.optimization_barrier(
+                        ((act_buf, fwd_send, res_bufs),
+                         (g_layers, g_embed, g_head, loss_acc))))
 
             # 3. backward unit (rematerializing)
             bv, bm = row[COL_BWD_V], row[COL_BWD_M]
@@ -1475,7 +1516,7 @@ def make_pipeline_grad_fn(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig,
                 with jax.named_scope("pp/bwd_dgrad"):
                     loss_acc, bwd_send = run_unit(
                         bm >= 0, dgrad_unit, dgrad_noop, loss_acc,
-                        know=_concrete_know(ccol(COL_BWD_M)))
+                        know=knows[COL_BWD_M])
                 if reverse_routes:
                     grad_buf = store(grad_buf, bwd_send, COL_BWD_LOCAL_SLOT)
                 act_buf, grad_buf = bank_now(BANK_BEFORE_W, act_buf,
@@ -1540,7 +1581,7 @@ def make_pipeline_grad_fn(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig,
                         (sq_mb,) if dyn else ())
                     w_out = run_unit(
                         wm >= 0, wgrad_unit, lambda operand: operand, w_op,
-                        know=_concrete_know(ccol(COL_W_M)))
+                        know=knows[COL_W_M])
                     if dyn:
                         g_layers, g_embed, g_head, sq_mb = w_out
                     else:
@@ -1698,7 +1739,7 @@ def make_pipeline_grad_fn(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig,
                     bm >= 0,
                     bwd_unit_stored if use_stored else bwd_unit_remat,
                     bwd_noop, b_op,
-                    know=_concrete_know(ccol(COL_BWD_M)))
+                    know=knows[COL_BWD_M])
                 if dyn:
                     g_layers, g_embed, g_head, loss_acc, sq_mb = b_out
                 else:

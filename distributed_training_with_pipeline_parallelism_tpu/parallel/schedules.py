@@ -15,9 +15,12 @@ single-program SPMD executor:
    v = stage // n_devices — the reference's wrap placement
    ``stage_idx = rank + world_size * i``, ``LLMsDistributedTrainingHelper.py:208``).
 2. **Tick scheduling** — an ASAP list scheduler assigns each action to a
-   discrete tick: one compute action per device per tick, actions execute
-   in list order per device, and a cross-device data dependency costs one
-   tick of transfer latency (the ``ppermute`` hop).
+   discrete tick: a device takes its actions in list order, its next
+   forward and its next full backward in the same tick where both are ready
+   — a row's F and B slots, so a steady 1F1B stage runs its forward and its
+   backward in ONE tick; split-backward (F/B/W) orders keep one unit a tick
+   — and a cross-device data dependency costs one tick of transfer latency
+   (the ``ppermute`` hop).
 3. **Tick tables** — dense int32 arrays the SPMD executor scans over; every
    entry is static, so the whole schedule compiles into one XLA program with
    no data-dependent control flow.
@@ -130,15 +133,26 @@ def gpipe_order(n_devices: int, n_microbatches: int) -> List[List[Action]]:
 
 
 def one_f_one_b_order(n_devices: int, n_microbatches: int) -> List[List[Action]]:
-    """1F1B: per-device warmup of (D-1-d) forwards, steady-state alternating
-    F/B, cooldown backwards (SURVEY.md U3; upstream requires M >= D,
-    ``schedules.py:1020-1024`` — enforced here too)."""
+    """1F1B: per-device warmup of ``2 * (D-1-d)`` forwards, steady-state
+    alternating F/B, cooldown backwards (SURVEY.md U3; upstream requires
+    M >= D, ``schedules.py:1020-1024`` — enforced here too).
+
+    The warm-up is twice upstream's ``D-1-d`` because of what a hop costs
+    on the tick executor: one tick each way. A microbatch's round trip from
+    stage ``d`` to the last stage and back takes ``2 * (D-1-d)`` ticks, so
+    that many forwards must be in flight before ``B(d, 0)`` can run — the
+    depth :func:`interleaved_order` already uses. With it
+    :func:`schedule_ticks` packs every steady-state ``F, B`` pair into one
+    tick, all stages are in steady state together, and a lock-step tick
+    costs f + b on every device: ``M + 2(D-1)`` rows at the async runtime's
+    cost ``(M + D - 1)(f + b)``. The price is ``min(M, 2D-1)`` stage inputs
+    in flight on stage 0 instead of ``D``."""
     D, M = n_devices, n_microbatches
     if M < D:
         raise ScheduleError(f"1F1B requires n_microbatches >= n_devices ({M} < {D})")
     orders = []
     for d in range(D):
-        warmup = min(M, D - 1 - d)
+        warmup = min(M, 2 * (D - 1 - d))
         acts = [Action(d, F, m) for m in range(warmup)]
         nf, nb = warmup, 0
         while nf < M:  # steady state: one forward, one backward
@@ -458,12 +472,26 @@ def schedule_ticks(orders: List[List[Action]], n_devices: int, n_virtual: int,
                    placement: str = "wrap") -> Tuple[Dict[Action, int], int]:
     """Assign each action a tick. Returns (action -> tick, makespan).
 
-    Rules: one action per device per tick; per-device actions run in list
-    order; F(s, m) needs F(s-1, m) completed >= 1 tick earlier when the stages
-    live on different devices (ppermute latency), B(s, m) needs F(s, m) (same
-    device, activations saved locally) and B(s+1, m) >= 1 tick earlier.
-    (Same-device inter-stage transfers — vshape's s=D-1 -> D hop — need only
-    ``done + 1 <= now`` too, which one-action-per-tick already implies.)
+    Rules: per-device actions are placed in list order; a device takes, in
+    one tick, its next forward and its next full backward — the F and the B
+    slot every row of the tick table has, which the executor runs in that
+    order whatever the list order was (a *packed* tick). F(s, m) needs
+    F(s-1, m) completed >= 1 tick earlier (ppermute latency; a same-device
+    inter-stage transfer — vshape's s=D-1 -> D hop — is held to the same
+    rule), B(s, m) needs F(s, m) (same device, input saved locally: the
+    same tick will do, the F slot runs first — the last stage's F(m) and
+    B(m) share a tick) and B(s+1, m) >= 1 tick earlier. A slot is reusable
+    only from ``release_tick + 1``, so a forward packed beside a backward
+    listed before it never overwrites what that backward reads.
+
+    Split-backward orders (any ``W`` in them) keep ONE unit per device per
+    tick. Their synthesis (:func:`_zb_greedy_order`) already fills every
+    unit tick of every device — that is what meets the papers' makespans —
+    and a hop costs a tick however long the tick is, so packing them
+    lengthens the ticks along the dependency chain and costs more under
+    lock-step than it saves (ZBV D=4 M=8, weights F, B, W = 1, 2, 2:
+    94 unpacked, 153 packed; ZBH1 D=8 M=16: 102 and 118). A W unit needs
+    its dgrad twin B(s, m) done, the same tick not included then.
 
     This is the deadlock-freedom analog of upstream's ``_validate_schedule``
     (``schedules.py:1619``) plus gloo's peer-sorted P2P batching
@@ -478,6 +506,8 @@ def schedule_ticks(orders: List[List[Action]], n_devices: int, n_virtual: int,
     t = 0
     limit = 4 * n_actions + 4 * S + 16
 
+    split = any(a.op == W for o in orders for a in o)
+
     def device_of(stage: int) -> int:
         return placement_device_of(placement, stage, D)
 
@@ -486,8 +516,8 @@ def schedule_ticks(orders: List[List[Action]], n_devices: int, n_virtual: int,
             if a.stage == 0:
                 return True
             dep = Action(a.stage - 1, F, a.microbatch)
-            # one tick of ppermute latency (for D == 1 the +1 is subsumed by
-            # one-action-per-tick, so the same rule applies)
+            # one tick of ppermute latency (for D == 1 too: the next stage's
+            # F slot of the same tick has already run)
             return dep in done and done[dep] + 1 <= now
         if Action(a.stage, F, a.microbatch) not in done:
             return False
@@ -511,14 +541,18 @@ def schedule_ticks(orders: List[List[Action]], n_devices: int, n_virtual: int,
         if t > limit:
             raise ScheduleError("schedule deadlocked: no progress within tick limit")
         for d in range(D):
-            if ptr[d] >= len(orders[d]):
-                continue
-            a = orders[d][ptr[d]]
-            if device_of(a.stage) != d:
-                raise ScheduleError(f"action {a} listed on device {d}")
-            if ready(a, t):
+            taken = set()  # kinds this device already runs in tick t
+            while ptr[d] < len(orders[d]):
+                a = orders[d][ptr[d]]
+                if device_of(a.stage) != d:
+                    raise ScheduleError(f"action {a} listed on device {d}")
+                if a.op in taken or not ready(a, t):
+                    break
                 done[a] = t
+                taken.add(a.op)
                 ptr[d] += 1
+                if split:
+                    break
         t += 1
     return done, t
 
@@ -651,6 +685,18 @@ class CompiledSchedule:
         """True when the table uses the -1 fwd / +1 bwd channels or local
         hops — the executor then issues the two extra ppermutes."""
         return bool(np.any(self.table[:, :, N_COLS_CLASSIC:] >= 0))
+
+    @property
+    def work_cells(self) -> int:
+        """(tick, device) cells that run at least one unit."""
+        return int((1 - table_unit_activity(self.table)[..., 3]).sum())
+
+    @property
+    def packed(self) -> int:
+        """(tick, device) cells that run more than one unit — how often
+        :func:`schedule_ticks`'s packing engages (20 of 1F1B D=4 M=8's 56
+        cells)."""
+        return int((table_unit_activity(self.table)[..., :3].sum(-1) > 1).sum())
 
 
 def _allocate_slots(events: List[Tuple[int, int, object]]) -> Tuple[Dict[object, int], int]:
@@ -995,14 +1041,17 @@ def _artifact_fingerprint(art: Dict[str, object]) -> str:
 
 def _orders_from_ticks(cs: CompiledSchedule) -> List[List[Action]]:
     """Recover per-device action orders from a Python-compiled schedule's
-    tick assignment (one compute action per device per tick)."""
+    tick assignment. :func:`schedule_ticks` fills the map as it places —
+    tick by tick and, within a device, in list order — so the map's own
+    order, split by device, IS the order that was compiled (a tick may hold
+    several of a device's actions, and sorting them would not reproduce the
+    table)."""
     if not cs.ticks:
         raise ScheduleError(
             f"schedule {cs.name!r} has no tick map (natively compiled?); "
             "cannot recover per-device orders for an artifact")
     orders: List[List[Action]] = [[] for _ in range(cs.n_devices)]
-    key = lambda kv: (kv[1], kv[0].stage, kv[0].op, kv[0].microbatch)
-    for a, _t in sorted(cs.ticks.items(), key=key):
+    for a in cs.ticks:
         orders[placement_device_of(cs.placement, a.stage, cs.n_devices)].append(a)
     return orders
 
@@ -1716,20 +1765,16 @@ def simulated_bubble(cs: CompiledSchedule, w_f: float = 1.0,
     the weight used in its ``bubble_sim_w_b`` column). ``w_b=1`` is the
     unit-cost textbook model (= :func:`analytic_bubble_fraction`);
     ``w_b~=w_f`` fits split schedules whose B is dgrad-only. Lockstep
-    SPMD: each tick lasts as long as its most expensive active device
-    (the pessimistic bound — on hardware the ppermute dependency is
-    pairwise, so realized makespans sit between this and
-    :func:`async_makespan`)."""
-    T = cs.makespan
-    tick_cost = np.zeros(T + 1)
-    busy = np.zeros(cs.n_devices)
-    weight = {F: w_f, B: w_b, W: w_w}
-    for a, t in cs.ticks.items():
-        w = weight[a.op]
-        d = a.stage % cs.n_devices
-        tick_cost[t] = max(tick_cost[t], w)
-        busy[d] += w
-    makespan = float(tick_cost.sum())
+    SPMD: a device's units of one tick run one after the other, and each
+    tick lasts as long as its most expensive device — what the v5e
+    measures, every tick ending in the ring's ``ppermute`` (PERF.md §6,
+    PR 28 and PR 29). Packed 1F1B reads ``(D-1)/(M+D-1)`` at every
+    ``w_b``: all stages are in steady state together, so a tick costs
+    ``w_f + w_b`` on each, and the makespan is :func:`async_makespan`'s."""
+    weight = np.array([w_f, w_b, w_w, 0.0])
+    per_dev_tick = table_unit_activity(cs.table) @ weight  # [T, D]
+    busy = per_dev_tick.sum(axis=0)
+    makespan = float(per_dev_tick.max(axis=1).sum())
     per_device = 1.0 - busy / makespan
     return {
         "makespan": makespan,

@@ -217,24 +217,49 @@ def test_unrolled_ticks_match_scan(problem, name, V, M):
 def test_auto_unroll_past_32_rows_matches_scan(problem):
     """Round 5 (VERDICT r4 item 1): _UNROLL_TICKS_LIMIT was raised 32->64
     from chip measurements (results/unroll_crossover.json), so
-    ladder-scale tables (e.g. 1F1B D=2 M=16, >32 rows) now AUTO-unroll.
-    The auto path must equal the explicit scan form and the single-device
-    oracle at a table size the old limit would have scanned."""
+    ladder-scale tables (>32 rows) now AUTO-unroll. The auto path must
+    equal the explicit scan form and the single-device oracle at a table
+    size the old limit would have scanned. GPipe D=2 M=16 is 33 rows; the
+    1F1B table this test used until PR 29 packs into 18."""
     from distributed_training_with_pipeline_parallelism_tpu.parallel.pipeline import (
         _UNROLL_TICKS_LIMIT, _compile)
 
     params, tokens, targets, ref_loss, ref_grads = problem
     M = 16
-    rows = _compile("1F1B", 2, 1, M).table.shape[0]
+    rows = _compile("GPipe", 2, 1, M).table.shape[0]
     assert 32 < rows <= _UNROLL_TICKS_LIMIT, rows
     mesh = make_mesh(n_pipe=2)
-    sched = dtpp.ScheduleConfig(name="1F1B", n_microbatches=M)
+    sched = dtpp.ScheduleConfig(name="GPipe", n_microbatches=M)
     # oracle-only: unroll==scan equivalence is already asserted at smaller
     # tables (test_unrolled_ticks_match_scan); compiling the scan twin of
-    # this 34-row program would double an already-heavy 1-core-CI test
+    # this 33-row program would double an already-heavy 1-core-CI test
     la, ga = make_pipeline_step(CFG, mesh, sched,
                                 remat_backward=True)(params, tokens, targets)
     assert_matches_reference(la, ga, ref_loss, ref_grads)
+
+
+def test_packed_tick_logs_its_table_and_fences_forward_from_backward(
+        problem, caplog):
+    """PR 29: the build logs rows, work cells and packed cells once, and a
+    tick that holds a forward AND a backward unit runs them one after the
+    other — an ``optimization_barrier`` between the two, without which the
+    v5e's compiler interleaves them and gpt2-xl D=4 no longer fits its HBM
+    (PERF.md, PR 29). A tick with one kind of unit needs no fence."""
+    import logging
+
+    from distributed_training_with_pipeline_parallelism_tpu.parallel.pipeline import (
+        make_pipeline_grad_fn)
+    params, tokens, targets, _, _ = problem
+    mesh = make_mesh(n_pipe=4)
+    with caplog.at_level(logging.INFO):
+        fn = make_pipeline_grad_fn(
+            CFG, mesh, dtpp.ScheduleConfig(name="1F1B", n_microbatches=8))
+    lines = [r.getMessage() for r in caplog.records if "tick table" in r.getMessage()]
+    assert len(lines) == 1, lines
+    assert "14 rows, 44 of 56 cells work, 20 of them packed" in lines[0]
+    jaxpr = str(jax.make_jaxpr(fn)(params, tokens, targets))
+    # ticks 3..10 of the 14 hold both kinds of unit on some stage
+    assert jaxpr.count("optimization_barrier") == 8
 
 
 def test_phase_executor_matches_scan_light(problem):

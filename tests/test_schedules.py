@@ -25,9 +25,10 @@ def test_1f1b_warmup_depths():
     D, M = 4, 8
     orders = build_order("1F1B", D, 1, M)
     for d, order in enumerate(orders):
-        # warmup = D-1-d forwards before the first backward
+        # warmup = 2(D-1-d) forwards before the first backward: a hop costs
+        # one tick each way, so that many are in flight when B(d, 0) can run
         first_b = next(i for i, a in enumerate(order) if a.op == B)
-        assert first_b == (D - 1 - d) + 1, f"device {d}"  # warmup F's + steady first F
+        assert first_b == 2 * (D - 1 - d) + 1, f"device {d}"  # warmup F's + steady first F
     # last device alternates F,B from the start
     assert [a.op for a in orders[D - 1][:6]] == [F, B, F, B, F, B]
 
@@ -69,7 +70,10 @@ def test_compile_and_validate(name, D, V, M):
         if a.op == F and a.stage > 0:
             assert cs.ticks[Action(a.stage - 1, F, a.microbatch)] + 1 <= t
         if a.op == B:
-            assert cs.ticks[Action(a.stage, F, a.microbatch)] < t
+            # a tick runs its F slot before its B slot: the last stage, which
+            # waits for no cotangent, may turn a microbatch round in one tick
+            tf = cs.ticks[Action(a.stage, F, a.microbatch)]
+            assert tf < t or (tf == t and a.stage == S - 1)
             if a.stage < S - 1:
                 assert cs.ticks[Action(a.stage + 1, B, a.microbatch)] + 1 <= t
     # table consistency: every compute appears once; arrivals precede consumption
@@ -143,11 +147,15 @@ def test_wrap_tables_do_not_use_reverse_routes():
 
 
 def test_gpipe_makespan_matches_analytic():
-    # unit-cost fill-drain makespan: 2M + 2(D-1) compute ticks
+    # fill-drain makespan: 2M + 2(D-1) units, in one row fewer — the last
+    # stage's F(M-1) and B(0) share the tick at the turn
     for D, M in [(2, 4), (4, 4), (4, 8)]:
         cs = compile_schedule("GPipe", D, 1, M)
         last_tick = max(cs.ticks.values())
-        assert last_tick + 1 == 2 * M + 2 * (D - 1)
+        assert last_tick + 1 == 2 * M + 2 * (D - 1) - 1
+        assert cs.packed == 1
+        assert cs.ticks[Action(D - 1, F, M - 1)] == cs.ticks[Action(D - 1, B, 0)]
+        assert simulated_bubble(cs, 1.0, 1.0)["makespan"] == 2 * M + 2 * (D - 1)
 
 
 def test_bubble_fractions():
@@ -196,9 +204,11 @@ def test_async_model_reproduces_reference_orderings():
     reproduce BASELINE.md's published orderings: Interleaved1F1B wins
     exactly when 2 virtual stages fit, the degenerate V=1 interleave ties
     1F1B, and 1F1B ties GPipe (its win is memory). Under the LOCKSTEP
-    tick model (simulated_bubble — at any w_b >= 2, i.e. stored or remat
-    backward) GPipe leads instead, which is what the committed sim-mesh
-    sweep measures. Both models, one set of tables."""
+    tick model (simulated_bubble) the unpacked tables of before PR 29 had
+    GPipe leading instead — a mixed F/B tick cost a backward on every
+    device — which is what the committed sim-mesh sweep measured. Packed
+    ticks cost every full-backward table its async makespan, so the two
+    models now agree. Both models, one set of tables."""
     from distributed_training_with_pipeline_parallelism_tpu.parallel.schedules import (
         async_makespan, predicted_throughput)
     toks = 32 * 128
@@ -211,16 +221,20 @@ def test_async_model_reproduces_reference_orderings():
         # degenerate interleave == 1F1B == GPipe in ticks
         assert tp[("Interleaved1F1B", 1)] == pytest.approx(tp[("1F1B", 1)])
         assert tp[("1F1B", 1)] == pytest.approx(tp[("GPipe", 1)])
-    # lockstep (w_b=2 default; the inequality also holds at the D>1
-    # remat executor's w_b=3), M=2D: GPipe's homogeneous phases keep the
-    # textbook bubble while mixed F/B ticks pay the barrier -> GPipe
-    # leads where the async model has it tied-or-behind. (At small M=D
-    # the V-bubble reduction still outweighs the barrier cost; the
-    # sim-mesh wall-clock flip there comes from per-tick dispatch
-    # overhead — 2x ticks at V=2 — quantified in docs/results.md.)
+    # lockstep (w_b=2 default, and the D>1 remat executor's w_b=3), M=2D:
+    # a device's units of a tick are summed and the slowest device sets the
+    # tick. With F and B of a steady stage in ONE tick no device waits for
+    # a neighbour's longer unit, and the barrier costs nothing: the
+    # lock-step cost of every full-backward table IS its async makespan.
+    for w_b in (2.0, 3.0):
+        for n, V in [("GPipe", 1), ("1F1B", 1), ("Interleaved1F1B", 2),
+                     ("BFS", 2)]:
+            lock = simulated_bubble(compile_schedule(n, 4, V, 8), 1.0, w_b)
+            assert lock["makespan"] / V == pytest.approx(
+                async_makespan(n, 4, V, 8, w_b=w_b)), (n, w_b)
     gp = simulated_bubble(compile_schedule("GPipe", 4, 1, 8))
     il = simulated_bubble(compile_schedule("Interleaved1F1B", 4, 2, 8))
-    assert gp["bubble_fraction"] < il["bubble_fraction"]
+    assert il["bubble_fraction"] < gp["bubble_fraction"]
     # and the async model refuses malformed configs rather than hanging
     with pytest.raises(Exception):
         async_makespan("1F1B", 4, 1, 2)  # M < D invalid for 1F1B
@@ -241,16 +255,102 @@ def test_table_interpreter_catches_corruption():
 
 
 def test_slot_allocation_memory_advantage():
-    # GPipe must hold all M microbatch inputs; 1F1B only O(D) in-flight ones.
-    D, M = 4, 16
-    gp = compile_schedule("GPipe", D, 1, M)
-    fb = compile_schedule("1F1B", D, 1, M)
-    assert gp.n_act_slots == M
-    assert fb.n_act_slots <= D + 1, fb.n_act_slots
+    # GPipe must hold all M microbatch inputs; 1F1B only O(D) in-flight
+    # ones, whatever M: 2D-1 on stage 0, the round trip in ticks plus one.
+    D = 4
+    gp = compile_schedule("GPipe", D, 1, 16)
+    assert gp.n_act_slots == 16
+    for M in (16, 32):
+        fb = compile_schedule("1F1B", D, 1, M)
+        assert fb.n_act_slots <= 2 * D - 1, (M, fb.n_act_slots)
     assert fb.n_grad_slots <= 2
     # interleaved with V virtual stages stays bounded by ~S in-flight
     il = compile_schedule("Interleaved1F1B", 4, 2, 8)
     assert il.n_act_slots < 2 * il.n_microbatches
+
+
+# ---------------------------------------------------------------------------
+# Packed ticks (PR 29): a stage's forward and its backward in ONE tick
+# ---------------------------------------------------------------------------
+
+
+_PACKED_GRID = [(2, 2), (2, 4), (2, 8), (3, 6), (4, 4), (4, 8), (4, 32),
+                (8, 8), (8, 16)]
+
+
+@pytest.mark.parametrize("D,M", _PACKED_GRID)
+def test_1f1b_packed_rows_and_steady_state(D, M):
+    """1F1B compiles to M + 2(D-1) rows, and every tick in which all the
+    stages are in steady state holds F and B on every device."""
+    from distributed_training_with_pipeline_parallelism_tpu.analysis.table_check import (
+        check_table)
+    cs = compile_schedule("1F1B", D, 1, M)
+    assert cs.makespan == cs.table.shape[0] == M + 2 * (D - 1)
+    assert check_table(cs).ok
+    f_on = cs.table[:, :, sch.COL_FWD_M] >= 0
+    b_on = cs.table[:, :, sch.COL_BWD_M] >= 0
+    # stage d is steady from its first backward (tick 2(D-1) - d) to its
+    # last forward (tick M - 1 + d): all of them together in between
+    steady = range(2 * (D - 1), M)
+    for t in steady:
+        assert f_on[t].all() and b_on[t].all(), t
+    both = f_on & b_on
+    assert cs.packed == int(both.sum()) == sum(
+        M - min(M, 2 * (D - 1 - d)) for d in range(D))
+    assert cs.work_cells == 2 * D * M - cs.packed
+    others = [t for t in range(cs.makespan) if t not in steady]
+    assert not both[others].all(axis=1).any()
+
+
+@pytest.mark.parametrize("D,M", _PACKED_GRID)
+@pytest.mark.parametrize("w_b", [1.0, 2.0, 3.0, 3.3])
+def test_1f1b_packed_lockstep_cost_is_async(D, M, w_b):
+    """The lock-step cost — a device's units of a tick summed, then the
+    largest device — is (M+D-1)(w_f+w_b), the async runtime's, so the
+    weighted bubble is the textbook (D-1)/(M+D-1) at every w_b."""
+    cs = compile_schedule("1F1B", D, 1, M)
+    sim = simulated_bubble(cs, 1.0, w_b)
+    assert sim["makespan"] == pytest.approx((M + D - 1) * (1.0 + w_b))
+    assert sim["makespan"] == pytest.approx(
+        sch.async_makespan("1F1B", D, 1, M, w_b=w_b))
+    ana = analytic_bubble_fraction("1F1B", D, 1, M)
+    assert sim["bubble_fraction"] == pytest.approx(ana, abs=1e-9)
+    assert sim["bubble_fraction_max"] == pytest.approx(ana, abs=1e-9)
+
+
+@pytest.mark.parametrize("D,M", _PACKED_GRID)
+def test_1f1b_packed_slots(D, M):
+    """min(M, 2D-1) stage inputs in flight, one gradient slot, and the last
+    stage turns each microbatch round inside one tick, on one slot."""
+    cs = compile_schedule("1F1B", D, 1, M)
+    assert cs.n_act_slots == min(M, 2 * D - 1)
+    assert cs.n_grad_slots == 1
+    for m in range(M):
+        t = cs.ticks[Action(D - 1, F, m)]
+        assert cs.ticks[Action(D - 1, B, m)] == t
+        row = cs.table[t, D - 1]
+        assert row[sch.COL_FWD_M] == row[sch.COL_BWD_M] == m
+        assert row[sch.COL_FWD_SLOT] == row[sch.COL_BWD_ASLOT]
+
+
+@pytest.mark.parametrize("name,V,M", [("ZBH1", 1, 8), ("ZBV", 2, 8)])
+def test_split_backward_orders_keep_one_unit_a_tick(name, V, M):
+    """The zero-bubble orders fill every unit tick themselves; packing them
+    lengthens the ticks along the dependency chain (schedule_ticks says by
+    how much), so their tables are what they were."""
+    cs = compile_schedule(name, 4, V, M)
+    assert cs.packed == 0
+    assert cs.work_cells == len(cs.ticks)
+
+
+def test_orders_recovered_from_a_packed_table_recompile_to_it():
+    """A tick holds several of a device's actions; the artifact's orders
+    must still be the ones that compile to the stored table."""
+    for name, V in [("1F1B", 1), ("Interleaved1F1B", 2), ("GPipe", 1)]:
+        cs = compile_schedule(name, 4, V, 8)
+        assert sch._orders_from_ticks(cs) == build_order(name, 4, V, 8)
+        cs2 = sch.load_schedule_artifact(sch.schedule_artifact(cs))
+        assert np.array_equal(cs2.table, cs.table)
 
 
 # ---------------------------------------------------------------------------
@@ -317,12 +417,18 @@ def test_phase_replay_custom_schedule():
 def test_phase_compression_actually_compresses():
     # the steady state must not fall out as all length-1 phases: GPipe
     # D=1 (pure F* then B* runs) compresses to a handful of descriptors,
-    # and 1F1B's F/B alternation is caught as multi-rep phases
+    # and 1F1B's steady state — every stage F+B in every row — is ONE
+    # multi-rep phase whatever M, between 2(D-1) warm-up rows and 2(D-1)
+    # cool-down rows that are a phase each
     t_gpipe = compile_schedule("GPipe", 1, 1, 32).table
     assert sch.phase_stats(sch.compress_schedule(t_gpipe))["n_phases"] <= 4
-    t_1f1b = compile_schedule("1F1B", 4, 1, 16).table
-    st = sch.phase_stats(sch.compress_schedule(t_1f1b))
-    assert st["n_phases"] < st["n_rows"] // 2
+    for D, M in [(4, 32), (4, 64), (2, 16), (8, 48)]:
+        phases = sch.compress_schedule(compile_schedule("1F1B", D, 1, M).table)
+        st = sch.phase_stats(phases)
+        assert st["n_phases"] == 4 * (D - 1) + 1, (D, M, st)
+        assert st["n_phases"] < st["n_rows"] // 2, (D, M, st)
+        steady = [p for p in phases if p.reps > 1]
+        assert len(steady) == 1 and steady[0].start == 2 * (D - 1)
 
 
 def test_phase_replay_degenerate_tables():
