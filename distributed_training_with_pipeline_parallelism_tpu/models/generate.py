@@ -58,6 +58,8 @@ Pytree = Dict
 def init_cache(cfg: ModelConfig, batch_size: int, max_len: int,
                dtype=None) -> Pytree:
     """Allocate an all-zeros KV cache: leaves [n_layers, B, max_len, Hkv, hd]."""
+    from .nemotron_h import not_served
+    not_served("models/generate.py", cfg)
     n_kv = cfg.n_kv_heads or cfg.n_heads
     shape = (cfg.n_layers, batch_size, max_len, n_kv, cfg.head_dim)
     dtype = dtype or jnp.dtype(cfg.dtype)

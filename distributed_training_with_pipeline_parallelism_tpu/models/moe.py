@@ -1,4 +1,18 @@
-"""Mixture-of-Experts feed-forward layers and a MoE decoder LM.
+"""Mixture-of-Experts feed-forward layers and a MoE decoder LM — the OLDER,
+capacity-routed one.
+
+Two expert layers live in this repo. This module is the first: softmax
+top-k with a per-expert capacity that DROPS tokens past it, dense one-hot
+dispatch (``route()``'s ``[T, k, E, C]`` tensor does not fit a chip at real
+sizes), a load-balancing auxiliary loss, its own decoder LM (``moe_lm_*``)
+and a ``moe=`` argument through the executors. The newer layer,
+:mod:`..ops.experts` (the ``E`` layers of ``arch="nemotron_h"``, PR 30),
+uses NONE of this module's routing: sigmoid scores over the router's whole
+width, top-k, nothing dispatched and so nothing dropped (every held expert
+on every token, gated), one expert-parallel rank's share, on the normal path
+with no ``moe=``. This module and ``parallel/expert_parallel.py`` stay
+until that layer has its exchange across chips (ROADMAP R1, D7).
+
 
 The reference is dense-FFN only (SURVEY.md §2.4, EP row: "NO — dense FFN
 only (`nn.TransformerDecoderLayer`)"), so this module is beyond-parity

@@ -1,0 +1,243 @@
+"""The ``nemotron_h`` family (NVIDIA Nemotron-H / Nemotron-3 hybrids): a stack
+of pre-norm residual layers ``h <- h + mixer_l(RMSNorm_l(h))`` in which every
+layer is ONE mixer, chosen by a letter of ``cfg.hybrid_override_pattern``:
+
+- ``M`` — Mamba-2 (:mod:`..ops.mamba2`);
+- ``*`` — causal grouped-query attention, no bias, no positional encoding
+  (the state-space layers carry position; ``rope_theta`` is carried by the
+  source's config and unused), through :func:`..ops.attention.mha_apply` and
+  so through the Pallas flash kernels where ``cfg.flash_for`` says so;
+- ``E`` — routed and shared relu^2 experts (:mod:`..ops.experts`), as the
+  expert-parallel rank that holds ``cfg.held_experts`` computes them.
+
+Parameters: ``layers`` is ``{"mamba": .., "attn": .., "moe": ..}``, each the
+layers of one kind stacked on axis 0 in pattern order; the stack is walked in
+pattern order as straight-line code (layers of different kinds share no
+scan), each layer under ``jax.checkpoint`` where ``cfg.remat_layers``.
+``transformer_init`` / ``body_apply`` of :mod:`.transformer` dispatch here,
+so ``transformer_loss``, ``train.init_params`` and ``train.make_train_step``
+run this family on the normal path.
+
+What it does not run, by a named error: ``pipe`` > 1 and tensor, sequence or
+fsdp axes (:func:`check_mesh`), ``models/generate.py`` and ``serving/``.
+"""
+
+from __future__ import annotations
+
+import functools
+import logging
+from typing import Dict, List, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from ..ops.attention import mha_apply, mha_init
+from ..ops.experts import experts_apply, experts_init
+from ..ops.layers import rms_norm_apply, rms_norm_init
+from ..ops.mamba2 import mamba2_apply, mamba2_init
+from ..utils.config import ModelConfig
+
+#: pattern letter -> the key of its stack under ``params["layers"]``
+KINDS = {"M": "mamba", "*": "attn", "E": "moe"}
+#: leaves that stay in the storage dtype under mixed precision: the
+#: recurrence's decay parameters and the whole router are float32 whatever
+#: ``cfg.dtype`` is
+FLOAT32_LEAVES = frozenset(("A_log", "dt_bias", "D", "router"))
+
+_log = logging.getLogger(__name__)
+
+
+def nemotron_h_config(name: str = "stage", **overrides) -> ModelConfig:
+    """``stage``: the first nine layers of Nemotron-Labs-TwoTower-30B-A3B's
+    language model at published widths as one rank of 16-way expert
+    parallelism holds them (8 of 128 experts, an eighth of the vocabulary:
+    667 M parameters; ``benchmark/configs/nemotron-twotower-30b-a3b.json``
+    has the source and the arithmetic). ``debug``: every kind of layer at toy
+    widths, for the CPU."""
+    sizes = {
+        "stage": dict(dim=2688, n_heads=32, n_kv_heads=2,
+                      head_dim_override=128, vocab_size=16384,
+                      max_seq_len=262144, hybrid_override_pattern="MEMEM*EME",
+                      experts_held=tuple(range(8))),
+        "debug": dict(dim=64, n_heads=4, n_kv_heads=2, head_dim_override=16,
+                      vocab_size=256, max_seq_len=4096,
+                      hybrid_override_pattern="MEM*E", mamba_num_heads=8,
+                      mamba_head_dim=8, n_groups=2, ssm_state_size=16,
+                      chunk_size=16, n_routed_experts=16,
+                      experts_held=(0, 1, 2, 3), num_experts_per_tok=3,
+                      moe_intermediate_size=32,
+                      moe_shared_expert_intermediate_size=48),
+    }
+    if name not in sizes:
+        raise ValueError(f"unknown nemotron_h size {name!r}; options: "
+                         f"{sorted(sizes)}")
+    kw = dict(sizes[name], arch="nemotron_h")
+    kw.update(overrides)
+    kw.setdefault("n_layers", len(kw["hybrid_override_pattern"]))
+    return ModelConfig(**kw)
+
+
+def layer_plan(cfg: ModelConfig) -> List[Tuple[str, int]]:
+    """``(stack key, index inside that stack)`` for every layer, in order."""
+    seen: Dict[str, int] = {}
+    plan = []
+    for letter in cfg.hybrid_override_pattern:
+        kind = KINDS[letter]
+        plan.append((kind, seen.get(kind, 0)))
+        seen[kind] = seen.get(kind, 0) + 1
+    return plan
+
+
+def mixer_init(key: jax.Array, cfg: ModelConfig, kind: str) -> Dict:
+    norm = rms_norm_init(cfg.dim)
+    if kind == "mamba":
+        return {"norm": norm, **mamba2_init(
+            key, cfg.dim, cfg.mamba_num_heads, cfg.mamba_head_dim,
+            cfg.n_groups, cfg.ssm_state_size, cfg.conv_kernel,
+            cfg.time_step_min, cfg.time_step_max, cfg.time_step_floor)}
+    if kind == "attn":
+        return {"norm": norm, "attn": mha_init(
+            key, cfg.dim, cfg.n_heads, cfg.n_kv_heads, bias=False,
+            head_dim=cfg.head_dim)}
+    if kind == "moe":
+        return {"norm": norm, **experts_init(
+            key, cfg.dim, cfg.n_routed_experts, len(cfg.held_experts),
+            cfg.moe_intermediate_size,
+            cfg.moe_shared_expert_intermediate_size)}
+    raise ValueError(f"unknown layer kind {kind!r}")
+
+
+def stack_init(key: jax.Array, cfg: ModelConfig) -> Dict:
+    """``params["layers"]``: per kind, its layers stacked on axis 0. Says at
+    ``logging.INFO`` what was built."""
+    plan = layer_plan(cfg)
+    keys = jax.random.split(key, len(plan))
+    stacks = {}
+    for kind in dict.fromkeys(k for k, _ in plan):
+        mine = jnp.stack([keys[i] for i, (k, _) in enumerate(plan)
+                          if k == kind])
+        stacks[kind] = jax.vmap(lambda k: mixer_init(k, cfg, kind))(mine)
+    _log.info("nemotron_h: pattern %s (%s); experts held %s of %d",
+              cfg.hybrid_override_pattern,
+              ", ".join(f"{sum(k == kind for k, _ in plan)} {kind}"
+                        for kind in stacks),
+              list(cfg.held_experts), cfg.n_routed_experts)
+    return stacks
+
+
+def mixer(cfg: ModelConfig, kind: str, params: Dict, x: jax.Array):
+    """One layer's mixer on ``x`` [B, T, d], already normed -> (out [B, T,
+    d], the assignments each held expert got or None)."""
+    if kind == "mamba":
+        return mamba2_apply(
+            params, x, cfg.mamba_num_heads, cfg.mamba_head_dim, cfg.n_groups,
+            cfg.ssm_state_size, cfg.chunk_size, cfg.rms_eps), None
+    if kind == "attn":
+        return mha_apply(params["attn"], x, x, cfg.n_heads, causal=True,
+                         flash=cfg.flash_for(True, x.shape[1])), None
+    if kind == "moe":
+        b, t, d = x.shape
+        out, counts = experts_apply(
+            params, x.reshape(b * t, d), cfg.held_experts,
+            cfg.num_experts_per_tok, cfg.routed_scaling_factor)
+        return out.reshape(b, t, d), counts
+    raise ValueError(f"unknown layer kind {kind!r}")
+
+
+#: the profiler region of a layer's norm and mixer, by kind
+SCOPES = {"mamba": "model/ssm", "attn": "model/attn", "moe": "model/moe"}
+
+
+def mixer_apply(cfg: ModelConfig, kind: str, params: Dict, h: jax.Array):
+    """One residual layer ``h + mixer(RMSNorm(h))`` -> (h, counts or None)."""
+    with jax.named_scope(SCOPES[kind]):
+        out, counts = mixer(cfg, kind, params, rms_norm_apply(
+            params["norm"], h, cfg.rms_eps))
+        return h + out, counts
+
+
+def stack_apply(cfg: ModelConfig, layers: Dict, h: jax.Array,
+                ) -> Tuple[jax.Array, List[jax.Array]]:
+    """Walk the pattern -> (h, the expert layers' counts in order)."""
+    counts = []
+    for kind, i in layer_plan(cfg):
+        one = lambda p, x, kind=kind: mixer_apply(cfg, kind, p, x)  # noqa: E731
+        if cfg.remat_layers:
+            one = jax.checkpoint(one)
+        h, c = one(jax.tree.map(lambda x: x[i], layers[kind]), h)
+        if c is not None:
+            counts.append(c)
+    return h, counts
+
+
+def compute_cast(cfg: ModelConfig, tree: Dict) -> Dict:
+    """Storage -> compute dtype for every leaf but :data:`FLOAT32_LEAVES`."""
+    dtype = jnp.dtype(cfg.dtype)
+
+    def cast(path, x):
+        names = {getattr(p, "key", None) for p in path}
+        return x if names & FLOAT32_LEAVES else x.astype(dtype)
+
+    return jax.tree_util.tree_map_with_path(cast, tree)
+
+
+def check_mesh(cfg: ModelConfig, mesh) -> None:
+    """The patterned stack runs as ONE pipeline stage on a mesh without
+    model/seq/expert axes; anything else is a named error, not another
+    program."""
+    if cfg.arch != "nemotron_h":
+        return
+    missing = []
+    if mesh.shape.get("pipe", 1) > 1:
+        missing.append("a patterned stack cut into pipeline stages "
+                       "(parallel/pipeline.py:stack_stage_layers stacks "
+                       "identical layers only)")
+    for axis, what in (("model", "tensor-parallel"), ("seq", "sequence-"
+                       "parallel"), ("expert", "expert-parallel exchange of")):
+        if mesh.shape.get(axis, 1) > 1:
+            missing.append(f"{what} Mamba-2 and expert layers ('{axis}' axis)")
+    if missing:
+        raise NotImplementedError(
+            "arch='nemotron_h' runs on one pipeline stage; not written: "
+            + "; ".join(missing))
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def routing_stats(cfg: ModelConfig, params: Dict, tokens: jax.Array) -> Dict:
+    """What the expert layers do with ``tokens`` [B, S], per expert layer in
+    pattern order: ``tokens_per_expert`` [layers, held] and ``max_over_mean``
+    [layers] (the busiest held expert over the mean). One forward pass
+    through the layers, jitted, for tests and operators (``scripts/train.py``
+    logs :func:`describe_routing` of it at ``fit``'s log points)."""
+    from .transformer import compute_cast as cast, embed_apply  # circular
+    params = cast(cfg, params)
+    h = embed_apply(cfg, params["embed"], tokens)
+    _, counts = stack_apply(cfg, params["layers"], h)
+    if not counts:
+        raise ValueError(f"pattern {cfg.hybrid_override_pattern!r} has no "
+                         "expert layer")
+    counts = jnp.stack(counts)
+    return {"tokens_per_expert": counts,
+            "max_over_mean": counts.max(-1) / jnp.maximum(
+                counts.mean(-1, dtype=jnp.float32), 1e-9)}
+
+
+def describe_routing(stats: Dict) -> str:
+    """One line for a log: per expert layer, the busiest held expert."""
+    stats = jax.device_get(stats)
+    return "; ".join(
+        f"E{i}: max/mean {m:.2f}, busiest {int(c.max())} of {int(c.sum())} "
+        "local assignments"
+        for i, (c, m) in enumerate(zip(stats["tokens_per_expert"],
+                                       stats["max_over_mean"])))
+
+
+def not_served(what: str, cfg: ModelConfig) -> None:
+    """``models/generate.py`` and ``serving/`` keep a KV cache per layer and
+    nothing else: no recurrent state, no expert layer."""
+    if cfg.arch == "nemotron_h":
+        raise NotImplementedError(
+            f"{what}: arch='nemotron_h' is not written for generation — a "
+            "decode step of a Mamba-2 layer needs its convolution window and "
+            "state-space state cached beside the attention layers' keys and "
+            "values, and an expert layer a decode path")

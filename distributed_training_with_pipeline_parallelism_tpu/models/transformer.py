@@ -12,6 +12,11 @@ Three block families selected by ``ModelConfig.arch``:
 - ``gpt2`` — pre-LN, learned position embeddings, causal self-attn, gelu MLP.
 - ``llama`` — pre-RMSNorm, RoPE, grouped-query causal attention, SwiGLU MLP,
   no biases.
+- ``nemotron_h`` — ONE mixer a layer, chosen by ``cfg.hybrid_override_pattern``
+  (Mamba-2, attention, experts): the layers and their stack are
+  :mod:`.nemotron_h`; ``layers`` is then a dict of per-kind stacks walked in
+  pattern order, not one scan (regions ``model/ssm``, ``model/ssm_scan``,
+  ``model/moe``, ``model/moe_experts`` beside ``model/attn``).
 
 Every block names itself for the profiler with ``jax.named_scope`` —
 ``model/embed``, ``model/layers``, ``model/attn``, ``model/mlp``,
@@ -39,6 +44,7 @@ from ..ops.layers import (dropout_apply, embedding_apply, embedding_init,
                           linear_init, rms_norm_apply, rms_norm_init,
                           select_xent, sharded_dropout_apply)
 from ..utils.config import ModelConfig
+from . import nemotron_h
 
 # ---------------------------------------------------------------------------
 # Per-layer init / apply
@@ -258,13 +264,16 @@ def transformer_init(key: jax.Array, cfg: ModelConfig) -> Dict:
     embed: Dict = {"tok": tok}
     if cfg.arch == "gpt2":
         embed["pos"] = 0.02 * jax.random.normal(kp, (cfg.max_seq_len, cfg.dim))
-    layer_keys = jax.random.split(kl, cfg.n_layers)
-    layers = jax.vmap(lambda k: layer_init(k, cfg))(layer_keys)
-    norm = (rms_norm_init(cfg.dim) if cfg.arch == "llama"
-            else layer_norm_init(cfg.dim))
+    if cfg.arch == "nemotron_h":
+        layers = nemotron_h.stack_init(kl, cfg)  # one stack per kind
+    else:
+        layer_keys = jax.random.split(kl, cfg.n_layers)
+        layers = jax.vmap(lambda k: layer_init(k, cfg))(layer_keys)
+    rms = cfg.arch in ("llama", "nemotron_h")
+    norm = rms_norm_init(cfg.dim) if rms else layer_norm_init(cfg.dim)
     if cfg.tie_embeddings:
         head = {"norm": norm}  # logits come from embed.tok.T
-    elif cfg.arch == "llama":
+    elif rms:
         head = {"norm": norm,
                 "out": linear_init(ko, cfg.dim, cfg.vocab_size, bias=False)}
     else:
@@ -283,6 +292,8 @@ def compute_cast(cfg: ModelConfig, tree: Dict) -> Dict:
     use site, so cotangents flow back in the storage dtype."""
     if not cfg.mixed_precision:
         return tree
+    if cfg.arch == "nemotron_h":  # decay parameters and router stay float32
+        return nemotron_h.compute_cast(cfg, tree)
     dtype = jnp.dtype(cfg.dtype)
     return jax.tree.map(lambda x: x.astype(dtype), tree)
 
@@ -323,7 +334,17 @@ def body_apply(cfg: ModelConfig, layers: Dict, h: jax.Array,
     *global* layer index — so masks depend only on (rng, global layer, site),
     making a pipeline-stage run reproduce exactly the masks of any other
     stage partitioning of the same model (asserted in tests/test_dropout.py).
+
+    nemotron_h: ``layers`` is the dict of per-kind stacks and the WHOLE
+    pattern is walked (:func:`.nemotron_h.stack_apply`); a stack of one kind
+    keeps the scan below.
     """
+    if cfg.arch == "nemotron_h":
+        if tp_axis is not None or rng is not None:
+            raise NotImplementedError("arch='nemotron_h' layers are not "
+                                      "written for tensor parallelism or "
+                                      "dropout")
+        return nemotron_h.stack_apply(cfg, layers, h)[0]
     rope = _rope(cfg, h.shape[1])
     n = jax.tree.leaves(layers)[0].shape[0]
 
@@ -363,7 +384,7 @@ def body_apply(cfg: ModelConfig, layers: Dict, h: jax.Array,
 def head_norm_apply(cfg: ModelConfig, head: Dict, h: jax.Array) -> jax.Array:
     """The head's final norm (arch-dispatched) — shared with the executor's
     vocab-parallel loss branch so the two cannot drift."""
-    if cfg.arch == "llama":
+    if cfg.arch in ("llama", "nemotron_h"):
         return rms_norm_apply(head["norm"], h, cfg.rms_eps)
     return layer_norm_apply(head["norm"], h)
 

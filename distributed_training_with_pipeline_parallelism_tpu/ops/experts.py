@@ -1,0 +1,123 @@
+"""A routed-expert layer as ONE expert-parallel rank holds it.
+
+The ``nemotron_h`` family's expert layer (DeepSeek-V3-style routing, as the
+source's ``config.json`` declares it): sigmoid scores over ALL
+``n_routed_experts``, the ``top_k`` largest of ``score + bias`` chosen (the
+bias is ``e_score_correction_bias``, a buffer that no gradient reaches),
+weights ``scale * s_e / (sum over the chosen of s + 1e-20)``, experts
+``f(x) = relu(x W_up)^2 W_down``, plus one shared expert that every token
+takes::
+
+    out = sum_{e chosen and held here} w_e f_e(x)  +  f_shared(x)
+
+The layer is told which experts it holds (``held``: ids of the router's
+width). It routes over all of them and computes its own experts' part —
+what expert parallelism asks of a rank (``parallel/expert_parallel.py`` is
+the later consumer); nothing here stands in for the absent ranks or their
+exchange.
+
+Unlike ``models/moe.py`` (softmax top-k with a capacity that drops tokens,
+a dense ``[tokens, k, experts, capacity]`` one-hot) nothing is dispatched and
+so nothing can be dropped: every held expert multiplies every token, and a
+``[tokens, held]`` gate — an expert's weight where the token chose it, zero
+elsewhere — is applied between its two products. That is one relu^2 MLP of
+width ``held * width`` with its hidden columns gated by expert: two plain
+matrix products, no sort, gather, scatter or buffer, and a step costs the
+same whatever is routed.
+
+It pays for ``held`` expert passes a token where the routing asks for
+``top_k * held / n_routed`` (8 against 0.375 in the benchmark's cell: 95% of
+the gated columns are zeros). That is the price of the worst case, and the
+worst case is what one rank's share of training meets (PR 30, on the chip,
+with ``lax.ragged_dot`` over a sorted buffer): on random tokens AdamW moved a
+layer's local assignments 7876 -> 12757 in 20 steps and the whole batch came
+to pick the same experts within ~30, so every buffer short of a row a token
+overflowed; the compiler's grouped-product kernels skip empty tiles, so the
+step time followed the routing (3% between seeds); what they leave in rows
+outside every group is unspecified (NaN included, forward and in every
+cotangent); and given the worst-case buffer whole they ran at 15% of the
+MXU's peak. A bounded buffer needs the source's load balancing
+(``e_score_correction_bias`` updated outside the loss, here a buffer held at
+zero) before it can hold: ROADMAP Queue 2.
+
+The router is float32 end to end (its product at ``Precision.HIGHEST``: a
+TPU otherwise rounds float32 operands to bf16).
+
+Profiler regions (``utils/profiling.py:HYBRID_REGIONS``): the held experts'
+two products and their gate are ``model/moe_experts``; router, top-k and the
+shared expert are the caller's ``model/moe``.
+"""
+
+from __future__ import annotations
+
+import math
+from typing import Dict, Sequence
+
+import jax
+import jax.numpy as jnp
+
+from .layers import linear_apply, linear_init
+
+
+def experts_init(key: jax.Array, dim: int, n_routed: int, n_held: int,
+                 width: int, shared_width: int) -> Dict:
+    """``router.w`` is ``n_routed`` wide whatever is held; ``experts.w1`` /
+    ``w2`` stack the ``n_held`` held experts (the names AdamW's decay mask
+    knows, ``utils/train.py:adamw``)."""
+    kr, k1, k2, ku, kd = jax.random.split(key, 5)
+    b1, b2 = 1.0 / math.sqrt(dim), 1.0 / math.sqrt(width)
+    return {
+        "router": {"w": jax.random.uniform(kr, (dim, n_routed), minval=-b1,
+                                           maxval=b1),
+                   "bias": jnp.zeros((n_routed,))},
+        "experts": {"w1": jax.random.uniform(k1, (n_held, dim, width),
+                                             minval=-b1, maxval=b1),
+                    "w2": jax.random.uniform(k2, (n_held, width, dim),
+                                             minval=-b2, maxval=b2)},
+        "shared": {"up": linear_init(ku, dim, shared_width, bias=False),
+                   "down": linear_init(kd, shared_width, dim, bias=False)},
+    }
+
+
+def relu2(x: jax.Array) -> jax.Array:
+    return jnp.square(jax.nn.relu(x))
+
+
+def route(router: Dict, x: jax.Array, top_k: int, scale: float):
+    """``x`` [T, d] -> (ids [T, k] of the chosen experts, weights [T, k]),
+    float32. The weights are normalised over all ``k`` chosen, wherever they
+    live."""
+    logits = jnp.dot(x.astype(jnp.float32), router["w"].astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    bias = jax.lax.stop_gradient(router["bias"].astype(jnp.float32))
+    _, ids = jax.lax.top_k(scores + bias, top_k)
+    chosen = jnp.take_along_axis(scores, ids, axis=-1)
+    weights = scale * chosen / (chosen.sum(-1, keepdims=True) + 1e-20)
+    return ids, weights
+
+
+@jax.named_scope("model/moe_experts")
+def held_experts(experts: Dict, x: jax.Array, gate: jax.Array) -> jax.Array:
+    """``sum_e gate[t, e] * relu(x[t] W_up[e])^2 W_down[e]`` for ``x``
+    [T, d] and ``gate`` [T, held] (float32): every held expert on every
+    token, the gate between the two products."""
+    h = jax.checkpoint(relu2)(jnp.einsum("td,edf->tef", x, experts["w1"]))
+    h = (h.astype(jnp.float32) * gate[..., None]).astype(x.dtype)
+    return jnp.einsum("tef,efd->td", h, experts["w2"])
+
+
+def experts_apply(params: Dict, x: jax.Array, held: Sequence[int],
+                  top_k: int, scale: float):
+    """``x`` [T, d] (already normed) -> (out [T, d], the assignments each
+    held expert got [held]).
+
+    ``held``: static ids of the experts whose weights ``params["experts"]``
+    stacks, in that order."""
+    ids, weights = route(params["router"], x, top_k, scale)
+    chose = ids[:, :, None] == jnp.asarray(held, ids.dtype)    # [T, k, held]
+    gate = jnp.where(chose, weights[:, :, None], 0.0).sum(1)
+    routed = held_experts(params["experts"], x, gate)
+    shared = linear_apply(params["shared"]["down"], jax.checkpoint(relu2)(
+        linear_apply(params["shared"]["up"], x)))
+    return routed + shared, chose.sum((0, 1))

@@ -376,6 +376,29 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
 
+# The compiler gives a kernel 16 MiB of VMEM unless told otherwise.
+_DEFAULT_SCOPED_VMEM_BYTES = 16 * 1024 * 1024
+
+
+def _bwd_vmem(s_pad: int, dh: int, itemsize: int) -> dict:
+    """``pallas_call`` keywords for the classic backward's VMEM. The kernel
+    keeps a whole row's q and do and the float32 dq accumulator resident,
+    each double-buffered by the pipeline: ``2 * s * dh * (2 * itemsize +
+    4)`` bytes, 16 MiB at s = 8192, dh = 128 in bf16 — with the blocks and
+    the row statistics, over the default scoped limit, and the compiler
+    refuses (seen compiling the nemotron_h step for a described v5e). Where
+    the residency passes three quarters of the default the limit is asked
+    for explicitly, half as much again (a v5e core has 128 MiB); everywhere
+    else — every shape that compiled before — nothing is passed and the
+    program is the one it was."""
+    resident = 2 * s_pad * dh * (2 * itemsize + 4)
+    if resident <= 0.75 * _DEFAULT_SCOPED_VMEM_BYTES:
+        return {}
+    from jax.experimental.pallas import tpu as pltpu
+    return {"compiler_params": pltpu.CompilerParams(
+        vmem_limit_bytes=int(1.5 * resident))}
+
+
 def _flash_bwd(q, k, v, o, lse, g, causal, block_q, block_k, window):
     """Blockwise dq/dk/dv from saved (o, lse): the [s, s] matrix never
     materializes. Inputs [bh, s, dh] unpadded; lse [bh, 1, s_pad] (padded,
@@ -416,6 +439,7 @@ def _flash_bwd(q, k, v, o, lse, g, causal, block_q, block_k, window):
             pl.BlockSpec((1, block_k, dh), lambda i, j: (i, j, 0)),
         ),
         interpret=_use_interpret(),
+        **_bwd_vmem(s_pad, dh, q.dtype.itemsize),
     )(q, k, v, g, lse, delta)
     # the deferred `scale` fold (see kernel docstring); XLA fuses it into
     # the cast + transpose that follow

@@ -486,6 +486,9 @@ def param_shardings(cfg: ModelConfig, mesh: Mesh, moe=None,
     ``n_virtual > 1`` the wrap placement's strided stage->device map makes
     the per-step stacking a (sharded) permute; with V=1 it is movement-free."""
     from jax.sharding import NamedSharding
+
+    from ..models.nemotron_h import check_mesh
+    check_mesh(cfg, mesh)  # a patterned stack rests on one stage only
     n_data = mesh.shape.get(DATA_AXIS, 1)
     T = mesh.shape.get(MODEL_AXIS, 1)
     n_ep = mesh.shape.get(EXPERT_AXIS, 1)
@@ -506,6 +509,23 @@ def param_shardings(cfg: ModelConfig, mesh: Mesh, moe=None,
     return jax.tree.map(
         lambda sp, sub: jax.tree.map(lambda _: NamedSharding(mesh, sp), sub),
         specs, shapes, is_leaf=is_spec)
+
+
+def _check_patterned_stack(cfg: ModelConfig, mesh: Mesh,
+                           sched: ScheduleConfig, moe, fsdp: bool) -> None:
+    """A patterned stack (``arch='nemotron_h'``: per-kind stacks walked in
+    pattern order) is ONE stage: ``stack_stage_layers`` cuts stacks of
+    identical layers only. What is not written raises by name."""
+    if cfg.arch != "nemotron_h":
+        return
+    from ..models.nemotron_h import check_mesh
+    check_mesh(cfg, mesh)
+    if sched.n_virtual > 1 or fsdp or moe is not None:
+        raise NotImplementedError(
+            "arch='nemotron_h' runs as one pipeline stage with its own "
+            "expert layers: virtual stages, fsdp=True and moe= (the "
+            "capacity-routed MoEConfig blocks of models/moe.py) are not "
+            "written for it")
 
 
 def _check_moe_mesh(cfg: ModelConfig, moe, T: int, n_seq: int,
@@ -842,6 +862,7 @@ def make_pipeline_grad_fn(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig,
     weight all-gathers ride 'data' while activations shard over 'seq' —
     orthogonal by construction.
     """
+    _check_patterned_stack(cfg, mesh, sched, moe, fsdp)
     D = mesh.shape[PIPE_AXIS]
     n_data = mesh.shape.get(DATA_AXIS, 1)
     T = mesh.shape.get(MODEL_AXIS, 1)
@@ -2102,6 +2123,7 @@ def _build_forward_program(cfg: ModelConfig, mesh: Mesh,
     streams (fold_in(step key, microbatch) then global-layer offsets), so
     a phase-separated stored-backward step equals the slot-buffer
     executor's bit-for-tolerance."""
+    _check_patterned_stack(cfg, mesh, sched, moe, fsdp)
     D = mesh.shape[PIPE_AXIS]
     n_data = mesh.shape.get(DATA_AXIS, 1)
     T = mesh.shape.get(MODEL_AXIS, 1)

@@ -226,6 +226,8 @@ def make_pipeline_generate_fn(cfg: ModelConfig, mesh: Mesh,
     The KV cache stays stage-sliced over 'pipe' as before. Seq/expert
     axes remain unsupported here.
     """
+    from ..models.nemotron_h import not_served
+    not_served("parallel/pipelined_decode.py", cfg)
     if cfg.arch not in ("gpt2", "llama"):
         raise ValueError(
             f"generation is undefined for arch {cfg.arch!r} (see "

@@ -490,6 +490,8 @@ def make_serving_step_fn(cfg: ModelConfig, mesh: Mesh, *, n_slots: int,
     the accepted length stay uncommitted on the host allocator and are
     rolled back by overwrite (docs/serving.md "Speculative decoding").
     """
+    from ..models.nemotron_h import not_served
+    not_served("serving/engine.py", cfg)
     if cfg.arch not in ("gpt2", "llama"):
         raise ValueError(
             f"generation is undefined for arch {cfg.arch!r} (see "

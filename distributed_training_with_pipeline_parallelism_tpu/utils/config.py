@@ -29,6 +29,12 @@ class ModelConfig:
       - "gpt2": pre-LN, causal self-attn, gelu MLP, learned position embeddings.
       - "llama": pre-RMSNorm, causal self-attn with RoPE, SwiGLU MLP, no biases,
         tied-free output head.
+      - "nemotron_h": pre-RMSNorm residual layers of ONE mixer each, chosen
+        per layer by ``hybrid_override_pattern`` (``M`` Mamba-2, ``*`` causal
+        GQA attention without positions, ``E`` routed + shared experts), as
+        one expert-parallel rank holds it (``experts_held``). One pipeline
+        stage without tensor/sequence/fsdp axes; training and eval only
+        (``models/nemotron_h.py``).
     """
 
     dim: int = 768
@@ -114,14 +120,43 @@ class ModelConfig:
     # divide and falls back to the unfused path on the CPU proxy
     # (parallel.tensor_parallel.resolve_tp_overlap).
     tp_overlap: str = "none"
+    # nemotron_h only, under the names of the source's ``config.json``
+    # (hidden_size, num_attention_heads, num_key_value_heads, head_dim,
+    # layer_norm_epsilon and vocab_size are ``dim``, ``n_heads``,
+    # ``n_kv_heads``, ``head_dim_override``, ``rms_eps`` and ``vocab_size``).
+    # One letter a layer; ``n_layers`` is its length.
+    hybrid_override_pattern: Optional[str] = None
+    mamba_num_heads: int = 64
+    mamba_head_dim: int = 64
+    n_groups: int = 8
+    ssm_state_size: int = 128
+    conv_kernel: int = 4
+    chunk_size: int = 128
+    time_step_min: float = 0.001
+    time_step_max: float = 0.1
+    time_step_floor: float = 1e-4
+    n_routed_experts: int = 128  # the router's width, whatever is held here
+    num_experts_per_tok: int = 6
+    moe_intermediate_size: int = 1856
+    moe_shared_expert_intermediate_size: int = 3712
+    routed_scaling_factor: float = 2.5
+    # The ids (of ``n_routed_experts``) whose weights this rank holds; None =
+    # all. A token's weights are normalised over ALL its chosen experts and
+    # only the held ones' outputs are computed (``ops/experts.py``).
+    experts_held: Optional[Tuple[int, ...]] = None
 
     def __post_init__(self):
         if self.dim % self.n_heads != 0:
             raise ValueError(f"dim={self.dim} must be divisible by n_heads={self.n_heads}")
         if self.n_kv_heads is not None and self.n_heads % self.n_kv_heads != 0:
             raise ValueError(f"n_heads={self.n_heads} must be divisible by n_kv_heads={self.n_kv_heads}")
-        if self.arch not in ("ref_decoder", "gpt2", "llama"):
+        if self.arch not in ("ref_decoder", "gpt2", "llama", "nemotron_h"):
             raise ValueError(f"unknown arch {self.arch!r}")
+        if self.arch == "nemotron_h":
+            self._check_nemotron_h()
+        elif self.hybrid_override_pattern is not None:
+            raise ValueError("hybrid_override_pattern requires "
+                             "arch='nemotron_h'")
         if self.attention_qkv_bias and self.arch != "llama":
             raise ValueError("attention_qkv_bias requires arch='llama' "
                              "(Qwen2-family blocks; gpt2/ref biases are "
@@ -129,10 +164,13 @@ class ModelConfig:
         if self.mlp_act not in ("silu", "gelu"):
             raise ValueError(f"mlp_act={self.mlp_act!r} must be 'silu' or "
                              f"'gelu'")
-        if ((self.head_dim_override is not None or self.mlp_act != "silu")
-                and self.arch != "llama"):
-            raise ValueError("head_dim_override / mlp_act are Gemma-family "
-                             "knobs on arch='llama' blocks")
+        if self.mlp_act != "silu" and self.arch != "llama":
+            raise ValueError("mlp_act is a Gemma-family knob on arch='llama' "
+                             "blocks")
+        if (self.head_dim_override is not None
+                and self.arch not in ("llama", "nemotron_h")):
+            raise ValueError("head_dim_override is a knob of arch='llama' "
+                             "(Gemma-family) and arch='nemotron_h' blocks")
         if self.embed_scale and self.arch == "ref_decoder":
             raise ValueError("embed_scale applies to gpt2/llama blocks "
                              "(Gemma-style scaled embeddings; gpt2 is "
@@ -163,6 +201,42 @@ class ModelConfig:
                 "dropout (torch applies dropout to attention weights, so "
                 "silently skipping it would change train-mode semantics; "
                 "'auto' resolves to the dense path under dropout)")
+
+    def _check_nemotron_h(self) -> None:
+        pattern = self.hybrid_override_pattern
+        if not pattern:
+            raise ValueError("arch='nemotron_h' needs hybrid_override_pattern "
+                             "(one of 'M', '*', 'E' a layer)")
+        unknown = sorted(set(pattern) - set("M*E"))
+        if unknown:
+            raise ValueError(
+                f"hybrid_override_pattern {pattern!r}: unknown layer kind(s) "
+                f"{unknown}; 'M' is Mamba-2, '*' attention, 'E' experts")
+        if self.n_layers != len(pattern):
+            raise ValueError(f"n_layers={self.n_layers} is not the length of "
+                             f"hybrid_override_pattern {pattern!r}")
+        if self.mamba_num_heads % self.n_groups:
+            raise ValueError(f"mamba_num_heads={self.mamba_num_heads} must be "
+                             f"divisible by n_groups={self.n_groups}")
+        held = self.held_experts
+        if (len(set(held)) != len(held) or not held
+                or min(held) < 0 or max(held) >= self.n_routed_experts):
+            raise ValueError(f"experts_held={held} must be distinct ids of "
+                             f"the {self.n_routed_experts} routed experts")
+        if not 1 <= self.num_experts_per_tok <= self.n_routed_experts:
+            raise ValueError(f"num_experts_per_tok={self.num_experts_per_tok}")
+        if self.tie_embeddings or self.dropout or self.pad_token_id is not None:
+            raise NotImplementedError(
+                "arch='nemotron_h' has an untied head and no dropout or pad "
+                "masking: tie_embeddings, dropout and pad_token_id are not "
+                "written for its layers")
+
+    @property
+    def held_experts(self) -> Tuple[int, ...]:
+        """The routed experts' ids this rank holds (nemotron_h)."""
+        if self.experts_held is None:
+            return tuple(range(self.n_routed_experts))
+        return tuple(self.experts_held)
 
     @property
     def causal(self) -> bool:

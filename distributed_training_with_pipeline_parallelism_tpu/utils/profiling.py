@@ -81,6 +81,17 @@ def annotated_steps(steps: Iterable[int],
 #: regions too, wherever no name of this list is further in.
 REGIONS = ("model/embed", "model/layers", "model/attn", "model/mlp",
            "model/head_loss", "train/optimizer")
+#: The ``nemotron_h`` layers' own four, beside ``model/attn`` for the
+#: attention layer, disjoint by the innermost-wins rule: ``model/ssm``
+#: (``models/nemotron_h.py``: a Mamba-2 mixer but its scan — norm, both
+#: projections, convolution, gate, group norm), ``model/ssm_scan``
+#: (``ops/mamba2.py:ssd_chunked``), ``model/moe`` (norm, router, top-k,
+#: the gate, shared expert) and ``model/moe_experts``
+#: (``ops/experts.py:held_experts``, the held experts' two gated
+#: products). Kept apart from :data:`REGIONS`, which every dense step names
+#: whole (``benchmark/tests/test_bench_scopes.py`` holds it to that).
+HYBRID_REGIONS = ("model/ssm", "model/ssm_scan", "model/moe",
+                  "model/moe_experts")
 UNSCOPED = "unscoped"
 
 #: In this order, the first mark an op_name holds gives its phase. JAX writes
@@ -91,7 +102,10 @@ PHASE_MARKS = (("train/optimizer", "optimizer"),
                ("rematted_computation", "recompute"),
                ("transpose(", "backward"))
 
-_REGION = re.compile("|".join(re.escape(r) for r in REGIONS))
+# longest first: ``model/ssm_scan`` is not ``model/ssm`` with a tail
+_REGION = re.compile("|".join(
+    re.escape(r) for r in sorted(REGIONS + HYBRID_REGIONS, key=len,
+                                 reverse=True)))
 _PP = re.compile(r"pp/[A-Za-z_]+")  # pp/tick003 -> pp/tick, pp/phase2 -> pp/phase
 # The tick executors' backward units: each re-runs its stage's forward by
 # hand (``jax.vjp`` inside the tick), which JAX marks ``jvp(``, not
@@ -127,7 +141,8 @@ def classify(op_name: str) -> Tuple[str, str]:
     compiled HLO carries (``jit(train_step)/transpose(jvp())/while/body/
     closed_call/checkpoint/model/mlp/dot_general``).
 
-    ``region`` is the innermost name of :data:`REGIONS` in it, else the
+    ``region`` is the innermost name of :data:`REGIONS` or
+    :data:`HYBRID_REGIONS` in it, else the
     innermost ``pp/...`` scope (tick and phase numbers dropped), else
     ``"unscoped"``. ``phase`` is ``optimizer``, ``recompute``, ``backward``
     or ``forward`` by :data:`PHASE_MARKS` and the rule for the executors'

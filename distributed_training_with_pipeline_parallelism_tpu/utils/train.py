@@ -370,8 +370,12 @@ def adamw(learning_rate: float = 3e-4, weight_decay: float = 0.01,
     (decaying LayerNorm scales toward zero actively hurts). Leaf ndim
     cannot distinguish these in the stacked-layer layout (a per-layer bias
     stack is 2-D), so the mask keys off this framework's naming
-    convention: matrices live under "w" (linear/attention/router) and
-    "w1"/"w2" (MoE expert stacks)."""
+    convention: matrices live under "w" (linear/attention/router, and the
+    nemotron_h layers' projections and convolution) and "w1"/"w2" (expert
+    stacks, ``models/moe.py``'s and ``ops/experts.py``'s). The nemotron_h
+    leaves that are no matrices — ``A_log``, ``D``, ``dt_bias``, the
+    router's ``bias`` buffer, norm scales — carry other names and are not
+    decayed."""
     lr = optax.warmup_cosine_decay_schedule(
         init_value=0.0, peak_value=learning_rate, warmup_steps=warmup_steps,
         decay_steps=max(total_steps, warmup_steps + 1))
@@ -469,7 +473,8 @@ def fit(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig, params: Pytree,
         guard=None, fault_plan=None,
         handle_preemption: bool = False,
         stall_timeout_s: Optional[float] = None,
-        dynamics=None):
+        dynamics=None,
+        on_log: Optional[Callable[[int, Pytree, jax.Array], None]] = None):
     """Training loop over a ``(tokens, targets)`` iterator.
 
     Returns (params, list of (step, loss)). ``params`` is CONSUMED: it is
@@ -555,6 +560,10 @@ def fit(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig, params: Pytree,
       ``report_dir``). With ``guard`` set, skipped steps additionally emit
       an ``anomaly_attributed`` event naming the first non-finite stage.
       ``dynamics=None`` (default) leaves the compiled step byte-identical.
+    - ``on_log``: called as ``on_log(step, params, tokens)`` at every log
+      point, after the loss is read — for what a caller wants said about
+      its own model there (``scripts/train.py`` logs an expert model's
+      routing). It must not keep or donate ``params``.
     """
     from .resilience import (AnomalyBudgetExceeded, AnomalyGuard,
                              CheckpointManager, PreemptionHandler,
@@ -854,6 +863,8 @@ def fit(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig, params: Pytree,
                     history.append((i, loss_f))
                     if verbose:
                         print(f"step {i}: loss {loss_f:.4f}", flush=True)
+                    if on_log is not None:
+                        on_log(i, params, tokens)
                     if metrics_path:
                         with open(metrics_path, "a") as f:
                             f.write(json.dumps({

@@ -28,7 +28,10 @@ def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--model", default="gpt2-small",
                     help="gpt2-{small,medium,large,xl}, llama2-7b, llama3-8b, "
-                         "llama-debug, or ref (the reference parity model)")
+                         "llama-debug, nemotron-h-{stage,debug} (a patterned "
+                         "Mamba-2 / attention / expert stack: --pipe 1, no "
+                         "--tp/--sp/--ep), or ref (the reference parity "
+                         "model)")
     ap.add_argument("--schedule", default="1F1B", choices=list(SCHEDULE_NAMES))
     ap.add_argument("--pipe", type=int, default=2)
     ap.add_argument("--data", type=int, default=1)
@@ -223,6 +226,11 @@ def main(argv=None):
             return gpt2_config(args.model.removeprefix("gpt2-"), **overrides)
         if args.model.startswith(("llama", "mistral", "qwen2", "gemma")):
             return llama_config(args.model, **overrides)
+        if args.model.startswith("nemotron-h-"):
+            from distributed_training_with_pipeline_parallelism_tpu.models.nemotron_h import (
+                nemotron_h_config)
+            return nemotron_h_config(args.model.removeprefix("nemotron-h-"),
+                                     **overrides)
         if args.model == "ref":
             return dtpp.ModelConfig(**overrides)
         raise SystemExit(f"unknown model {args.model}")
@@ -347,6 +355,16 @@ def main(argv=None):
             eval_data = lambda: train.synthetic_data(  # noqa: E731
                 cfg, args.batch, args.seq, seed=123)
 
+    on_log = None
+    if cfg.arch == "nemotron_h" and "E" in cfg.hybrid_override_pattern:
+        from distributed_training_with_pipeline_parallelism_tpu.models.nemotron_h import (
+            describe_routing, routing_stats)
+
+        def on_log(i, params, tokens):  # one more forward pass a log point
+            print(f"step {i} routing: "
+                  f"{describe_routing(routing_stats(cfg, params, tokens))}",
+                  flush=True)
+
     params, history = train.fit(
         cfg, mesh, sched, params, data, args.steps, optimizer=optimizer,
         log_every=max(1, args.steps // 20),
@@ -367,7 +385,7 @@ def main(argv=None):
         handle_preemption=args.preemption_safe,
         stall_timeout_s=args.stall_timeout or None,
         report_dir=args.report_dir or None,
-        dynamics=args.dynamics or None)
+        dynamics=args.dynamics or None, on_log=on_log)
     if args.ckpt:
         print(f"checkpoints in {args.ckpt}", flush=True)
     if history:
