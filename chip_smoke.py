@@ -41,9 +41,11 @@ ROOT = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, ROOT)
 
 BATCH, SEQ = 8, 1024  # every training phase: 8 sequences of 1024 tokens
-# gpt2-medium at that batch; then the ragged length whose backward the v5e
-# compiler used to refuse (one block spans the row)
-FLASH_SHAPES = ((BATCH, SEQ, 16, 64), (2, 1000, 12, 64))
+# gpt2-medium at that batch (packed kernels, static causal strips); a
+# gpt2-xl pipeline microbatch (25 heads: odd, so the classic form, strips
+# too); then the ragged length whose backward the v5e compiler used to
+# refuse (one block spans the row, no whole strips: the one-tile form)
+FLASH_SHAPES = ((BATCH, SEQ, 16, 64), (2, SEQ, 25, 64), (2, 1000, 12, 64))
 XENT_SHAPE = (BATCH * SEQ, 50257)
 
 # bf16 keeps 8 bits of mantissa (eps = 2**-8 = 3.9e-3). A kernel and its

@@ -59,6 +59,19 @@ Measured kernel disciplines (rounds 3-4, one v5e chip — docs/profiles/):
      of twice (the round-3 form ran separate dq and dk/dv kernels, each
      redoing s, exp and dp — 7 block matmuls and ~2x the VPU work per
      pair vs 5 matmuls here).
+- **Dead tiles** (PR 31): with ONE block a row (every s <= 1024,
+  :func:`_auto_block`) the diagonal block of (2) is the whole [s, s]
+  square, so the one-tile body did twice the causal work: at 8 x 16 heads
+  x 1024 x 64 the forward ran at 30% and the backward at 46% of the bf16
+  peak on what they EXECUTED, 15% and 23% on what the mask keeps. Every
+  product here has the 64 in its contraction or its output width and fills
+  half of the 128 x 128 array (two heads stacked in one product stream the
+  same rows through the same weight tiles: no lever), so about half the
+  peak is the ceiling of the geometry and the square was most of the
+  distance to it. Where the row is a whole number of strips both kernels
+  now cut the triangle statically inside the one block (the section
+  "Static causal strips" below: forward 590 -> 368 us, backward 958 -> 605
+  us a call; the classic form at 2 x 25 heads 215 -> 134 and 358 -> 228).
 
 Layout (round 4): the training hot path (plain causal, full-length,
 head_dim 64/128) runs the HEAD-PACKED kernels — inputs stay [b, s, h*dh]
@@ -80,12 +93,15 @@ the same code paths.
 from __future__ import annotations
 
 import functools
+import logging
 import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+logger = logging.getLogger(__name__)
 
 NEG_INF = -1e30
 LOG2E = 1.4426950408889634
@@ -134,9 +150,181 @@ def _make_block_mask(qi_base, block_shape, causal: bool, true_len: int,
     return mask
 
 
+def _causal_tile(n: int, keys_down: bool = False):
+    """The additive mask of a diagonal [n, n] tile of scores [queries, keys]
+    (``keys_down``: [keys, queries]): 0 where the key is at or before the
+    query, NEG_INF elsewhere (one add an element, no compare + select)."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
+    return jnp.where(rows <= cols if keys_down else rows >= cols, 0.0,
+                     NEG_INF)
+
+
+# ---------------------------------------------------------------------------
+# Static causal strips (PR 31). Where ONE block spans the row (every
+# s <= 1024, see _auto_block) the "diagonal block" of the static split is the
+# whole [s, s] square: the one-tile body forms every score, exponential and
+# product of it, and the mask zeroes half. The whole row's q, k, v (and do)
+# are resident in that program anyway, so the triangle is cut into s/t strips
+# of t query rows by a Python loop: static slices, no fori_loop, no cond, no
+# program_id in a bound, no online rescale, the grid unchanged. Only the
+# [t, t] tiles on the diagonal are masked; above them nothing is formed.
+#
+# What the chip said of the ways to cut it (v5e, kernel microseconds a call,
+# PERF.md section 6, PR 31): a product streams its left operand's rows through
+# the weight tiles its right operand makes, and a strip that streams only t
+# rows a tile pays for the tiles, not for the area (key strips of 256 rows in
+# the backward: 921 us against the square's 958; query strips of 256 rows in
+# an untransposed forward: 471 against 590). So both kernels form a strip's
+# scores TRANSPOSED, [keys, queries]: ``k[0:r1] @ q.T`` streams the strip's
+# keys, the long side; the softmax statistics, lse and delta are [1, t] lane
+# rows, which is what they are stored as (no lane-to-sublane relayout), and
+# the reductions run down the sublanes. The forward then takes
+# ``o.T = v.T @ p.T``, whose weights ARE p.T (no transpose of the
+# probabilities): 590 -> 368 us with two strips of 512 (432 untransposed
+# strips, 433 transposed but uncut). The backward's ``p.T @ do`` and
+# ``ds.T @ q`` are plain products, one transpose (``ds @ k``) is left of two:
+# 958 -> 605 us with eight strips of 128, 36 of 64 tiles.
+# ---------------------------------------------------------------------------
+
+# Strip heights in the order they are taken, per kernel: the first that cuts
+# the row into >= 2 whole strips. Multiples of 128, so that the lse / delta
+# rows are cut at whole lane tiles. Measured at s = 1024, 512 and 256.
+_STRIP_ROWS = {"fwd": (512, 256, 128), "bwd": (128,)}
+
+_NT = (((1,), (1,)), ((), ()))  # a @ b.T
+_TN = (((0,), (0,)), ((), ()))  # a.T @ b
+
+# What the strip bodies are told of each head of a block: its lane slice and
+# the index of its lse / delta row, kept [1, s]. The classic blocks hold one
+# head.
+_ONE_HEAD = ((slice(None), (0, slice(0, 1))),)
+
+
+def _slab_heads(hp: int, dh: int):
+    """The ``hp`` heads of a packed 128-lane slab."""
+    return [(slice(p * dh, (p + 1) * dh), (0, 0, slice(p, p + 1)))
+            for p in range(hp)]
+
+
+def causal_strips(s: int, t: int) -> tuple[int, int]:
+    """``(live_tiles, square_tiles)``: of the ``(s/t)**2`` [t, t] tiles of
+    the causal square, those at or below the diagonal — what the strip form
+    computes against what the one-tile form does (10 of 16 at s/t = 4)."""
+    n = s // t
+    return n * (n + 1) // 2, n * n
+
+
+def _strip_rows(s: int, block_q: int, block_k: int, causal: bool,
+                window: Optional[int], kernel: str) -> Optional[int]:
+    """Strip height of the ``kernel`` ("fwd" / "bwd") for a call, or None
+    where it keeps the form it had: a window, a non-causal call, unequal
+    blocks, blocks that do not span the row (every s > 1024: those skip dead
+    blocks already), or a row that is no whole number >= 2 of strips (a
+    ragged s). A function of what the call can see, nothing to tune."""
+    if not (causal and window is None and block_q == block_k == s):
+        return None
+    return next((t for t in _STRIP_ROWS[kernel]
+                 if s % t == 0 and s // t >= 2), None)
+
+
+def _fwd_strips(q_ref, k_ref, v_ref, o_ref, lse_ref, heads, t: int,
+                scale: float):
+    """The one-block causal forward on strips of ``t`` query rows, scores
+    TRANSPOSED: strip [r0, r1) forms ``s.T = k @ q.T`` against keys [0, r1)
+    only — the diagonal [t, t] tile, masked, and the interior [r0, t],
+    unmasked — takes ONE softmax pass down the trapezoid's columns (joint
+    max, exp2, column sum: [1, t] rows, as lse is stored), and
+    ``o.T = v.T @ p.T`` with p.T as the product's weights. ``heads``: per
+    head its lane slice of the blocks and the index of its [1, s] lse row
+    (the packed kernels pass a slab's heads). A head's q, k, v leave the
+    blocks once, as in the one-tile form; the strips slice those values,
+    which moves nothing."""
+    s = q_ref.shape[1]
+    diag_add = _causal_tile(t, keys_down=True)
+    for lane, row in heads:
+        q = q_ref[0, :, lane]
+        q = (q.astype(jnp.float32) * (scale * LOG2E)).astype(q.dtype)
+        k = k_ref[0, :, lane]
+        v_t = v_ref[0, :, lane].T  # [dh, s]
+        for r0 in range(0, s, t):
+            r1 = r0 + t
+            keys = [slice(r0, r1)] + ([slice(0, r0)] if r0 else [])
+            scores = [jax.lax.dot_general(k[ks], q[r0:r1], _NT,
+                                          preferred_element_type=jnp.float32)
+                      for ks in keys]
+            scores[0] = scores[0] + diag_add
+            m = functools.reduce(jnp.maximum, [
+                jnp.max(x, axis=0, keepdims=True) for x in scores])
+            l = o_t = 0.0
+            for ks, x in zip(keys, scores):
+                p = jnp.exp2(x - m)
+                l = l + jnp.sum(p, axis=0, keepdims=True)
+                o_t = o_t + jax.lax.dot(v_t[:, ks], p.astype(v_t.dtype),
+                                        preferred_element_type=jnp.float32)
+            # l >= 1: every query's own key is live
+            o_ref[0, r0:r1, lane] = (o_t / l).T.astype(o_ref.dtype)
+            lse_ref[row + (slice(r0, r1),)] = m + jnp.log2(l)
+
+
+def _bwd_strips(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+                dk_ref, dv_ref, heads, t: int, scale: float):
+    """The one-block causal backward on strips of ``t`` query rows, scores
+    transposed as in :func:`_fwd_strips`: strip [r0, r1) forms ``s.T =
+    k[0:r1] @ q.T`` and ``dp.T = v[0:r1] @ do.T`` as [r1, t] (its last
+    [t, t] tile masked), lse and delta subtract as [1, t] rows, ``p.T @ do``
+    and ``ds.T @ q`` are plain products whose row strips add into dv and dk
+    of keys [0, r1), and the strip's dq = ``ds @ k`` comes out whole (the
+    one transpose; nothing is zeroed, nothing accumulates in the block).
+    ``heads`` as in :func:`_fwd_strips`."""
+    s = q_ref.shape[1]
+    n = s // t
+    diag_add = _causal_tile(t, keys_down=True)
+    for lane, row in heads:
+        q = q_ref[0, :, lane]  # unscaled
+        # exp2-domain fold, matching the forward's lse
+        qc = (q.astype(jnp.float32) * (scale * LOG2E)).astype(q.dtype)
+        do = do_ref[0, :, lane]
+        k = k_ref[0, :, lane]
+        v = v_ref[0, :, lane]
+        dk = [0.0] * n
+        dv = [0.0] * n
+        for i in range(n):
+            r0, r1 = i * t, (i + 1) * t
+            cols = row + (slice(r0, r1),)
+            x = jax.lax.dot_general(k[:r1], qc[r0:r1], _NT,
+                                    preferred_element_type=jnp.float32)
+            x = (jnp.concatenate([x[:r0], x[r0:] + diag_add]) if r0
+                 else x + diag_add)
+            p = jnp.exp2(x - lse_ref[cols])
+            dp = jax.lax.dot_general(v[:r1], do[r0:r1], _NT,
+                                     preferred_element_type=jnp.float32)
+            ds = (p * (dp - delta_ref[cols])).astype(q.dtype)
+            dv_i = jax.lax.dot(p.astype(do.dtype), do[r0:r1],
+                               preferred_element_type=jnp.float32)
+            dk_i = jax.lax.dot(ds, q[r0:r1],
+                               preferred_element_type=jnp.float32)
+            for j in range(i + 1):
+                dv[j] = dv[j] + dv_i[j * t:(j + 1) * t]
+                dk[j] = dk[j] + dk_i[j * t:(j + 1) * t]
+            # dq rides unscaled f32; the caller applies `scale`
+            dq_ref[0, r0:r1, lane] = jax.lax.dot_general(
+                ds, k[:r1], _TN, preferred_element_type=jnp.float32)
+        for j in range(n):
+            rows = slice(j * t, (j + 1) * t)
+            # q was unscaled in the dk product, so the scale applies once here
+            dk_ref[0, rows, lane] = (dk[j] * scale).astype(dk_ref.dtype)
+            dv_ref[0, rows, lane] = dv[j].astype(dv_ref.dtype)
+
+
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
                       causal: bool, scale: float, seq_len: int,
-                      true_len: int, window: Optional[int]):
+                      true_len: int, window: Optional[int],
+                      strip: Optional[int]):
+    if strip is not None:
+        _fwd_strips(q_ref, k_ref, v_ref, o_ref, lse_ref, _ONE_HEAD, strip,
+                    scale)
+        return
     qi = _block_index(1, seq_len // q_ref.shape[1])
     # exp2-domain scores: scale*log2e folds into the [block_q, dh] q tile
     # so the per-element softmax path has no multiplies (module docstring)
@@ -202,9 +390,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
               jnp.zeros((block_q, dh), jnp.float32))
     if diag_split:
         # diagonal tile: rc >= 0 is instance-invariant at bq == bk
-        rows = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-        cols = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-        diag_add = jnp.where(rows >= cols, 0.0, NEG_INF)
+        diag_add = _causal_tile(block_q)
         m, l, acc = jax.lax.fori_loop(0, qi, make_body(None), carry0)
         m, l, acc = make_body(lambda s, _: s + diag_add)(qi, (m, l, acc))
     else:
@@ -241,9 +427,10 @@ def _flash_fwd(q: jax.Array, k: jax.Array, v: jax.Array, causal: bool,
         pad = ((0, 0), (0, s_pad - s), (0, 0))
         q, k, v = (jnp.pad(x, pad) for x in (q, k, v))
     grid = (bh, s_pad // block_q)
-    kernel = functools.partial(_flash_fwd_kernel, block_k=block_k,
-                               causal=causal, scale=scale, seq_len=s_pad,
-                               true_len=s, window=window)
+    kernel = functools.partial(
+        _flash_fwd_kernel, block_k=block_k, causal=causal, scale=scale,
+        seq_len=s_pad, true_len=s, window=window,
+        strip=_strip_rows(s, block_q, block_k, causal, window, "fwd"))
     out, lse = pl.pallas_call(
         kernel,
         out_shape=(jax.ShapeDtypeStruct(q.shape, q.dtype),
@@ -264,7 +451,7 @@ def _flash_fwd(q: jax.Array, k: jax.Array, v: jax.Array, causal: bool,
 def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                       dq_ref, dk_ref, dv_ref, *, block_q: int, causal: bool,
                       scale: float, seq_len: int, true_len: int,
-                      window: Optional[int]):
+                      window: Optional[int], strip: Optional[int]):
     """One-sweep backward: grid (batch*heads, k blocks). Each instance owns
     one k block, loops over its live q blocks, accumulates dk/dv in f32
     carries, and accumulates dq into a grid-revisited f32 VMEM output block
@@ -272,6 +459,10 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     across the sweep; zeroed when the sweep starts). Scores, probabilities
     and dp are computed once per (q, k) block pair — the round-3 two-kernel
     form computed each twice."""
+    if strip is not None:
+        _bwd_strips(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+                    dk_ref, dv_ref, _ONE_HEAD, strip, scale)
+        return
     ki = _block_index(1, seq_len // k_ref.shape[1])
     k = k_ref[0]  # [block_k, dh], storage dtype
     v = v_ref[0]
@@ -417,9 +608,10 @@ def _flash_bwd(q, k, v, o, lse, g, causal, block_q, block_k, window):
         q, k, v, g = (jnp.pad(x, pad3) for x in (q, k, v, g))
         delta = jnp.pad(delta, ((0, 0), (0, 0), (0, s_pad - s)))
     dq, dk, dv = pl.pallas_call(
-        functools.partial(_flash_bwd_kernel, block_q=block_q, causal=causal,
-                          scale=scale, seq_len=s_pad, true_len=s,
-                          window=window),
+        functools.partial(
+            _flash_bwd_kernel, block_q=block_q, causal=causal, scale=scale,
+            seq_len=s_pad, true_len=s, window=window,
+            strip=_strip_rows(s, block_q, block_k, causal, window, "bwd")),
         out_shape=(jax.ShapeDtypeStruct(q.shape, jnp.float32),  # dq, f32
                    jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)),
@@ -487,14 +679,16 @@ def _packed_ok(s, h, dh, causal, window, block_q, block_k, itemsize=2):
 
 def _flash_fwd_kernel_packed(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
                              block_k: int, dh: int, hp: int, scale: float,
-                             seq_len: int):
+                             seq_len: int, strip: Optional[int]):
+    if strip is not None:
+        _fwd_strips(q_ref, k_ref, v_ref, o_ref, lse_ref, _slab_heads(hp, dh),
+                    strip, scale)
+        return
     qi = _block_index(1, seq_len // q_ref.shape[1])
     q2 = q_ref[0]  # [block_q, hp*dh]
     block_q = q2.shape[0]
     c = scale * LOG2E
-    rows = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-    diag_add = jnp.where(rows >= cols, 0.0, NEG_INF)
+    diag_add = _causal_tile(block_q)
 
     for p in range(hp):
         sl = slice(p * dh, (p + 1) * dh)
@@ -531,7 +725,11 @@ def _flash_fwd_kernel_packed(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
 def _flash_bwd_kernel_packed(q_ref, k_ref, v_ref, do_ref, lse_ref,
                              delta_ref, dq_ref, dk_ref, dv_ref, *,
                              block_q: int, dh: int, hp: int, scale: float,
-                             seq_len: int):
+                             seq_len: int, strip: Optional[int]):
+    if strip is not None:
+        _bwd_strips(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+                    dk_ref, dv_ref, _slab_heads(hp, dh), strip, scale)
+        return
     block_k = k_ref.shape[1]
     ki = _block_index(1, seq_len // block_k)
     n_q = seq_len // block_q
@@ -541,9 +739,7 @@ def _flash_bwd_kernel_packed(q_ref, k_ref, v_ref, do_ref, lse_ref,
     def _zero_dq():
         dq_ref[...] = jnp.zeros_like(dq_ref)
 
-    rows = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-    diag_add = jnp.where(rows >= cols, 0.0, NEG_INF)
+    diag_add = _causal_tile(block_q)
 
     for p in range(hp):
         sl = slice(p * dh, (p + 1) * dh)
@@ -594,8 +790,9 @@ def _flash_fwd_packed(q, k, v, h, block_q, block_k):
     nhp = h // hp
     scale = 1.0 / (dh ** 0.5)
     grid = (b * nhp, s // block_q)
-    kernel = functools.partial(_flash_fwd_kernel_packed, block_k=block_k,
-                               dh=dh, hp=hp, scale=scale, seq_len=s)
+    kernel = functools.partial(
+        _flash_fwd_kernel_packed, block_k=block_k, dh=dh, hp=hp, scale=scale,
+        seq_len=s, strip=_strip_rows(s, block_q, block_k, True, None, "fwd"))
     slab = hp * dh  # = 128 lanes
 
     out, lse = pl.pallas_call(
@@ -631,8 +828,9 @@ def _flash_bwd_packed(q, k, v, o, lse, g, h, block_q, block_k):
     delta = jnp.sum((g.astype(jnp.float32) * o.astype(jnp.float32))
                     .reshape(b, s, h, dh), axis=-1)
     delta = delta.reshape(b, s, nhp, hp).transpose(0, 2, 3, 1)
-    kernel = functools.partial(_flash_bwd_kernel_packed, block_q=block_q,
-                               dh=dh, hp=hp, scale=scale, seq_len=s)
+    kernel = functools.partial(
+        _flash_bwd_kernel_packed, block_q=block_q, dh=dh, hp=hp, scale=scale,
+        seq_len=s, strip=_strip_rows(s, block_q, block_k, True, None, "bwd"))
     dq, dk, dv = pl.pallas_call(
         kernel,
         out_shape=(jax.ShapeDtypeStruct(q.shape, jnp.float32),  # dq f32
@@ -717,10 +915,17 @@ _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 def _auto_block(s: int) -> int:
     """Default kernel block (v5e measurements, docs/performance.md).
 
-    s <= 1024: ONE block covers the whole row — no interior k loop, the
-    diagonal-split tile is the entire score matrix; measured fastest
-    (round 4: fwd 0.94 -> 0.83 ms at gpt2-small shapes vs 512 blocks,
-    and `min(block, s)` keeps short rows unpadded). Beyond 1024 the
+    s <= 1024: ONE block covers the whole row — one grid step a row, no
+    interior k loop with a `program_id` trip count (round 4 measured that
+    loop SLOWER at blocks of 512, fwd 0.94 against 0.83 ms at gpt2-small
+    shapes, although it skips a tile in four: a dynamic bound costs Mosaic
+    its software pipelining), and `min(block, s)` keeps short rows
+    unpadded. Under a causal mask the one [s, s] tile costs the whole
+    square for half of it (8 x 16 heads x 1024: forward 590 us, backward
+    958 us a call; chip, PR 31); where the row is a whole number of
+    strips the kernels therefore cut the triangle STATICALLY inside the
+    one block (:func:`_strip_rows`: forward 368 us, backward 605 us),
+    and a ragged row keeps the square. Beyond 1024 the
     [bq, bk] f32 tiles exceed VMEM at block 1024 (the backward fails to
     compile) and 512 measured up to ~20% (fwd) / ~34% (grad) faster per
     row than 256; estimated time ~ padded_length / per-row-speed, so 256
@@ -759,13 +964,19 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     (Mosaic constraint; :func:`_auto_block`'s 256/512/1024 are always
     safe, and CPU interpret mode takes any block, which is what the
     small-block unit tests use). ``block_q``/``block_k`` default to :func:`_auto_block`
-    (512, or 256 where it avoids a dead padding block); both kernels keep
-    one [block_q, block_k] f32 tile plus the full per-(batch, head) K/V
-    in VMEM, so block size trades tile-reuse against grid parallelism,
-    not memory. ``window`` (requires ``causal``) applies the Mistral
-    sliding-window band: both directions skip K/V (resp. Q) blocks entirely
-    outside ``[i - window + 1, i]``, so long-sequence *compute* scales with
-    the window. K/V VMEM residency still scales with the sequence (the
+    (the whole row up to 1024; beyond it 512, or 256 where it avoids a dead
+    padding block); both kernels keep one [block_q, block_k] f32 tile plus
+    the full per-(batch, head) K/V in VMEM, so block size trades tile-reuse
+    against grid parallelism, not memory. Where one block spans a plain
+    causal row of a whole number of strips (every ``s`` <= 1024 that is a
+    multiple of 128, from 256), both kernels leave the dead half of the
+    square unformed (:func:`_strip_rows`, :func:`causal_strips`): nothing
+    to pass, and one ``logging.INFO`` line a traced call says which form
+    runs, at what strip heights, on how many tiles of the square.
+    ``window`` (requires ``causal``) applies the Mistral sliding-window
+    band: both directions skip K/V (resp. Q) blocks entirely outside
+    ``[i - window + 1, i]``, so long-sequence *compute* scales with the
+    window. K/V VMEM residency still scales with the sequence (the
     whole [s, dh] K/V maps in per (batch, head)); truly long sequences
     should shard over a 'seq' mesh axis instead (ring attention).
     """
@@ -788,8 +999,17 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                 f"clamp to the sequence)")
     block_q = block_q or min(_auto_block(s), s)
     block_k = block_k or min(_auto_block(s), s)
-    if _packed_ok(s, h, dh, causal, window, block_q, block_k,
-                  q.dtype.itemsize):
+    packed = _packed_ok(s, h, dh, causal, window, block_q, block_k,
+                        q.dtype.itemsize)
+    strips = {kernel: _strip_rows(s, block_q, block_k, causal, window, kernel)
+              for kernel in ("fwd", "bwd")}
+    logger.info(
+        "flash_attention: %s kernels, %d x %d x %d x %d, blocks %d x %d, %s",
+        "packed" if packed else "classic", b, s, h, dh, block_q, block_k,
+        "; ".join("%s strips of %d rows, %d of %d tiles of the causal square"
+                  % (kernel, t, *causal_strips(s, t))
+                  for kernel, t in strips.items() if t) or "no strips")
+    if packed:
         # transpose-free path: heads stay packed in the lane dimension
         # (see _flash_packed) — the [b,s,h,dh]->[b*h,s,dh] relayouts this
         # call otherwise pays were ~10% of a GPT-2 train step

@@ -9,7 +9,7 @@ import jax.numpy as jnp
 import distributed_training_with_pipeline_parallelism_tpu as dtpp
 from distributed_training_with_pipeline_parallelism_tpu.models import transformer as tfm
 from distributed_training_with_pipeline_parallelism_tpu.ops.pallas_attention import (
-    flash_attention)
+    _auto_block, _packed_ok, _strip_rows, causal_strips, flash_attention)
 
 
 def _full(q, k, v, causal):
@@ -213,10 +213,32 @@ def test_flash_unequal_blocks_multi_padded_kblocks(causal):
                                    atol=2e-5, rtol=2e-5)
 
 
+def _assert_matches_dense(q, k, v, g, **blocks):
+    """Forward and all three gradients of the causal kernels against
+    ``_full``."""
+    got = flash_attention(q, k, v, causal=True, **blocks)
+    want = _full(q, k, v, True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-5, rtol=2e-5)
+    gf = jax.grad(lambda q, k, v: jnp.vdot(
+        flash_attention(q, k, v, causal=True, **blocks), g),
+        argnums=(0, 1, 2))(q, k, v)
+    gd = jax.grad(lambda q, k, v: jnp.vdot(_full(q, k, v, True), g),
+                  argnums=(0, 1, 2))(q, k, v)
+    for a, b_ in zip(gf, gd):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                   atol=2e-5, rtol=2e-5)
+
+
+def _qkvg(seed, shape):
+    return (jax.random.normal(kk, shape)
+            for kk in jax.random.split(jax.random.key(seed), 4))
+
+
 @pytest.mark.parametrize("h,dh,block", [
     (4, 64, 128),   # 2 heads/slab, block tiles 128 lanes
     (2, 128, 128),  # hp=1 slab variant
-    (2, 64, 256),   # single-block row (block == s)
+    (2, 64, 256),   # single-block row (block == s): two strips of 128
 ])
 def test_flash_packed_head_path_matches_dense(h, dh, block):
     """The head-packed (transpose-free) kernels (round 4): heads stay in
@@ -226,27 +248,60 @@ def test_flash_packed_head_path_matches_dense(h, dh, block):
     so these configurations compile on the device, not just in interpret
     mode. Forward and grads vs dense."""
     b, s = 2, 256
-    ks = jax.random.split(jax.random.key(11), 4)
-    q = jax.random.normal(ks[0], (b, s, h, dh))
-    k = jax.random.normal(ks[1], (b, s, h, dh))
-    v = jax.random.normal(ks[2], (b, s, h, dh))
-    g = jax.random.normal(ks[3], (b, s, h, dh))
-    from distributed_training_with_pipeline_parallelism_tpu.ops.pallas_attention import (
-        _packed_ok)
     assert _packed_ok(s, h, dh, True, None, block, block)
     # sub-128 blocks must REJECT packing (Mosaic lowering would fail)
     assert not _packed_ok(s, h, dh, True, None, 64, 64)
-    got = flash_attention(q, k, v, causal=True, block_q=block,
+    _assert_matches_dense(*_qkvg(11, (b, s, h, dh)), block_q=block,
                           block_k=block)
-    want = _full(q, k, v, True)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               atol=2e-5, rtol=2e-5)
-    gf = jax.grad(lambda q, k, v: jnp.vdot(
-        flash_attention(q, k, v, causal=True, block_q=block,
-                        block_k=block), g),
-        argnums=(0, 1, 2))(q, k, v)
-    gd = jax.grad(lambda q, k, v: jnp.vdot(_full(q, k, v, True), g),
-                  argnums=(0, 1, 2))(q, k, v)
-    for a, b_ in zip(gf, gd):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
-                                   atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("s,h,dh,packed,t_fwd,t_bwd", [
+    (256, 1, 128, True, 128, 128),   # one head a slab; 3 of 4 tiles
+    (256, 3, 64, False, 128, 128),   # odd heads: the classic form
+    (512, 2, 64, True, 256, 128),    # 3 of 4 and 10 of 16 tiles
+    (512, 1, 64, False, 256, 128),
+    (1024, 2, 64, True, 512, 128),   # the benchmark cells' row: 3 of 4, 36 of 64
+])
+def test_flash_causal_strips_match_dense(s, h, dh, packed, t_fwd, t_bwd):
+    """One block spans the row (what ``flash_attention`` resolves to up to
+    1024), so both kernels run their static-strip bodies and form no dead
+    tile: same forward and gradients as dense, in the packed and in the
+    classic form, at every strip height the rule returns."""
+    assert bool(_packed_ok(s, h, dh, True, None, s, s)) is packed
+    assert _strip_rows(s, s, s, True, None, "fwd") == t_fwd
+    assert _strip_rows(s, s, s, True, None, "bwd") == t_bwd
+    _assert_matches_dense(*_qkvg(13, (1, s, h, dh)))
+
+
+@pytest.mark.parametrize("s,block_q,block_k,causal,window,want", [
+    (1024, 1024, 1024, True, None, (512, 128)),
+    (768, 768, 768, True, None, (256, 128)),
+    (640, 640, 640, True, None, (128, 128)),
+    (256, 256, 256, True, None, (128, 128)),
+    (128, 128, 128, True, None, (None, None)),    # one strip is no cut
+    (1024, 1024, 1024, True, 256, (None, None)),  # a window
+    (1000, 1000, 1000, True, None, (None, None)),  # ragged: no whole strips
+    (1024, 1024, 1024, False, None, (None, None)),  # nothing is dead
+    (1024, 1024, 512, True, None, (None, None)),  # unequal blocks
+    (2048, 512, 512, True, None, (None, None)),   # blocks: dead ones skipped
+])
+def test_flash_strip_rule(s, block_q, block_k, causal, window, want):
+    """Strips are chosen from what a call can see — plain causal, one block
+    spanning a row of >= 2 whole strips — and every other call keeps the
+    kernels it had."""
+    assert tuple(_strip_rows(s, block_q, block_k, causal, window, kernel)
+                 for kernel in ("fwd", "bwd")) == want
+    if s > 1024:  # what flash_attention resolves the blocks to by itself
+        assert min(_auto_block(s), s) == block_q
+
+
+def test_flash_causal_strips_count_and_log(caplog):
+    assert causal_strips(1024, 256) == (10, 16)
+    assert causal_strips(1024, 128) == (36, 64)
+    assert causal_strips(1024, 512) == (3, 4)
+    q = jax.ShapeDtypeStruct((2, 1024, 3, 64), jnp.float32)
+    with caplog.at_level("INFO"):
+        jax.eval_shape(lambda q: flash_attention(q, q, q, causal=True), q)
+    assert ("classic kernels, 2 x 1024 x 3 x 64, blocks 1024 x 1024, "
+            "fwd strips of 512 rows, 3 of 4 tiles of the causal square; "
+            "bwd strips of 128 rows, 36 of 64 tiles") in caplog.text
