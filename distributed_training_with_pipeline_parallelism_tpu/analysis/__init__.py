@@ -9,9 +9,9 @@ distributed_training_with_pipeline_parallelism_tpu.analysis``):
   compression roundtrips, unit counts, slot high-water marks (a static
   activation-memory bound), and per-channel comm volume (the unrolled
   executor's predicted ppermute count).
-- :mod:`.jaxpr_audit` — walks traced step functions: zero host callbacks
-  with telemetry off, collective counts/axes vs the mesh and the table
-  verifier's prediction, dtype drift.
+- :mod:`.jaxpr_audit` — walks traced step functions: zero host
+  callbacks, collective counts/axes vs the mesh and the table verifier's
+  prediction, dtype drift.
 - :mod:`.repo_lint` — ast rules: no host calls in tick/scan bodies,
   lazy-export discipline in ``__init__.py``, no bare ``jax.jit`` without
   a named scope in ``parallel/``, no raw host-clock step timing outside
@@ -20,13 +20,13 @@ distributed_training_with_pipeline_parallelism_tpu.analysis``):
   tables (FLOPs per F/B/W unit, bytes per ring hop, predicted step time
   under a :class:`~.cost_model.HardwareSpec`, table-exact/closed-form
   bubble fractions, MFU/HFU from measured step time) — the predicted
-  side of the predicted↔measured loop ``utils.telemetry`` closes
-  (docs/observability.md "Cost model & MFU").
+  side of the predicted↔measured comparison (docs/observability.md
+  "Cost model & MFU").
 - :mod:`.memory_model` — the bytes-domain twin of the cost model:
-  per-device HBM priced three ways (analytic slot-peaks x slot-bytes +
-  params/optimizer/KV, AOT-compiled ``memory_analysis()``, live
-  ``memory_stats()`` watermarks) and reconciled; source of the
-  sweep/bench OOM preflight and the byte-denominated search budgets
+  per-device HBM priced two ways (analytic slot-peaks x slot-bytes +
+  params/optimizer/KV, AOT-compiled ``memory_analysis()``) and
+  reconciled; source of the sweep's OOM preflight and the
+  byte-denominated search budgets
   (docs/observability.md "Memory observatory").
 - :mod:`.calibration` — the measured-probe leg that closes the loop on
   both models: a deterministic micro-probe harness
@@ -183,7 +183,6 @@ _LAZY = {
     "contiguous_slots_for_budget": ("memory_model",
                                     "contiguous_slots_for_budget"),
     "comm_overlap_step_time": ("cost_model", "comm_overlap_step_time"),
-    "predicted_tick_seconds": ("cost_model", "predicted_tick_seconds"),
     "memory_probe_axes": ("memory_model", "memory_probe_axes"),
     "CalibrationError": ("calibration", "CalibrationError"),
     "ProbeSpec": ("calibration", "ProbeSpec"),

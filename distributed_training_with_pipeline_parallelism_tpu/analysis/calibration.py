@@ -1,7 +1,8 @@
 """Calibration observatory: measured micro-probes vs. the analytical models.
 
-``analysis.cost_model`` and ``analysis.memory_model`` *predict*;
-``utils.telemetry`` *measures*. Nothing in between tracked the error —
+``analysis.cost_model`` and ``analysis.memory_model`` *predict*; a timed
+loop and the compiler's memory accounting *measure*. Nothing in between
+tracked the error —
 ``results/history.jsonl`` accumulates points but nobody computes, groups
 or guards the model residual, and the ROADMAP's auto-planner search
 ("validated by measured probes") needs exactly that layer. This module
@@ -10,8 +11,8 @@ closes the loop:
 - **Probes**: :func:`run_probe` executes one short measured run (a few
   warm steps of a tiny model on the live mesh) for one
   :class:`ProbeSpec` — schedule family x microbatch count x backward
-  policy x comm_overlap mode — and records the measured step time, the
-  telemetry-derived comm seconds and the compiled peak HBM side-by-side
+  policy x comm_overlap mode — and records the measured step time and
+  the compiled peak HBM side-by-side
   with every prediction variant the models quote (lockstep serial,
   optimistically overlapped, double-buffered comm_overlap, table-exact
   bubble, analytic peak bytes). :func:`probe_grid` builds the seeded
@@ -61,7 +62,7 @@ __all__ = [
     "correction_artifact", "correction_artifact_bytes",
     "save_correction_artifact", "load_correction_artifact",
     "maybe_load_default_corrections", "row_from_cost_model",
-    "backfill_row_from_history", "backfill_row_from_bench",
+    "backfill_row_from_history",
     "run_probe", "reprice_row", "calibration_section",
     "calibration_section_from_cost_model",
 ]
@@ -214,7 +215,7 @@ def _rel_err_block(predicted: Optional[Dict[str, Any]],
         return None
     out: Dict[str, Any] = {}
     for axis in ("step_s", "step_s_overlapped", "step_s_comm_overlap",
-                 "comm_s", "peak_bytes"):
+                 "peak_bytes"):
         m_axis = "step_s" if axis.startswith("step_s") else axis
         err = signed_rel_err(predicted.get(axis), measured.get(m_axis))
         if err is not None:
@@ -586,12 +587,14 @@ def maybe_load_default_corrections() -> Optional[Dict[str, CorrectionFactors]]:
 def row_from_cost_model(cm: Dict[str, Any], *, source: str, name: str,
                         backend: str, t: float = 0.0,
                         seed: Optional[int] = None,
-                        measured_comm_s: Optional[float] = None,
                         predicted_peak_bytes: Optional[float] = None,
                         measured_peak_bytes: Optional[float] = None
                         ) -> Dict[str, Any]:
     """Build one validated ledger row from a ``cost_model_section`` dict
-    (which already pairs a predicted block with a measured one)."""
+    (which already pairs a predicted block with a measured one). Rows
+    written before PR 32 may carry a ``measured.comm_s`` (read off the
+    executors' host stamps, which are gone); it is never written now and
+    nothing reads it."""
     hw = cm.get("hardware") or {}
     pred_src = cm.get("predicted") or {}
     meas_src = cm.get("measured")
@@ -606,8 +609,6 @@ def row_from_cost_model(cm: Dict[str, Any], *, source: str, name: str,
     if meas_src and meas_src.get("step_s"):
         measured = {"step_s": float(meas_src["step_s"]),
                     "tokens_per_sec": meas_src.get("tokens_per_sec")}
-        if measured_comm_s is not None:
-            measured["comm_s"] = float(measured_comm_s)
         if measured_peak_bytes is not None:
             measured["peak_bytes"] = float(measured_peak_bytes)
     corrected = None
@@ -695,58 +696,6 @@ def backfill_row_from_history(hrow: Dict[str, Any], *, path: str = "history"
     return validate_ledger_row(row, f"backfill:{path}")
 
 
-_BENCH_META = re.compile(
-    r"\((?P<sched>[A-Za-z0-9_]+),.*?batch (?P<batch>\d+), "
-    r"seq (?P<seq>\d+),.*?(?P<stages>\d+)-stage", re.S)
-
-
-def backfill_row_from_bench(blob: Dict[str, Any], *, label: str
-                            ) -> Optional[Dict[str, Any]]:
-    """One ``BENCH_rNN.json`` wrapper → a ledger row, or None when the
-    run failed / parsed nothing (caller reports the skip)."""
-    parsed = blob.get("parsed")
-    if not isinstance(parsed, dict) or parsed.get("value") in (None, 0):
-        return None
-    if parsed.get("unit") != "tokens/sec":
-        return None
-    meta = _BENCH_META.search(str(parsed.get("metric", "")))
-    schedule = meta.group("sched") if meta else "unknown"
-    batch = int(meta.group("batch")) if meta else 0
-    seq = int(meta.group("seq")) if meta else 0
-    stages = int(meta.group("stages")) if meta else 0
-    tps = float(parsed["value"])
-    measured = {"step_s": (batch * seq / tps) if batch and seq else None,
-                "tokens_per_sec": tps}
-    if measured["step_s"] is None:
-        # tokens/sec alone can't be turned into a step time — keep the
-        # throughput but there is no calibratable axis
-        return None
-    row = {
-        "schema_version": CALIBRATION_SCHEMA_VERSION,
-        "kind": LEDGER_KIND,
-        "source": f"backfill:{label}",
-        "t": 0.0,
-        "name": label,
-        "backend": "unknown",
-        "hardware": "unknown",
-        "cpu_proxy": False,
-        "schedule": schedule,
-        "schedule_family": schedule_family(schedule),
-        "backward_policy": "unknown",
-        "comm_overlap": "none",
-        "n_devices": stages,
-        "n_virtual": 1,
-        "n_microbatches": 0,
-        "batch_size": batch,
-        "seq_length": seq,
-        "predicted": None,       # bench wrappers predate the cost model rows
-        "measured": measured,
-        "rel_err": None,
-        "corrected": None,
-    }
-    return validate_ledger_row(row, f"backfill:{label}")
-
-
 # ---------------------------------------------------------------------------
 # The measured micro-probe
 # ---------------------------------------------------------------------------
@@ -762,25 +711,19 @@ _PROBE_SEQ = 16
 
 def run_probe(spec: ProbeSpec, *, seed: int = 0, num_iterations: int = 2,
               warmup_iterations: int = 1, correction=None,
-              t: float = 0.0,
-              detail: Optional[Dict[str, Any]] = None) -> Dict[str, Any]:
+              t: float = 0.0) -> Dict[str, Any]:
     """Execute one measured micro-probe on the live mesh → a validated
     ledger row.
 
     A few warm steps of a tiny model (warmup compiles + pages, then
     ``num_iterations`` timed steps via ``utils.metrics.
-    run_train_iterations`` — the only sanctioned step clock), with a
-    :class:`~..utils.telemetry.PipelineTelemetry` attached for the
-    measured comm-seconds axis and XLA's AOT accounting for the
-    measured peak-HBM axis. Deterministic modulo the measured fields:
+    run_train_iterations`` — the only sanctioned step clock), with
+    XLA's AOT accounting for the measured peak-HBM axis.
+    Deterministic modulo the measured fields:
     the spec, seeds, model and every predicted number are pure
     functions of (spec, seed). ``t`` stamps the row (pass
     ``time.time()`` from the driver; defaults to 0 so library callers
-    stay deterministic). Passing a dict as ``detail`` stashes the run's
-    live objects (``telemetry``, ``cost_model``, ``memory``,
-    ``compiled_schedule``) for callers that need more than the row —
-    ``scripts/probe.py`` uses it to write the annotated Perfetto trace
-    from a real probe instead of a synthetic run."""
+    stay deterministic)."""
     import jax
 
     from ..models.transformer import transformer_init
@@ -789,7 +732,6 @@ def run_probe(spec: ProbeSpec, *, seed: int = 0, num_iterations: int = 2,
     from ..parallel.schedules import compile_schedule
     from ..utils.config import ModelConfig, ScheduleConfig
     from ..utils.metrics import run_train_iterations
-    from ..utils.telemetry import PipelineTelemetry, critical_path
     from .cost_model import cost_model_section, resolve_backward_policy
     from .memory_model import memory_model_section, memory_probe_axes
 
@@ -800,7 +742,6 @@ def run_probe(spec: ProbeSpec, *, seed: int = 0, num_iterations: int = 2,
     cs = compile_schedule(spec.schedule, spec.n_devices, spec.n_virtual,
                           spec.n_microbatches)
     mesh = make_mesh(n_pipe=spec.n_devices)
-    tel = PipelineTelemetry()
     # the double-buffered executor requires the unrolled tick loop; every
     # other probe takes the scan executor, whose once-compiled tick body
     # keeps a 9-point grid's compile bill in CI budget (the probe measures
@@ -810,8 +751,7 @@ def run_probe(spec: ProbeSpec, *, seed: int = 0, num_iterations: int = 2,
     step = make_pipeline_step(cfg, mesh, sched,
                               remat_backward=spec.remat_backward,
                               unroll_ticks=unroll,
-                              comm_overlap=spec.comm_overlap,
-                              telemetry=tel)
+                              comm_overlap=spec.comm_overlap)
     params = transformer_init(jax.random.key(seed), cfg)
     kx, ky = jax.random.split(jax.random.key(seed + 1))
     tokens = jax.random.randint(kx, (_PROBE_BATCH, _PROBE_SEQ), 0,
@@ -820,14 +760,8 @@ def run_probe(spec: ProbeSpec, *, seed: int = 0, num_iterations: int = 2,
                                  cfg.vocab_size)
     metrics = run_train_iterations(step, params, tokens, targets,
                                    num_iterations=num_iterations,
-                                   warmup_iterations=warmup_iterations,
-                                   telemetry=tel)
+                                   warmup_iterations=warmup_iterations)
     measured_step_s = metrics["elapsed_time"] / num_iterations
-    measured_comm_s = None
-    if tel.events:
-        cp = critical_path(tel)
-        # telemetry covers the whole timed loop (reset after warmup)
-        measured_comm_s = float(cp["comm_s"]) / num_iterations
 
     cm = cost_model_section(cs, cfg, batch_size=_PROBE_BATCH,
                             seq_length=_PROBE_SEQ,
@@ -845,12 +779,8 @@ def run_probe(spec: ProbeSpec, *, seed: int = 0, num_iterations: int = 2,
     policy = resolve_backward_policy(cs, spec.remat_backward, spec.n_devices)
     name = (f"probe_{spec.schedule}_D{spec.n_devices}V{spec.n_virtual}"
             f"M{spec.n_microbatches}_{policy}_{spec.comm_overlap}")
-    if detail is not None:
-        detail.update(telemetry=tel, cost_model=cm, memory=mem,
-                      compiled_schedule=cs)
     return row_from_cost_model(
         cm, source="probe", name=name, backend=backend, t=t, seed=seed,
-        measured_comm_s=measured_comm_s,
         predicted_peak_bytes=peaks["predicted_peak_bytes"],
         measured_peak_bytes=peaks["measured_peak_bytes"])
 
@@ -881,7 +811,6 @@ def reprice_row(row: Dict[str, Any], spec: ProbeSpec, correction
     return row_from_cost_model(
         cm, source=row["source"], name=row["name"], backend=row["backend"],
         t=row["t"], seed=row.get("seed"),
-        measured_comm_s=meas.get("comm_s"),
         predicted_peak_bytes=pred_old.get("peak_bytes"),
         measured_peak_bytes=meas.get("peak_bytes"))
 
@@ -950,7 +879,7 @@ def calibration_section_from_cost_model(cm: Dict[str, Any], *, backend: str,
                                         correction: Optional[Mapping[str, Any]]
                                         = None) -> Optional[Dict[str, Any]]:
     """Single-run calibration section from a measured
-    ``cost_model_section`` — how fit/sweep/bench report their own
+    ``cost_model_section`` — how the sweep reports its own
     predicted-vs-measured point without running a probe grid. None when
     the section carries no measurement (nothing to calibrate)."""
     if not (cm.get("measured") or {}).get("step_s"):

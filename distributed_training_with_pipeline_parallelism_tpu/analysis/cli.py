@@ -288,7 +288,7 @@ def run_lint() -> Dict[str, Any]:
 
 def run_jaxpr_audits() -> Dict[str, Any]:
     """Trace small step functions (nothing executes) and audit them: zero
-    callbacks with telemetry off, collective axes declared on the mesh,
+    host callbacks, collective axes declared on the mesh,
     and — for the unrolled tick executor — traced ppermute hops equal to
     the table verifier's predicted comm volume."""
     import jax
@@ -360,7 +360,7 @@ def run_jaxpr_audits() -> Dict[str, Any]:
                          "predicted_ppermutes": expected_tp,
                          **audit.summary()})
     out["ok"] = out["ok"] and audit.ok
-    # serving block: telemetry-free by construction; audit callbacks + axes
+    # serving block: audit callbacks + axes
     from ..serving.engine import make_serving_step_fn
     serve_cfg = ModelConfig(dim=16, n_layers=8, n_heads=2, vocab_size=32,
                             ffn_dim=32, max_seq_len=16, arch="gpt2")

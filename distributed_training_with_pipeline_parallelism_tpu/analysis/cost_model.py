@@ -6,7 +6,7 @@ module prices those cells — FLOPs per unit from the model config, bytes
 per hop from the microbatch activation shape — against a
 :class:`HardwareSpec` roofline (peak dense FLOP/s + per-link ICI
 bandwidth) and produces the *predicted* side of the predicted↔measured
-loop that :mod:`..utils.telemetry` closes:
+comparison (the measured side is a host-clock step time):
 
 - per-unit FLOPs (F, and B/W under the backward policy the executor
   actually compiles: stored / remat / split — the same resolution
@@ -29,9 +29,8 @@ Everything here is host-side numpy over a handful of ``[T, D, 17]``
 tables — no jax execution (``jax.eval_shape`` only, for the parameter
 count). The output of :func:`cost_model_section` is a plain dict that
 rides the RunReport manifest (``attach_cost_model``; schema enforced by
-``utils.telemetry.validate_report``) and feeds
-``scripts/profile_breakdown.py`` and the ``scripts/regress.py``
-perf-regression sentinel.
+``utils.telemetry.validate_report``) and feeds the
+``scripts/regress.py`` perf-regression sentinel.
 """
 
 from __future__ import annotations
@@ -52,7 +51,7 @@ __all__ = [
     "detect_hardware", "fwd_flops_per_token", "train_flops_per_token",
     "resolve_backward_policy", "backward_weights", "dtype_bytes",
     "predicted_step_time", "comm_overlap_step_time",
-    "predicted_tick_seconds", "cost_model_section",
+    "cost_model_section",
     "serving_cost_model_section",
 ]
 
@@ -74,8 +73,7 @@ _DTYPE_BYTES = {"float32": 4, "bfloat16": 2, "float16": 2, "float64": 8}
 class HardwareSpec:
     """Roofline parameters for one chip of the pipeline mesh.
 
-    ``peak_flops``: advertised dense bf16 peak per chip (the same numbers
-    ``bench.chip_peak_flops`` divides MFU by — kept equal by test).
+    ``peak_flops``: advertised dense bf16 peak per chip.
     ``ici_bytes_per_s``: usable unidirectional bandwidth of the one ICI
     link a ring hop crosses. ``hbm_bytes_per_s``: per-chip HBM bandwidth
     (the second roofline ceiling, reported for context). ``hbm_bytes``:
@@ -99,10 +97,10 @@ class HardwareSpec:
         return dataclasses.asdict(self)
 
 
-# Peaks match bench._PEAK_FLOPS (v5e is 197 TFLOP/s bf16 — not its INT8
-# TOPS). ICI: one link of v4/v5e 3D/2D torus ~45-50 GB/s usable each
-# way; v5p ~100 GB/s; v6e ~90 GB/s. HBM: v5e 819 GB/s (the number
-# profile_breakdown.py's roofline uses), v4 1228, v5p 2765, v6e 1640.
+# Peaks are dense bf16 (v5e is 197 TFLOP/s — not its INT8 TOPS). ICI:
+# one link of v4/v5e 3D/2D torus ~45-50 GB/s usable each way; v5p
+# ~100 GB/s; v6e ~90 GB/s. HBM: v5e 819 GB/s, v4 1228, v5p 2765,
+# v6e 1640.
 # Capacity: v5e/v6e 16 GiB-class (16e9), v4 32, v5p 95.
 TPU_PRESETS: Dict[str, HardwareSpec] = {
     "v5 lite": HardwareSpec("v5e", 197e12, 5.0e10, 8.19e11, 16e9),
@@ -123,11 +121,10 @@ CPU_PROXY = HardwareSpec("cpu_proxy", 5e10, 1e9, 5e10, 16e9,
 def hardware_spec_for(device_kind: str) -> HardwareSpec:
     """Map a ``device_kind``/platform string to a preset.
 
-    Substring match over the TPU presets (same rule as
-    ``bench.chip_peak_flops``); a caller that asks for the CPU gets the
-    labelled :data:`CPU_PROXY`. A device that is not in the table is an
-    error, not a default: a roofline against another chip's peaks is not a
-    prediction."""
+    Substring match over the TPU presets; a caller that asks for the
+    CPU gets the labelled :data:`CPU_PROXY`. A device that is not in the
+    table is an error, not a default: a roofline against another chip's
+    peaks is not a prediction."""
     kind = device_kind.lower()
     for key, spec in TPU_PRESETS.items():
         if key in kind:
@@ -160,7 +157,7 @@ def fwd_flops_per_token(cfg, seq: int) -> float:
     ``jax.eval_shape`` — no arrays are materialized. Causal attention
     halves the live score matrix; ``ref_decoder`` runs two unmasked
     attentions per layer (self + cross), doubling it instead. This is the
-    canonical accounting: ``bench.train_flops_per_token`` is 3x this."""
+    canonical accounting: :func:`train_flops_per_token` is 3x this."""
     import jax
 
     from ..models import transformer as tfm
@@ -178,8 +175,7 @@ def fwd_flops_per_token(cfg, seq: int) -> float:
 
 def train_flops_per_token(cfg, seq: int) -> float:
     """``6N + 12*L*dim*seq``-family model FLOPs per trained token (fwd +
-    2x bwd — PaLM appendix B). The single source of truth bench delegates
-    to."""
+    2x bwd — PaLM appendix B)."""
     return 3.0 * fwd_flops_per_token(cfg, seq)
 
 
@@ -325,40 +321,6 @@ def comm_overlap_step_time(table: np.ndarray,
     }
 
 
-def predicted_tick_seconds(table: np.ndarray,
-                           unit_s: Tuple[float, float, float],
-                           hop_s: float,
-                           bank_stages: Optional[np.ndarray] = None,
-                           correction=None) -> np.ndarray:
-    """Per-tick predicted seconds ``[T]`` under the double-buffered
-    attribution of :func:`comm_overlap_step_time` — the vector the
-    Perfetto exporter lays beside each measured tick slice so
-    predicted-vs-measured disagreement is visible per tick, not just as
-    one summed scalar. Sums exactly to ``step_s_comm_overlap``."""
-    if correction is not None:
-        e_f = float(correction.flops_efficiency)
-        e_b = float(correction.bandwidth_efficiency)
-        unit_s = (unit_s[0] / e_f, unit_s[1] / e_f, unit_s[2] / e_f)
-        hop_s = hop_s / e_b
-    table = np.asarray(table)
-    if bank_stages is None:
-        bank_stages = overlap_bank_stages(table)
-    activity = table_unit_activity(table)
-    vec = np.array([unit_s[0], unit_s[1], unit_s[2], 0.0], dtype=np.float64)
-    compute_tick_s = (activity.astype(np.float64) @ vec).max(axis=1)  # [T]
-    T = table.shape[0]
-    exposed = np.zeros(T, dtype=np.int64)
-    deferred = np.zeros(T, dtype=np.int64)
-    for u in range(1, T):
-        for ci, (_, col, _) in enumerate(_STORE_CHANNELS):
-            if (table[u, :, col] >= 0).any():
-                if bank_stages[u, ci] == BANK_BEFORE_F:
-                    exposed[u] += 1
-                else:
-                    deferred[u] += 1
-    return exposed * hop_s + np.maximum(compute_tick_s, deferred * hop_s)
-
-
 def _resolve_correction(correction, hw_name: str):
     """Accept a CorrectionFactors, a {hardware_name: CorrectionFactors}
     mapping (the :func:`..analysis.calibration.load_correction_artifact`
@@ -377,17 +339,14 @@ def cost_model_section(cs: CompiledSchedule, cfg, *, batch_size: int,
                        hardware: Optional[HardwareSpec] = None,
                        remat_backward=None,
                        measured_step_s: Optional[float] = None,
-                       telemetry=None,
                        table_report=None,
                        comm_overlap: str = "none",
                        correction=None) -> Dict[str, Any]:
     """Price one compiled schedule against a roofline; reconcile with a
     measured run when one is supplied.
 
-    ``telemetry``: a stamped :class:`..utils.telemetry.PipelineTelemetry`
-    — supplies ``measured_step_s`` (sum of timeline durations) when not
-    given explicitly, and adds the critical-path attribution table
-    (compute vs comm vs bubble seconds, straggler stage).
+    ``measured_step_s``: a host-clock step time (a loop closed with
+    ``utils.metrics.force_completion``); adds the ``measured`` block.
     ``table_report``: a precomputed :class:`.table_check.TableReport`;
     verified fresh via ``check_table`` when absent. ``comm_overlap``
     records the ring-hop discipline the run's executor compiled
@@ -511,17 +470,6 @@ def cost_model_section(cs: CompiledSchedule, cfg, *, batch_size: int,
             "step_s_comm_overlap": ov_c["step_s_comm_overlap"],
         }
 
-    if telemetry is not None and getattr(telemetry, "events", None):
-        if measured_step_s is None:
-            measured_step_s = sum((rec.get("duration_s") or 0.0)
-                                  for rec in telemetry.timeline())
-        from ..utils.telemetry import critical_path
-        cp = critical_path(telemetry)
-        section["attribution"] = {
-            k: cp[k] for k in ("compute_s", "comm_s", "bubble_s", "total_s",
-                               "n_ticks", "straggler_device",
-                               "straggler_stage", "straggler_s_per_device")}
-
     if measured_step_s is not None and measured_step_s > 0:
         chip_s = measured_step_s * D * hw.peak_flops
         measured: Dict[str, Any] = {
@@ -540,10 +488,6 @@ def cost_model_section(cs: CompiledSchedule, cfg, *, batch_size: int,
         if corrected is not None:
             measured["rel_err_corrected"] = \
                 (corrected["step_s"] - measured_step_s) / measured_step_s
-        if telemetry is not None and getattr(telemetry, "events", None):
-            sb = telemetry.stage_breakdown()
-            if "bubble_measured_mean" in sb:
-                measured["bubble_measured_mean"] = sb["bubble_measured_mean"]
         section["measured"] = measured
 
     return section

@@ -7,9 +7,9 @@ calls, scan bodies with their trip counts, cond branches, custom-vjp
 wrappers) and accumulates:
 
 - ``n_callbacks``: host callbacks (``io_callback`` / ``pure_callback`` /
-  debug prints). The telemetry contract (docs/observability.md) is that
-  an uninstrumented step fn contains ZERO of these — telemetry off is
-  free at trace time.
+  debug prints). No step function of this repo holds one
+  (docs/observability.md): the device's time is read from the profiler's
+  trace, never stamped from inside the program.
 - ``collectives``: weighted counts per collective primitive. Scan bodies
   multiply by the scan ``length``; cond contributes the elementwise MAX
   over its branches (the executor's worst-case tick); a while loop makes
@@ -203,8 +203,7 @@ def audit_jaxpr(closed_jaxpr: Any, mesh_axes: Sequence[str] = (),
                 f"(mesh declares {tuple(mesh_axes)})")
     if expect_no_callbacks and audit.n_callbacks:
         audit.problems.append(
-            f"{audit.n_callbacks} host callback(s) traced with telemetry "
-            f"off (must be zero)")
+            f"{audit.n_callbacks} host callback(s) traced (must be zero)")
     if expected_ppermutes is not None \
             and audit.ppermute_count != expected_ppermutes:
         audit.problems.append(

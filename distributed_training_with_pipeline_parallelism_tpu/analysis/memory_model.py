@@ -1,7 +1,7 @@
 """Analytical HBM model: the bytes-domain twin of :mod:`.cost_model`.
 
 The tick table prices *time* through :func:`.cost_model.cost_model_section`;
-this module prices *memory*, three ways, and reconciles them:
+this module prices *memory*, two ways, and reconciles them:
 
 1. **analytic** — per-device bytes built from the static verifier's exact
    slot high-water marks (:class:`.table_check.TableReport`'s
@@ -24,15 +24,11 @@ this module prices *memory*, three ways, and reconciles them:
    :func:`reconcile_memory` pins analytic parameter+input bytes against
    the compiled argument bytes (documented tolerance: 10% — layout
    padding and donation are XLA's business, wholesale drift is ours).
-3. **live** — ``device.memory_stats()`` watermarks sampled at step
-   boundaries by :class:`..utils.telemetry.PipelineTelemetry` (a no-op
-   on backends that return ``None``, e.g. CPU), summarized per device
-   and drawn as a Perfetto counter track.
 
-All three land in the schema-validated ``memory`` RunReport section
-(``attach_memory``) that fit/sweep/bench/serving auto-attach, and the
+Both land in the schema-validated ``memory`` RunReport section
+(``attach_memory``) that fit/sweep/serving auto-attach, and the
 analytic peak against :attr:`.cost_model.HardwareSpec.hbm_bytes` is the
-OOM preflight sweep/bench consult *before* compiling a config
+OOM preflight the sweep consults *before* compiling a config
 (:func:`oom_preflight`).
 
 Host-side only: ``jax.eval_shape`` for shapes/dtypes, numpy for sums —
@@ -190,33 +186,23 @@ def reconcile_memory(analytic: Dict[str, Any],
     }
 
 
-def _live_section(telemetry) -> Optional[Dict[str, Any]]:
-    if telemetry is None:
-        return None
-    summary = getattr(telemetry, "memory_summary", None)
-    if summary is None:
-        return None
-    return summary()
-
-
 def memory_model_section(cs: CompiledSchedule, cfg, *, batch_size: int,
                          seq_length: int,
                          hardware: Optional[HardwareSpec] = None,
                          remat_backward=None,
                          optimizer_slots: int = 0,
                          table_report=None,
-                         compiled: Optional[Dict[str, Any]] = None,
-                         telemetry=None) -> Dict[str, Any]:
+                         compiled: Optional[Dict[str, Any]] = None
+                         ) -> Dict[str, Any]:
     """Price one compiled schedule's per-device HBM; reconcile with the
-    compiled and live accountings when supplied.
+    compiled accounting when supplied.
 
     ``optimizer_slots``: fp32 moment buffers per parameter the training
     loop keeps (2 for the ``fit`` AdamW path; 0 for the bare
-    loss-and-grads step sweep/bench time). ``table_report``: precomputed
+    loss-and-grads step the sweep times). ``table_report``: precomputed
     :class:`.table_check.TableReport` (verified fresh when absent) —
     the source of the exact slot live peaks. ``compiled``: an
-    ``aot_memory_analysis`` dict. ``telemetry``: a stamped
-    :class:`..utils.telemetry.PipelineTelemetry` with watermark samples.
+    ``aot_memory_analysis`` dict.
     Returns the plain dict ``RunReport.attach_memory`` embeds."""
     D = int(cs.table.shape[1])
     hw = hardware if hardware is not None else detect_hardware()
@@ -231,7 +217,7 @@ def memory_model_section(cs: CompiledSchedule, cfg, *, batch_size: int,
     stored_mb_b = (stored_residual_bytes(cfg, cs.n_stages, tokens_per_mb)
                    if policy == "stored" else 0.0)
     pb = params_bytes(cfg, D)
-    # sweep/bench/fit steps all return a grads pytree shaped like params;
+    # sweep and fit steps both return a grads pytree shaped like params;
     # optimizer moments are fp32 regardless of the storage dtype
     grads_dev_b = pb["per_device_bytes"]
     opt_dev_b = optimizer_slots * pb["n_params"] * 4.0 / D \
@@ -297,9 +283,6 @@ def memory_model_section(cs: CompiledSchedule, cfg, *, batch_size: int,
         rec = reconcile_memory(analytic, comp)
         if rec is not None:
             section["reconciliation"] = rec
-    live = _live_section(telemetry)
-    if live is not None:
-        section["live"] = live
     return section
 
 
@@ -467,7 +450,7 @@ def oom_preflight(section: Dict[str, Any],
     """Price a memory section against the chip's HBM capacity.
 
     ``ok=False`` means the analytic per-device peak exceeds
-    ``headroom x HardwareSpec.hbm_bytes`` — the sweep/bench preflight
+    ``headroom x HardwareSpec.hbm_bytes`` — the sweep's preflight
     then emits a ``skip_reason="predicted_oom"`` row *before* compiling.
     Unknown capacity (``hbm_bytes == 0``) always passes."""
     hw = hardware if hardware is not None else detect_hardware()

@@ -64,15 +64,15 @@ Each rule encodes a contract documented elsewhere in the repo
     No direct host-clock *calls* (``time.time()``,
     ``time.perf_counter()``, ``time.perf_counter_ns()``,
     ``time.monotonic()``) outside the sanctioned timing surfaces:
-    ``utils/telemetry.py`` (stamp recorder + event log),
+    ``utils/telemetry.py`` (run-report timers + event log),
     ``utils/metrics.py`` (the timed benchmark loop),
     ``utils/profiling.py``, ``utils/train.py`` (log-window wall clock),
     ``utils/resilience.py`` (checkpoint stamps), ``serving/engine.py``
     (serving wall clock), and ``analysis/calibration.py`` (the probe
     harness). Anywhere else, a raw clock read is an ad-hoc step timing
     that bypasses the predicted-vs-measured calibration ledger
-    (docs/observability.md §9) — route it through ``utils.metrics`` /
-    telemetry so every measurement is reconcilable with the cost model.
+    (docs/observability.md §9) — route it through ``utils.metrics``
+    so every measurement is reconcilable with the cost model.
 
 The linter is stdlib-only (``ast``) — no jax import, safe for CI legs
 that run before any backend exists.
@@ -389,7 +389,7 @@ def _lint_raw_step_timing(tree: ast.AST, path: str,
                 f"timing surfaces (utils/metrics.py, utils/telemetry.py, "
                 f"...) — ad-hoc step timing bypasses the calibration "
                 f"ledger (docs/observability.md §9); route measurements "
-                f"through utils.metrics / telemetry stamps"))
+                f"through utils.metrics"))
 
 
 def lint_source(path: str, source: str,

@@ -554,11 +554,12 @@ def _check_moe_mesh(cfg: ModelConfig, moe, T: int, n_seq: int,
 # Auto-unroll threshold for the tick executor: tables at or below this many
 # tick rows compile as straight-line code (each row's units traced once
 # more), above it the lax.scan form keeps compile time bounded. Set from
-# round-5 v5e measurements (results/unroll_crossover.json, GPipe D=1 remat
-# executor, per-microbatch shapes fixed): unrolled beats scanned at EVERY
-# size measured — 1.19-1.20x through 32 rows, narrowing to ~1.05x at 48-64
-# rows — so there is no throughput crossover to encode; the binding cost is
-# compile time, which grows ~2.2 s/row (14 s at 8 rows -> 140 s at 64).
+# round-5 v5e measurements (docs/performance.md "Unroll-vs-scan
+# crossover"; GPipe D=1 remat executor, per-microbatch shapes fixed):
+# unrolled beats scanned at EVERY size measured — 1.19-1.20x through 32
+# rows, narrowing to ~1.05x at 48-64 rows — so there is no throughput
+# crossover to encode; the binding cost is compile time, which grows
+# ~2.2 s/row (14 s at 8 rows -> 140 s at 64).
 # 64 rows covers every ladder config (Interleaved D=4/V=2/M=8 = 38 rows,
 # GPipe D=1 M=32 = 64) at <= ~2.5 min compile; beyond it the measured win
 # trend (shrinking) no longer justifies unbounded compile growth. Callers
@@ -592,8 +593,7 @@ _PHASE_TRACE_HOOK = None
 logger = logging.getLogger(__name__)
 
 
-def _phase_compressed_ticks(tick, carry, table, phases, telemetry=None,
-                            bank_stages=None):
+def _phase_compressed_ticks(tick, carry, table, phases, bank_stages=None):
     """Drive a tick program as per-phase ``lax.scan`` s with per-pattern
     specialized bodies — the ``unroll_ticks="phases"`` executor core,
     shared by the training and forward-only programs.
@@ -618,13 +618,6 @@ def _phase_compressed_ticks(tick, carry, table, phases, telemetry=None,
     banks is dead (``_masked_store`` skips slot -1), so results stay
     bit-exact against the plain scan executor.
 
-    ``telemetry`` (a :class:`..utils.telemetry.PipelineTelemetry`, opt-in)
-    brackets each phase's scan with host-timestamp stamps whose probes are
-    scalars drawn from the live carry — dataflow pins phase j's start
-    stamp after phase j-1's work and its end stamp after its own, giving a
-    measured per-phase timeline aligned with the ``phases`` descriptors.
-    When None (default), no callback is emitted at all.
-
     ``bank_stages`` (opt-in, ``[T, 4]`` int from ``..schedules.
     overlap_bank_stages``) enables the double-buffered ring discipline:
     each body position banks its ring arrivals at the per-position stage
@@ -632,7 +625,6 @@ def _phase_compressed_ticks(tick, carry, table, phases, telemetry=None,
     banking earlier than latest-safe is always lockstep-correct). The
     stage tuple joins the memo key, so two phases sharing a mask pattern
     but differing in bank stages compile separate bodies."""
-    from ..utils import telemetry as _tm
     memo = {}
     n_cols = phases[0].base.shape[-1]
     end_mask = np.full(phases[0].base.shape[1:], -1, np.int32)  # [D, C]
@@ -687,12 +679,8 @@ def _phase_compressed_ticks(tick, carry, table, phases, telemetry=None,
 
             memo[key] = body
         xs = table[ph.start:ph.start + L].reshape(L // q, q, -1, n_cols)
-        if telemetry is not None:
-            telemetry.emit(_tm.PHASE_START, j, _tm.probe_of(carry))
         with jax.named_scope(f"pp/phase{j}"):
             carry, _ = jax.lax.scan(memo[key], carry, xs)
-        if telemetry is not None:
-            telemetry.emit(_tm.PHASE_END, j, _tm.probe_of(carry))
     return carry
 
 
@@ -703,7 +691,6 @@ def make_pipeline_grad_fn(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig,
                           fsdp: bool = False,
                           remat_backward=None,
                           unroll_ticks=None,
-                          telemetry=None,
                           dynamics=None,
                           comm_overlap: str = "none",
                           ) -> Callable[[Pytree, jax.Array, jax.Array],
@@ -776,7 +763,7 @@ def make_pipeline_grad_fn(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig,
       elided entirely (warmup ticks carry no backward ring hop, cooldown
       no forward one). Worth 1.05-1.2x throughput over the scan form on
       v5e, but compile time grows ~2.2 s per table row (14 s at 8 rows,
-      ~140 s at 64 — results/unroll_crossover.json).
+      ~140 s at 64 — docs/performance.md "Unroll-vs-scan crossover").
     - ``"phases"``: the phase-compressed executor. The table is
       segmented into periodic phases (:func:`..schedules.
       compress_schedule`), each unique active/idle pattern is traced
@@ -794,16 +781,6 @@ def make_pipeline_grad_fn(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig,
     - ``None`` (auto, default): ``True`` for tables of at most
       ``_UNROLL_TICKS_LIMIT`` (= 64) rows, ``"phases"`` above (a one-line
       ``logging.info`` records when that auto phase-compression fires).
-
-    ``telemetry`` (a :class:`..utils.telemetry.PipelineTelemetry`, default
-    None) opts in to a MEASURED tick/phase timeline: the executor plants
-    host-timestamp callbacks at segment boundaries — per phase
-    (``"phases"``), per tick (``True``), or per step (``False``) — and
-    records the compiled table/phases on the collector so its analysis
-    aligns the stamps with the simulated timeline (docs/observability.md).
-    When None the built program contains NO callback (tests assert
-    ``"io_callback" not in str(jaxpr)``) and is bit-identical to an
-    uninstrumented build.
 
     ``dynamics`` (truthy, default None) additionally accumulates each
     microbatch's squared gradient norm in an ``[M]`` f32 carry — the
@@ -1012,25 +989,8 @@ def make_pipeline_grad_fn(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig,
                 "and has no per-tick bank sites) — pass remat_backward="
                 "True/None for the tick executor, or comm_overlap='auto' "
                 "to fall back to lockstep here")
-        fn = _make_phase_stored_grad_fn(cfg, mesh, sched, sp_attn_impl,
-                                        tp_vocab_parallel)
-        if telemetry is None:
-            return fn
-        # The phase-stored program differentiates THROUGH its forward tick
-        # scan, so stamps cannot live inside it (io_callback has no
-        # transpose rule); bracket the whole step instead — one measured
-        # whole-table segment, the same shape as the scan executor's
-        # record.
-        from ..utils import telemetry as _tm
-        telemetry.attach(cs.table, None, "phase_stored")
-
-        def instrumented(params, tokens, targets, *rest):
-            telemetry.emit(_tm.STEP_START, 0, _tm.probe_of(tokens))
-            out = fn(params, tokens, targets, *rest)
-            telemetry.emit(_tm.STEP_END, 0, _tm.probe_of(out))
-            return out
-
-        return instrumented
+        return _make_phase_stored_grad_fn(cfg, mesh, sched, sp_attn_impl,
+                                          tp_vocab_parallel)
     n_rows = cs.table.shape[0]
     logger.info(
         "pipeline: %s D=%d V=%d M=%d tick table: %d rows, %d of %d cells "
@@ -1068,10 +1028,6 @@ def make_pipeline_grad_fn(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig,
         phases = compress_schedule(cs.table)
     else:
         phases = None
-    if telemetry is not None:
-        telemetry.attach(cs.table, phases,
-                         {True: "unrolled", False: "scan",
-                          "phases": "phases"}[unroll_ticks])
     table = jnp.asarray(cs.table)  # [T, D, N_COLS]
     dtype = jnp.dtype(cfg.dtype)
     fwd_perm = [(i, (i + 1) % D) for i in range(D)]
@@ -1795,7 +1751,6 @@ def make_pipeline_grad_fn(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig,
             # phase-compressed: one specialized scan body per unique row
             # pattern, each phase driven as a lax.scan over its real rows
             carry = _phase_compressed_ticks(tick, carry0, table, phases,
-                                            telemetry=telemetry,
                                             bank_stages=bank_stages_tab)
         elif unroll_ticks:
             # straight-line tick program: the Python loop IS the schedule,
@@ -1803,9 +1758,6 @@ def make_pipeline_grad_fn(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig,
             # (cond/ppermute/store elision — see the tick helpers above)
             carry = carry0
             n_rows = cs.table.shape[0]
-            if telemetry is not None:
-                from ..utils import telemetry as _tm
-                telemetry.emit(_tm.STEP_START, 0, _tm.probe_of(carry))
             # after the final tick nothing banks: an all-dead pseudo-row
             # elides the last hops (None means "no knowledge" — scan path)
             end_row = np.full_like(cs.table[0], -1)
@@ -1816,15 +1768,8 @@ def make_pipeline_grad_fn(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig,
                 with jax.named_scope(f"pp/tick{t:03d}"):
                     carry, _ = tick(carry, table[t], concrete=cs.table[t],
                                     next_concrete=nxt, bank_stages=bs)
-                if telemetry is not None:
-                    telemetry.emit(_tm.TICK, t, _tm.probe_of(carry))
         else:
-            if telemetry is not None:
-                from ..utils import telemetry as _tm
-                telemetry.emit(_tm.STEP_START, 0, _tm.probe_of(carry0))
             carry, _ = jax.lax.scan(tick, carry0, table)
-            if telemetry is not None:
-                telemetry.emit(_tm.STEP_END, 0, _tm.probe_of(carry))
         if dyn:
             (_, _, _, _, g_layers, g_embed, g_head, loss_acc,
              sq_mb) = carry
@@ -1944,7 +1889,6 @@ def make_pipeline_step(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig,
                        fsdp: bool = False,
                        remat_backward=None,
                        unroll_ticks=None,
-                       telemetry=None,
                        dynamics=None,
                        comm_overlap: str = "none",
                        ) -> Callable[[Pytree, jax.Array, jax.Array],
@@ -1976,10 +1920,6 @@ def make_pipeline_step(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig,
     (bounded-compile scan) or ``unroll_ticks="phases"`` — both run the
     identical tick program, bit-exact against the unrolled form.
 
-    ``telemetry`` (opt-in ``utils.telemetry.PipelineTelemetry``) records a
-    measured tick/phase timeline; None (default) compiles zero
-    instrumentation (see :func:`make_pipeline_grad_fn`).
-
     ``dynamics`` (truthy) returns ``(loss, grads, sq_mb)`` instead — the
     per-microbatch squared grad norms feeding the gradient-noise-scale
     estimator (see :func:`make_pipeline_grad_fn`; falsy compiles a
@@ -1994,7 +1934,7 @@ def make_pipeline_step(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig,
         cfg, mesh, sched, force_tick_executor=force_tick_executor, moe=moe,
         sp_attn_impl=sp_attn_impl, tp_vocab_parallel=tp_vocab_parallel,
         fsdp=fsdp, remat_backward=remat_backward, unroll_ticks=unroll_ticks,
-        telemetry=telemetry, dynamics=dynamics, comm_overlap=comm_overlap))
+        dynamics=dynamics, comm_overlap=comm_overlap))
 
 
 def aot_memory_analysis(step, *args) -> Dict[str, Any]:
@@ -2173,7 +2113,8 @@ def _build_forward_program(cfg: ModelConfig, mesh: Mesh,
         # auto: D == 1 always unrolls (measured fastest); D > 1 up to the
         # forward executor's OWN row budget — round 5 raised the training
         # executor's _UNROLL_TICKS_LIMIT to 64 from measurements of the
-        # train-step economics (results/unroll_crossover.json); forward
+        # train-step economics (docs/performance.md "Unroll-vs-scan
+        # crossover"); forward
         # ticks are ~1/3 of a train tick's compute, so the unroll win per
         # compile-second is unmeasured here and the round-4 budget stays.
         # Beyond the budget the phase-compressed form replaces the plain

@@ -1332,7 +1332,7 @@ def register_schedule_artifact(source, *, name: Optional[str] = None,
     """Load, certify, and register an artifact as a named schedule.
 
     After this, ``compile_schedule(name, D, V, M)`` (and therefore
-    ``ScheduleConfig``/fit/sweep/bench) resolves the searched schedule like
+    ``ScheduleConfig``/fit/sweep) resolves the searched schedule like
     any built-in — but pinned: the compile path re-checks the table digest
     against the artifact, so the certified table cannot drift.
     """
@@ -1523,9 +1523,8 @@ def phase_stats(phases: Sequence[Phase]) -> Dict[str, int]:
 
 
 def phase_spans(phases: Sequence[Phase]) -> List[Tuple[int, int]]:
-    """``[(start_tick, n_ticks)]`` per phase — the tick-axis alignment a
-    measured per-phase timeline (``utils.telemetry``) is interpreted on.
-    Spans tile ``[0, makespan)`` contiguously (compression invariant)."""
+    """``[(start_tick, n_ticks)]`` per phase. Spans tile ``[0, makespan)``
+    contiguously (the compression invariant ``check_table`` verifies)."""
     return [(p.start, p.length) for p in phases]
 
 
@@ -1537,9 +1536,7 @@ def table_unit_activity(table: np.ndarray) -> np.ndarray:
     microbatch) and the >=13-column training table (``COL_FWD_M`` /
     ``COL_BWD_M`` / ``COL_W_M``). A cell doing several units in one tick
     (e.g. B and W fused on non-split schedules' backward) counts each
-    active op; ``idle`` is set only when no unit runs. This is the
-    attribution mask that maps measured segment durations onto stages and
-    ops (the measured counterpart of :func:`simulated_bubble`'s weights).
+    active op; ``idle`` is set only when no unit runs.
     """
     table = np.asarray(table)
     if table.ndim != 3:
@@ -1554,15 +1551,6 @@ def table_unit_activity(table: np.ndarray) -> np.ndarray:
          else np.zeros(table.shape[:2], bool))
     idle = ~(f | b | w)
     return np.stack([f, b, w, idle], axis=-1).astype(np.int64)
-
-
-def phase_unit_activity(phases: Sequence[Phase]) -> np.ndarray:
-    """Per-phase, per-device tick counts in (F, B, W, idle): ``[n_phases,
-    D, 4]``. The weights that spread one phase's *measured* duration over
-    stages and ops — see ``utils.telemetry.PipelineTelemetry
-    .stage_breakdown``."""
-    return np.stack([table_unit_activity(rows_of(p)).sum(axis=0)
-                     for p in phases])
 
 
 # Ring channels in the executor's recv-register order: (bank column,

@@ -2,7 +2,7 @@
 
 Every run on the chip machine starts with no compiled code, and a whole
 train step takes tens of seconds to compile, so the entry points
-(``scripts/*.py``, ``bench.py``, ``chip_smoke.py``) call
+(``scripts/*.py``, ``chip_smoke.py``) call
 :func:`enable_compile_cache` before their first compile. It is NOT called
 on package import and not by the test suite: a persistent cache crashed
 XLA:CPU under full-suite volume (tests/conftest.py).

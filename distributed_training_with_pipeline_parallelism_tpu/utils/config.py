@@ -316,7 +316,7 @@ class ScheduleConfig:
         and return the :class:`ScheduleConfig` that selects it.
 
         The artifact is fully re-certified on load (recompile + cell diff
-        + ``check_table``) and pinned, so ``fit``/``sweep``/``bench`` runs
+        + ``check_table``) and pinned, so ``fit``/``sweep`` runs
         under the returned config execute exactly the certified table —
         see ``parallel.schedules.register_schedule_artifact``."""
         from ..parallel.schedules import register_schedule_artifact

@@ -433,8 +433,7 @@ def dynamics_section(n_stages: int, last_stats: Optional[dict] = None,
                      n_skipped_attributed: int = 0,
                      forensic_bundles=()) -> dict:
     """The manifest's ``dynamics`` section from host-fetched stats
-    (``validate_report`` checks this shape; ``profile_breakdown.py``
-    renders it)."""
+    (``validate_report`` checks this shape)."""
     section = {
         "n_stages": int(n_stages),
         "grad_norm_final": None,

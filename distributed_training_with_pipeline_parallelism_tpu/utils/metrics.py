@@ -37,16 +37,12 @@ def force_completion(out) -> None:
 def run_train_iterations(step: Callable, params, tokens, targets,
                          num_iterations: int = 10,
                          warmup_iterations: int = 2,
-                         report=None,
-                         telemetry=None) -> Dict[str, float]:
+                         report=None) -> Dict[str, float]:
     """Time ``num_iterations`` pipeline steps after untimed warmup.
 
     ``report`` (opt-in :class:`.telemetry.RunReport`) records the warmup
     (compile-inclusive) and timed-loop wall clocks as timers plus the
-    returned metrics as gauges. ``telemetry`` (opt-in
-    :class:`.telemetry.PipelineTelemetry`, already wired into ``step``) is
-    reset after warmup so its recorded events cover exactly the timed
-    iterations."""
+    returned metrics as gauges."""
     total_toks = tokens.shape[0] * tokens.shape[1] * num_iterations
 
     warm0 = time.perf_counter()
@@ -57,8 +53,6 @@ def run_train_iterations(step: Callable, params, tokens, targets,
         force_completion(out)
     if report is not None:
         report.timers["warmup_s"] = time.perf_counter() - warm0
-    if telemetry is not None:
-        telemetry.reset()  # timeline covers the timed loop only
 
     start = time.perf_counter()
     for _ in range(num_iterations):
@@ -76,6 +70,4 @@ def run_train_iterations(step: Callable, params, tokens, targets,
         report.count("timed_iterations", num_iterations)
         for k, v in metrics.items():
             report.gauge(k, v)
-        if telemetry is not None:
-            report.attach_telemetry(telemetry)
     return metrics

@@ -143,62 +143,6 @@ def plot_schedule_timeline(name_or_cs, n_devices: int = None,
     return fig
 
 
-def plot_timeline_overlay(name_or_cs, timeline, n_devices: int = None,
-                          n_virtual: int = 1, n_microbatches: int = 4,
-                          path: Optional[str] = None):
-    """Measured vs simulated timeline, stacked on a shared tick axis.
-
-    Top panel: the compiled schedule's tick timeline
-    (:func:`plot_schedule_timeline` — the SIMULATED structure, unit-cost
-    ticks). Bottom panel: the MEASURED per-tick cost from a
-    ``utils.telemetry.PipelineTelemetry`` timeline — each instrumented
-    segment (phase / tick / whole step) drawn as a horizontal span over the
-    ticks it covers at height ``duration / n_ticks`` (ms per tick), so
-    warmup, steady state and cooldown line up column-for-column with the
-    schedule cells above. A flat measured profile means unit-cost
-    simulation was a good model; spikes localize where real time deviates
-    (reading guide: docs/observability.md).
-
-    ``timeline`` is ``PipelineTelemetry.timeline()``'s record list (or the
-    ``telemetry.timeline`` section of a run-report manifest).
-    """
-    from ..parallel.schedules import CompiledSchedule, compile_schedule
-    if isinstance(name_or_cs, CompiledSchedule):
-        cs = name_or_cs
-    else:
-        cs = compile_schedule(name_or_cs, n_devices, n_virtual,
-                              n_microbatches)
-    plt = _mpl()
-    fig, (ax_top, ax_bot) = plt.subplots(
-        2, 1, figsize=(max(6, 0.32 * cs.makespan), 0.6 * cs.n_devices + 3.4),
-        sharex=True, gridspec_kw={"height_ratios": [cs.n_devices, 2.2]})
-    plot_schedule_timeline(cs, ax=ax_top, annotate=cs.makespan <= 80)
-    ax_top.set_xlabel("")
-
-    for rec in timeline:
-        dur = rec.get("duration_s")
-        t0, n = rec.get("start_tick", 0), rec.get("n_ticks", 1)
-        if dur is None or n <= 0:
-            continue
-        per_tick_ms = dur / n * 1e3
-        ax_bot.fill_between([t0, t0 + n], 0.0, per_tick_ms,
-                            step=None, color="#4e9ad1", alpha=0.55,
-                            edgecolor="#2a6496", linewidth=0.8)
-        if "phase" in rec and cs.makespan <= 80:
-            ax_bot.text(t0 + n / 2.0, per_tick_ms, f"p{rec['phase']}",
-                        ha="center", va="bottom", fontsize=6, color="#2a6496")
-    ax_bot.set_xlim(0, cs.makespan)
-    ax_bot.set_ylim(bottom=0.0)
-    ax_bot.set_xlabel("tick")
-    ax_bot.set_ylabel("measured ms/tick")
-    ax_bot.grid(alpha=0.3)
-    ax_bot.set_title("measured segment cost (host-stamped)", fontsize=9)
-    fig.tight_layout()
-    if path:
-        fig.savefig(path, dpi=120)
-    return fig
-
-
 def plot_latency_curve(section, path: Optional[str] = None):
     """The serving SLO observatory's headline figure: latency vs offered
     load from a ``serving_load`` manifest section (``serving.loadgen.

@@ -50,7 +50,7 @@ def annotate(name: str):
     ``with annotate("step3"): step(...)``. Complements the executors'
     ``jax.named_scope`` labels, which name DEVICE-side ops at trace time:
     ``TraceAnnotation`` marks wall-clock regions of the host timeline
-    (e.g. which bench rung or train step issued the work). No-op cost when
+    (e.g. which train step issued the work). No-op cost when
     no profiler session is active."""
     with jax.profiler.TraceAnnotation(name):
         yield

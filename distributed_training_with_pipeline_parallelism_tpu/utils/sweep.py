@@ -54,8 +54,8 @@ def run_one_experiment(n_layers: int, n_heads: int, num_devices: int,
     :class:`.telemetry.RunReport` manifest — config/mesh/schedule meta,
     the metrics as gauges, timed-loop timers — appended as one JSON line
     to ``{report_dir}/sweep_reports.jsonl`` (validated against the shared
-    schema before writing), so sweep rows, ``fit`` runs and ``bench.py``
-    all speak the same report format (docs/observability.md).
+    schema before writing), so sweep rows and ``fit`` runs speak the
+    same report format (docs/observability.md).
 
     Self-describing columns (so the artifact cannot be misread without its
     docs): ``backward_policy`` records which backward the executor compiled
@@ -193,8 +193,7 @@ def run_one_experiment(n_layers: int, n_heads: int, num_devices: int,
             "n_virtual": n_virtual,
             "n_microbatches": n_microbatches,
             # first-class predicted-vs-measured columns (the calibration
-            # ledger's headline axis; scripts/regress.py extracts these
-            # uniformly from sweep rows and bench results)
+            # ledger's headline axis; scripts/regress.py extracts them)
             "predicted_step_s": cost_model["predicted"]["step_s"],
             "rel_err": cost_model.get("measured", {}).get("rel_err"),
             "rel_err_corrected": cost_model.get("measured", {}).get(
@@ -224,10 +223,14 @@ def run_one_experiment(n_layers: int, n_heads: int, num_devices: int,
             from ..parallel.pipeline import make_pipeline_grad_fn
             from .dynamics import GNSEstimator, stage_stats
             # one instrumented pass off the clock; the tick executor with
-            # remat is the configuration the GNS accumulator supports
-            dyn_grad = make_pipeline_grad_fn(
+            # remat is the configuration the GNS accumulator supports.
+            # make_pipeline_grad_fn returns an UNJITTED step: called bare,
+            # the unrolled tick program runs op by op through an eager
+            # shard_map (minutes on the CPU where the jitted one takes
+            # seconds)
+            dyn_grad = jax.jit(make_pipeline_grad_fn(
                 cfg, mesh, sched, remat_backward=True, unroll_ticks=True,
-                dynamics=True)
+                dynamics=True))
             _, grads_d, sq_mb = dyn_grad(params, tokens, targets)
             st = stage_stats(cfg.n_layers, num_devices * n_virtual, grads_d)
             dyn_cols["grad_norm_final"] = float(st["grad_norm"])

@@ -1,55 +1,23 @@
-"""Pipeline telemetry: measured tick/phase timelines + structured run reports.
+"""Structured run reports, serving summaries and Perfetto tracks.
 
-The reference's only instrumentation is ``time.time()`` around the timed
-loop (SURVEY.md §5). This module makes the *measured* counterpart of the
-simulated tick timeline (``schedules.simulated_bubble``, ``replay_phases``)
-first-class, following arXiv:2605.24006's argument that the tick table is
-the right axis for evaluation and arXiv:2401.10241's that per-stage idle
-time should be measured, not inferred.
-
-Two pieces:
-
-- :class:`PipelineTelemetry` — an opt-in recorder the executors in
-  ``parallel.pipeline`` stamp from inside the traced program via
-  ``jax.experimental.io_callback``. Off by default: when no collector is
-  passed, the executor emits **no** callback at trace time (the jaxpr is
-  bit-identical to an uninstrumented build — tests assert ``"io_callback"
-  not in str(jaxpr)``). When enabled, each phase-scan segment (phase
-  executor), each tick (unrolled executor), or the whole table scan
-  records host-side ``perf_counter`` stamps, keyed so the analysis side
-  can reassemble a measured timeline aligned tick-for-tick with
-  ``schedules.compress_schedule``'s phases.
+The device's time is not measured here. It is read from the profiler's
+trace by the names the program gives its regions (``utils/profiling.py``
+scopes and the executors' ``pp/...`` scopes, reduced by
+``benchmark/harness/trace_reduce.py``); step time comes from the host clock
+around a loop closed with ``utils.metrics.force_completion``. This module
+keeps what a run writes down about itself:
 
 - :class:`RunReport` — a structured run recorder (counters, timers,
   gauges, JSONL event stream + a single JSON manifest carrying config,
-  mesh shape, schedule, phase stats, compile time and jax/jaxlib
-  versions) with a dependency-free :func:`validate_report` so sweeps,
-  ``fit`` and ``bench.py`` all emit the same schema instead of ad-hoc
-  dicts.
-
-Two consumers of the stamps beyond the tabular breakdown:
-
-- :func:`perfetto_trace` / :func:`write_perfetto_trace` — the measured
-  timeline serialized as Chrome-trace JSON (one track per device, one
-  complete "X" slice per F/B/W/idle cell, flow arrows for every ring-hop
-  store), loadable in ui.perfetto.dev or chrome://tracing
-  (docs/observability.md "Opening traces in Perfetto").
-
-- :func:`critical_path` — walks the measured ticks and attributes each
-  to compute (naming the straggler device under the per-tick lockstep
-  model) vs comm (a ring hop in flight, nothing computing) vs bubble
-  (nothing at all) — the attribution table the ``cost_model`` manifest
-  section embeds (``analysis.cost_model``).
-
-Stamp semantics under SPMD: ``io_callback`` inside ``shard_map`` fires
-once **per device** (a 4-device mesh emits 4 stamps per logical event), so
-every analysis groups events by ``(kind, index)`` and takes ``min`` of
-start stamps / ``max`` of end stamps — the earliest entry and the last
-straggler bound the segment. Each stamp carries a scalar *probe* derived
-from the executor's carry so plain dataflow (not effect ordering) pins the
-stamp after the computation it closes over; callbacks are emitted
-unordered, which keeps the program legal on backends where ordered
-effects constrain control flow.
+  mesh shape, schedule, compile time and jax/jaxlib versions) with a
+  dependency-free :func:`validate_report`, so sweeps, ``fit`` and the
+  serving scripts all emit the same schema instead of ad-hoc dicts.
+- :func:`serving_summary` — per-request latency (in ticks) and throughput
+  of one serving run.
+- :func:`write_perfetto_trace` and the three ``perfetto_*_events``
+  builders — serving-request slices, the tick-clock serving-load process
+  and training-dynamics counter tracks as Chrome-trace JSON, loadable in
+  ui.perfetto.dev or chrome://tracing.
 """
 
 from __future__ import annotations
@@ -59,574 +27,16 @@ import json
 import os
 import threading
 import time
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, Optional, Sequence
 
 import numpy as np
 
 SCHEMA_VERSION = 1
 
-# Event kinds carried in the first operand of every stamp.
-STEP_START, PHASE_START, PHASE_END, TICK, STEP_END = 0, 1, 2, 3, 4
-_KIND_NAMES = {STEP_START: "step_start", PHASE_START: "phase_start",
-               PHASE_END: "phase_end", TICK: "tick", STEP_END: "step_end"}
-
 
 # ---------------------------------------------------------------------------
-# Measured timelines
+# Perfetto tracks
 # ---------------------------------------------------------------------------
-
-
-def probe_of(carry) -> Any:
-    """Smallest array leaf of an executor carry, as the data-dependence
-    anchor of a stamp: the callback consumes this value, so XLA cannot
-    float the stamp before the computation that produced the carry (nor
-    drop it). Both executors' carries end in the scalar ``loss_acc``,
-    which this picks."""
-    import jax
-    leaves = [x for x in jax.tree_util.tree_leaves(carry)
-              if hasattr(x, "size")]
-    x = min(leaves, key=lambda v: v.size)
-    return x.ravel()[0]
-
-
-class PipelineTelemetry:
-    """Host-side collector for executor timing stamps.
-
-    Build-time: ``make_pipeline_grad_fn(..., telemetry=tel)`` calls
-    :meth:`attach` with the compiled tick table, its phases and the tick
-    executor it resolved, then plants :meth:`emit` calls at segment
-    boundaries. Run-time: each executed instrumented step appends
-    ``(kind, index, t_host)`` rows here (once per device). Analysis:
-    :meth:`timeline` / :meth:`stage_breakdown` / :meth:`report` after at
-    least one step has been forced to completion
-    (``utils.metrics.force_completion``).
-    """
-
-    def __init__(self) -> None:
-        self.events: List[Tuple[int, int, float]] = []
-        self.table: Optional[np.ndarray] = None
-        self.phases = None  # Tuple[schedules.Phase, ...] | None
-        self.executor: Optional[str] = None
-        # live HBM watermarks sampled at step boundaries (see _stamp);
-        # None = capability not probed yet, [] = backend has no stats
-        self.memory_samples: List[Dict[str, Any]] = []
-        self._mem_devices = None
-
-    # -- build-time -----------------------------------------------------
-
-    def attach(self, table: np.ndarray, phases, executor: str) -> None:
-        """Record the schedule the instrumented program was built against
-        (the alignment target every measured stamp is interpreted on)."""
-        self.table = np.asarray(table)
-        self.phases = tuple(phases) if phases is not None else None
-        self.executor = executor
-
-    def emit(self, kind: int, index: int, probe) -> None:
-        """Plant one stamp in the traced program. Called during tracing by
-        the executors; ``probe`` is a scalar from the live carry (see
-        :func:`probe_of`)."""
-        import jax.numpy as jnp
-        from jax.experimental import io_callback
-        io_callback(self._stamp, None, jnp.int32(kind), jnp.int32(index),
-                    probe, ordered=False)
-
-    # -- run-time host target -------------------------------------------
-
-    def _stamp(self, kind, index, _probe) -> None:
-        k = int(kind)
-        t = time.perf_counter()
-        self.events.append((k, int(index), t))
-        if k in (STEP_START, STEP_END):
-            self._sample_memory(k, t)
-
-    def _sample_memory(self, kind: int, t: float) -> None:
-        """Record per-device ``memory_stats()`` watermarks at a step
-        boundary. Rides the *existing* stamp callback — telemetry-off
-        builds still trace zero host callbacks, and backends whose
-        devices return ``None`` (CPU) probe once then no-op forever."""
-        if self._mem_devices is None:
-            try:
-                import jax
-                self._mem_devices = [
-                    d for d in jax.devices()
-                    if isinstance(d.memory_stats(), dict)]
-            except Exception:
-                self._mem_devices = []
-        for dev in self._mem_devices:
-            try:
-                stats = dev.memory_stats()
-                in_use = int(stats.get("bytes_in_use", 0))
-                self.memory_samples.append({
-                    "kind": _KIND_NAMES.get(kind, str(kind)),
-                    "device": int(dev.id), "t": t,
-                    "bytes_in_use": in_use,
-                    "peak_bytes_in_use": int(
-                        stats.get("peak_bytes_in_use", in_use)),
-                })
-            except Exception:
-                pass
-
-    def memory_summary(self) -> Dict[str, Any]:
-        """The ``live`` subsection of the manifest's ``memory`` block:
-        per-device high-water marks over the recorded samples.
-        ``available=False`` (no per-device rows) on backends without
-        allocator stats — consumers must degrade, not assume."""
-        per_dev: Dict[int, Dict[str, int]] = {}
-        for s in self.memory_samples:
-            d = s["device"]
-            row = per_dev.setdefault(
-                d, {"device": d, "peak_bytes_in_use": 0,
-                    "last_bytes_in_use": 0, "n_samples": 0})
-            row["peak_bytes_in_use"] = max(row["peak_bytes_in_use"],
-                                           s["peak_bytes_in_use"],
-                                           s["bytes_in_use"])
-            row["last_bytes_in_use"] = s["bytes_in_use"]
-            row["n_samples"] += 1
-        rows = [per_dev[d] for d in sorted(per_dev)]
-        return {
-            "available": bool(rows),
-            "n_samples": len(self.memory_samples),
-            "per_device": rows,
-            "peak_bytes_in_use": (max(r["peak_bytes_in_use"] for r in rows)
-                                  if rows else None),
-        }
-
-    def reset(self) -> None:
-        """Drop recorded events (keep the attached schedule) — call between
-        steps when only the last step's timeline is wanted."""
-        self.events = []
-        self.memory_samples = []
-
-    # -- analysis -------------------------------------------------------
-
-    def spans(self) -> Dict[Tuple[int, int], Tuple[float, float, int]]:
-        """Group per-device stamps: ``(kind, index) -> (t_min, t_max, n)``."""
-        out: Dict[Tuple[int, int], Tuple[float, float, int]] = {}
-        for kind, idx, t in self.events:
-            key = (kind, idx)
-            if key in out:
-                lo, hi, n = out[key]
-                out[key] = (min(lo, t), max(hi, t), n + 1)
-            else:
-                out[key] = (t, t, 1)
-        return out
-
-    def timeline(self) -> List[Dict[str, Any]]:
-        """The measured timeline, one record per instrumented segment.
-
-        Phase executor: one record per :class:`~..parallel.schedules.Phase`
-        (``phase``, ``start_tick``, ``n_ticks``, ``period``, ``reps``,
-        ``duration_s``) — directly comparable to ``replay_phases``' tick
-        spans. Unrolled executor: one record per tick. Scan executor: a
-        single whole-table record. Durations take the earliest start stamp
-        to the latest end stamp across devices (lockstep SPMD: the
-        straggler defines the segment).
-        """
-        if not self.events:
-            raise ValueError(
-                "no telemetry events recorded — run (and force completion "
-                "of) at least one instrumented step first")
-        spans = self.spans()
-        records: List[Dict[str, Any]] = []
-        if self.executor == "phases":
-            if self.phases is None:
-                raise ValueError("phase timeline requested but no phases "
-                                 "attached (was attach() called?)")
-            for j, ph in enumerate(self.phases):
-                start = spans.get((PHASE_START, j))
-                end = spans.get((PHASE_END, j))
-                if start is None or end is None:
-                    raise ValueError(f"phase {j} missing stamps (start="
-                                     f"{start}, end={end}) — incomplete run")
-                dur = max(end[1] - start[0], 0.0)
-                records.append({
-                    "kind": "phase", "phase": j, "start_tick": ph.start,
-                    "n_ticks": ph.length, "period": ph.period,
-                    "reps": ph.reps, "t0": start[0], "t1": end[1],
-                    "duration_s": dur,
-                })
-        elif self.executor == "unrolled":
-            t0 = spans.get((STEP_START, 0))
-            ticks = sorted(i for k, i in spans if k == TICK)
-            prev = t0[0] if t0 is not None else None
-            for t in ticks:
-                _, hi, _ = spans[(TICK, t)]
-                records.append({
-                    "kind": "tick", "tick": t, "start_tick": t, "n_ticks": 1,
-                    "t1": hi,
-                    "duration_s": (max(hi - prev, 0.0)
-                                   if prev is not None else None),
-                })
-                prev = hi
-        else:  # whole-table scan: one segment
-            start = spans.get((STEP_START, 0))
-            end = spans.get((STEP_END, 0))
-            if start is None or end is None:
-                raise ValueError("scan executor run missing step start/end "
-                                 "stamps — incomplete run")
-            n = self.table.shape[0] if self.table is not None else 0
-            records.append({
-                "kind": "step", "start_tick": 0, "n_ticks": n,
-                "t0": start[0], "t1": end[1],
-                "duration_s": max(end[1] - start[0], 0.0),
-            })
-        return records
-
-    def stage_breakdown(self) -> Dict[str, Any]:
-        """Per-stage measured F/B/W/idle attribution and bubble.
-
-        Each segment's measured duration is spread uniformly over its
-        ticks, and each (device, tick) is classified by the tick table's
-        op columns (``schedules.table_unit_activity``). That uniform
-        spread is an attribution model — within a phase the executor runs
-        a single fused scan, so per-tick variation inside a segment is
-        not observable; across segments (where schedules actually differ)
-        the attribution is measured. ``bubble_measured`` per stage is its
-        idle share of the measured makespan, the measured counterpart of
-        ``simulated_bubble``'s per-device fractions."""
-        from ..parallel.schedules import table_unit_activity
-        if self.table is None:
-            raise ValueError("no tick table attached")
-        activity = table_unit_activity(self.table)  # [T, D, 4] 0/1
-        D = activity.shape[1]
-        seconds = np.zeros((D, 4))
-        total = 0.0
-        for rec in self.timeline():
-            dur = rec.get("duration_s")
-            if dur is None:
-                continue
-            total += dur
-            t0, n = rec["start_tick"], rec["n_ticks"]
-            if n <= 0:
-                continue
-            per_tick = dur / n
-            seconds += activity[t0:t0 + n].sum(axis=0) * per_tick
-        per_stage = []
-        for d in range(D):
-            f_s, b_s, w_s, idle_s = (float(x) for x in seconds[d])
-            per_stage.append({
-                "device": d, "f_s": f_s, "b_s": b_s, "w_s": w_s,
-                "idle_s": idle_s,
-                "bubble_measured": idle_s / total if total > 0 else 0.0,
-            })
-        busy = seconds[:, :3].sum()
-        split = (seconds[:, :3].sum(axis=0) / busy if busy > 0
-                 else np.zeros(3))
-        return {
-            "total_s": total,
-            "per_stage": per_stage,
-            "f_frac": float(split[0]), "b_frac": float(split[1]),
-            "w_frac": float(split[2]),
-            "bubble_measured_mean": float(
-                np.mean([s["bubble_measured"] for s in per_stage])),
-        }
-
-    def report(self) -> Dict[str, Any]:
-        """The telemetry section embedded in :class:`RunReport` manifests."""
-        out: Dict[str, Any] = {"executor": self.executor,
-                               "n_events": len(self.events)}
-        if self.phases is not None:
-            from ..parallel.schedules import phase_stats
-            out["phase_stats"] = phase_stats(self.phases)
-        if self.events:
-            out["timeline"] = self.timeline()
-            if self.table is not None:
-                out["stage_breakdown"] = self.stage_breakdown()
-        if self.memory_samples:
-            out["memory_watermarks"] = self.memory_summary()
-        return out
-
-
-# ---------------------------------------------------------------------------
-# Trace export + critical-path attribution
-# ---------------------------------------------------------------------------
-
-
-def _tick_times(telemetry: PipelineTelemetry):
-    """Per-tick ``(t0, duration)`` seconds, relative to the first stamp.
-
-    Segment durations are spread uniformly over the segment's ticks (the
-    same attribution model as :meth:`PipelineTelemetry.stage_breakdown`:
-    inside one fused scan per-tick variation is not observable). Phase and
-    scan segments carry absolute ``t0``/``t1`` stamps; unrolled records
-    only a ``t1`` per tick, so starts chain from the previous boundary."""
-    if telemetry.table is None:
-        raise ValueError("no tick table attached")
-    T = int(telemetry.table.shape[0])
-    t0 = np.zeros(T)
-    dur = np.zeros(T)
-    origin = None
-    cursor = 0.0
-    for rec in telemetry.timeline():
-        start, n = rec["start_tick"], rec["n_ticks"]
-        d = rec.get("duration_s") or 0.0
-        per = d / n if n else 0.0
-        if rec.get("t0") is not None:
-            if origin is None:
-                origin = rec["t0"]
-            base = rec["t0"] - origin
-        elif rec.get("t1") is not None:
-            if origin is None:
-                origin = rec["t1"] - d
-            base = rec["t1"] - origin - d
-        else:
-            base = cursor
-        for k in range(n):
-            if start + k < T:
-                t0[start + k] = base + k * per
-                dur[start + k] = per
-        cursor = base + n * per
-    return t0, dur
-
-
-def _store_channels():
-    """(name, store column, sender offset) per ring direction: a store at
-    ``(t, d, col)`` banks data ppermuted during tick ``t-1`` by device
-    ``(d - offset) % D`` (same convention as
-    ``analysis.table_check.RING_CHANNELS``)."""
-    from ..parallel.schedules import (COL_STORE_B_POS_SLOT, COL_STORE_B_SLOT,
-                                      COL_STORE_F_NEG_SLOT, COL_STORE_F_SLOT)
-    return (("fwd_ring_pos", COL_STORE_F_SLOT, +1),
-            ("bwd_ring_neg", COL_STORE_B_SLOT, -1),
-            ("fwd_ring_neg", COL_STORE_F_NEG_SLOT, -1),
-            ("bwd_ring_pos", COL_STORE_B_POS_SLOT, +1))
-
-
-def critical_path(telemetry: PipelineTelemetry) -> Dict[str, Any]:
-    """Attribute each measured tick to compute vs comm vs bubble.
-
-    Under the executor's lockstep model every device waits for the tick's
-    straggler, so a tick is *compute* when any device runs a unit (the
-    straggler = the device with the heaviest weighted work that tick,
-    F=1/B=2/W=1), *comm* when nothing computes but a ring hop is in
-    flight (some channel banks a store next tick), and *bubble* when the
-    tick does neither. Returns aggregate seconds, the per-tick
-    classification, and per-device straggler time — "which stage is the
-    step waiting on" as a number.
-
-    Training-table comm is additionally attributed overlap-aware: each
-    hop landing at tick ``t`` is classified by its verified bank stage
-    (:func:`parallel.schedules.overlap_bank_stages`) into
-    ``hops_exposed`` (banks before the first unit — serial even under
-    ``comm_overlap="ring"``) vs ``hops_overlappable`` (hides behind the
-    units that run before its bank point); the aggregate
-    ``exposed_hop_ticks`` / ``overlappable_hop_ticks`` are the same
-    counts the cost model's ``comm_overlap`` mode prices."""
-    from ..parallel.schedules import (BANK_BEFORE_F, N_COLS,
-                                      overlap_bank_stages,
-                                      table_unit_activity)
-    if telemetry.table is None:
-        raise ValueError("no tick table attached")
-    table = telemetry.table
-    T, D = int(table.shape[0]), int(table.shape[1])
-    activity = table_unit_activity(table)  # [T, D, 4]
-    t0, dur = _tick_times(telemetry)
-    weights = np.array([1.0, 2.0, 1.0, 0.0])
-    work = activity.astype(np.float64) @ weights  # [T, D]
-    channels = _store_channels()
-    store_cols = [col for _, col, _ in channels]
-    # overlap-aware hop attribution: classify each landing hop by its
-    # verified bank stage (exposed = fences the tick's first unit even
-    # under comm_overlap="ring"; overlappable = hides behind the units
-    # before its bank point). Forward-only tables have no stage map.
-    bank_st = (overlap_bank_stages(table) if table.shape[2] >= N_COLS
-               else None)
-    agg = {"compute": 0.0, "comm": 0.0, "bubble": 0.0}
-    exposed_hops = overlappable_hops = 0
-    straggler_s = np.zeros(D)
-    per_tick: List[Dict[str, Any]] = []
-    for t in range(T):
-        hop_in_flight = (t + 1 < T
-                         and bool((table[t + 1][:, store_cols] >= 0).any()))
-        if work[t].max() > 0:
-            cls = "compute"
-            straggler = int(work[t].argmax())
-            straggler_s[straggler] += dur[t]
-        elif hop_in_flight:
-            cls, straggler = "comm", None
-        else:
-            cls, straggler = "bubble", None
-        agg[cls] += dur[t]
-        row: Dict[str, Any] = {"tick": t, "class": cls,
-                               "straggler": straggler,
-                               "duration_s": float(dur[t])}
-        if bank_st is not None and t >= 1:
-            n_exp = n_lap = 0
-            for ci, (_, col, _) in enumerate(channels):
-                if (table[t][:, col] >= 0).any():
-                    if int(bank_st[t, ci]) == BANK_BEFORE_F:
-                        n_exp += 1
-                    else:
-                        n_lap += 1
-            if n_exp or n_lap:
-                row["hops_exposed"], row["hops_overlappable"] = n_exp, n_lap
-            exposed_hops += n_exp
-            overlappable_hops += n_lap
-        per_tick.append(row)
-    sd = int(straggler_s.argmax())
-    return {
-        "n_ticks": T,
-        "total_s": float(dur.sum()),
-        "compute_s": float(agg["compute"]),
-        "comm_s": float(agg["comm"]),
-        "bubble_s": float(agg["bubble"]),
-        "exposed_hop_ticks": exposed_hops,
-        "overlappable_hop_ticks": overlappable_hops,
-        "straggler_s_per_device": [float(x) for x in straggler_s],
-        "straggler_device": sd,
-        "straggler_stage": f"device {sd}",
-        "per_tick": per_tick,
-    }
-
-
-def perfetto_trace(telemetry: PipelineTelemetry,
-                   serving_events: Optional[List[Dict[str, Any]]] = None,
-                   dynamics_events: Optional[List[Dict[str, Any]]] = None,
-                   predicted_tick_s: Optional[Sequence[float]] = None
-                   ) -> Dict[str, Any]:
-    """The measured timeline as a Chrome-trace/Perfetto JSON object.
-
-    One track (tid) per pipeline device under a single process, one
-    complete ``"X"`` slice per (tick, device) unit — named ``F m3`` /
-    ``B v1 m2`` / ``W m0`` / ``idle``, categorized by kind — and one
-    ``"s"``→``"f"`` flow pair per ring-hop store (cat ``ppermute``,
-    anchored mid-slice on the sending and receiving ticks) so arrows in
-    the UI show exactly the hops the table predicts; each flow's args
-    carry its verified ``bank_stage`` and an ``overlap`` tag
-    (``exposed`` = fences the landing tick's first unit,
-    ``overlappable`` = hides behind compute under
-    ``comm_overlap="ring"``). When the telemetry
-    carries live watermark samples, each device additionally gets a
-    ``"C"`` counter track (``HBM bytes_in_use``) sampled at step
-    boundaries, drawn right next to the F/B/W slices. ``serving_events``:
-    RunReport event rows — ``serve_admit``/``serve_finish`` pairs become
-    async request slices on a separate "requests" process
-    (:func:`perfetto_request_events`). ``dynamics_events``: RunReport
-    ``dynamics`` event rows — per-stage grad-norm counter tracks on a
-    "training dynamics" process (:func:`perfetto_dynamics_events`).
-    ``predicted_tick_s``: the cost model's per-tick predicted seconds
-    (``analysis.cost_model.predicted_tick_seconds``, length ``T``) — when
-    given, every per-tick slice's args additionally carry
-    ``predicted_tick_s`` / ``measured_tick_s`` / ``rel_err`` (signed,
-    predicted vs measured), so clicking any slice answers "was this tick
-    slower than the model said" without leaving the UI (the calibration
-    observatory's per-tick view, docs/observability.md §9).
-    Timestamps are microseconds from the first stamp, sorted ascending;
-    load the written file in ui.perfetto.dev or chrome://tracing."""
-    from ..parallel.schedules import (COL_BWD_M, COL_BWD_V, COL_FWD_M,
-                                      COL_FWD_V, COL_W_M, COL_W_V)
-    if telemetry.table is None:
-        raise ValueError("no tick table attached")
-    table = telemetry.table
-    T, D = int(table.shape[0]), int(table.shape[1])
-    n_virtual = max(1, (int(table[..., (COL_FWD_V, COL_BWD_V, COL_W_V),
-                                ].max()) + 1))
-    t0, dur = _tick_times(telemetry)
-    us = 1e6
-    events: List[Dict[str, Any]] = [{
-        "ph": "M", "name": "process_name", "pid": 0, "tid": 0, "ts": 0.0,
-        "args": {"name": f"pipeline ({telemetry.executor})"},
-    }]
-    for d in range(D):
-        events.append({"ph": "M", "name": "thread_name", "pid": 0, "tid": d,
-                       "ts": 0.0, "args": {"name": f"device {d}"}})
-    units = ((COL_FWD_V, COL_FWD_M, "F"), (COL_BWD_V, COL_BWD_M, "B"),
-             (COL_W_V, COL_W_M, "W"))
-    n_predicted = 0
-    for t in range(T):
-        ts, width = t0[t] * us, dur[t] * us
-        # calibration annotation: the cost model's prediction for this
-        # tick next to its measured duration, on every slice of the tick
-        pred_args: Dict[str, Any] = {}
-        if predicted_tick_s is not None and t < len(predicted_tick_s):
-            n_predicted += 1
-            p = float(predicted_tick_s[t])
-            pred_args = {"predicted_tick_s": p,
-                         "measured_tick_s": float(dur[t])}
-            if dur[t] > 0:
-                pred_args["rel_err"] = (p - float(dur[t])) / float(dur[t])
-        for d in range(D):
-            row = table[t, d]
-            active = 0
-            for col_v, col_m, kind in units:
-                if row[col_m] >= 0:
-                    active += 1
-                    v, m = int(row[col_v]), int(row[col_m])
-                    name = (f"{kind} v{v} m{m}" if n_virtual > 1
-                            else f"{kind} m{m}")
-                    events.append({
-                        "ph": "X", "name": name, "cat": kind, "pid": 0,
-                        "tid": d, "ts": ts, "dur": width,
-                        "args": {"tick": t, "v": v, "m": m, **pred_args}})
-            if active == 0:
-                events.append({"ph": "X", "name": "idle", "cat": "idle",
-                               "pid": 0, "tid": d, "ts": ts, "dur": width,
-                               "args": {"tick": t, **pred_args}})
-    # flow args carry the hop's verified bank stage so overlapped comm
-    # reads directly off the arrows: stage 0 arrivals fence the landing
-    # tick's first unit (exposed), later stages ride under its compute
-    from ..parallel.schedules import (BANK_BEFORE_F, N_COLS,
-                                      overlap_bank_stages)
-    bank_st = (overlap_bank_stages(table) if table.shape[2] >= N_COLS
-               else None)
-    flow_id = 0
-    n_overlappable = 0
-    for t in range(1, T):
-        for ci, (name, col, offset) in enumerate(_store_channels()):
-            stage = None if bank_st is None else int(bank_st[t, ci])
-            overlapped = stage is not None and stage > BANK_BEFORE_F
-            for d in range(D):
-                if table[t, d, col] >= 0:
-                    flow_id += 1
-                    n_overlappable += int(overlapped)
-                    sender = (d - offset) % D
-                    args = ({} if stage is None else
-                            {"bank_stage": stage,
-                             "overlap": ("overlappable" if overlapped
-                                         else "exposed")})
-                    events.append({
-                        "ph": "s", "id": flow_id, "name": name,
-                        "cat": "ppermute", "pid": 0, "tid": sender,
-                        "ts": (t0[t - 1] + 0.5 * dur[t - 1]) * us,
-                        "args": args})
-                    events.append({
-                        "ph": "f", "bp": "e", "id": flow_id, "name": name,
-                        "cat": "ppermute", "pid": 0, "tid": d,
-                        "ts": (t0[t] + 0.5 * dur[t]) * us,
-                        "args": args})
-    # live HBM counter track: one "C" event per (boundary sample, device),
-    # on the same clock as the stamps so the sawtooth lines up with ticks
-    n_counters = 0
-    if telemetry.memory_samples:
-        origin = min(t for _, _, t in telemetry.events)
-        for s in telemetry.memory_samples:
-            n_counters += 1
-            events.append({
-                "ph": "C", "name": f"HBM device {s['device']}",
-                "cat": "memory", "pid": 0, "tid": 0,
-                "ts": max(s["t"] - origin, 0.0) * us,
-                "args": {"bytes_in_use": s["bytes_in_use"],
-                         "peak_bytes_in_use": s["peak_bytes_in_use"]}})
-    if serving_events:
-        events.extend(perfetto_request_events(serving_events))
-    n_dyn = 0
-    if dynamics_events:
-        dyn_rows = perfetto_dynamics_events(dynamics_events)
-        n_dyn = sum(1 for e in dyn_rows if e["ph"] == "C")
-        events.extend(dyn_rows)
-    # sorted ts is part of the format contract (and what the schema test
-    # pins); metadata first among equals so track names land before slices
-    events.sort(key=lambda e: (e["ts"], 0 if e["ph"] == "M" else 1))
-    return {
-        "traceEvents": events,
-        "displayTimeUnit": "ms",
-        "otherData": {"executor": telemetry.executor, "n_devices": D,
-                      "n_ticks": T, "n_flows": flow_id,
-                      "n_overlappable_flows": n_overlappable,
-                      "n_memory_counters": n_counters,
-                      "n_dynamics_counters": n_dyn,
-                      "n_predicted_ticks": n_predicted},
-    }
 
 
 def perfetto_request_events(serving_events: List[Dict[str, Any]],
@@ -805,12 +215,10 @@ def perfetto_dynamics_events(dynamics_events: List[Dict[str, Any]],
                              pid: int = 2) -> List[Dict[str, Any]]:
     """Per-stage grad-norm counter tracks from RunReport ``dynamics``
     event rows (the rows ``fit`` streams at every log sync), one ``"C"``
-    counter per (log point, stage) plus global grad-norm and GNS tracks
-    — the model-health twin of the HBM sawtooth. The rows carry the
-    event stream's wall clock (a different clock than the executor
-    stamps), so they land on their own "training dynamics" process,
-    normalized to the first dynamics row; within the process, step
-    ordering is exact."""
+    counter per (log point, stage) plus global grad-norm and GNS tracks.
+    The rows carry the event stream's wall clock, so they land on their
+    own "training dynamics" process, normalized to the first dynamics
+    row; within the process, step ordering is exact."""
     rows = [r for r in (dynamics_events or [])
             if r.get("kind") == "dynamics" and "t" in r]
     if not rows:
@@ -843,36 +251,22 @@ def perfetto_dynamics_events(dynamics_events: List[Dict[str, Any]],
     return out
 
 
-def write_perfetto_trace(telemetry: Optional[PipelineTelemetry], path: str,
+def write_perfetto_trace(path: str,
                          serving_events: Optional[List[Dict[str, Any]]] = None,
                          dynamics_events: Optional[List[Dict[str, Any]]] = None,
-                         serving_load_tracks: Optional[Dict[str, Any]] = None,
-                         predicted_tick_s: Optional[Sequence[float]] = None
+                         serving_load_tracks: Optional[Dict[str, Any]] = None
                          ) -> str:
-    """Serialize :func:`perfetto_trace` to ``path``; returns the path.
-    With ``telemetry=None`` (a serving-only run has no pipeline
-    telemetry) the trace holds just the requests/dynamics tracks.
+    """Write the requests / dynamics / serving-load tracks to ``path`` as
+    Chrome-trace JSON; returns the path.
     ``serving_load_tracks`` (optional) adds the tick-clock serving-load
     process (:func:`perfetto_serving_load_events`): a dict with any of
     ``occupancy``/``queue_depth`` (block-boundary ``(tick, n)`` samples)
     and ``s_per_tick``; the request sub-spans come from
-    ``serving_events``. ``predicted_tick_s``: per-tick cost-model
-    predictions for the calibration annotations (see
-    :func:`perfetto_trace`)."""
-    if telemetry is None:
-        rows = perfetto_request_events(serving_events or [])
-        rows.extend(perfetto_dynamics_events(dynamics_events or []))
-        trace: Dict[str, Any] = {
-            "traceEvents": rows,
-            "displayTimeUnit": "ms",
-            "otherData": {"executor": "serving"},
-        }
-    else:
-        trace = perfetto_trace(telemetry, serving_events=serving_events,
-                               dynamics_events=dynamics_events,
-                               predicted_tick_s=predicted_tick_s)
+    ``serving_events``."""
+    rows = perfetto_request_events(serving_events or [])
+    rows.extend(perfetto_dynamics_events(dynamics_events or []))
     if serving_load_tracks is not None:
-        trace["traceEvents"].extend(perfetto_serving_load_events(
+        rows.extend(perfetto_serving_load_events(
             serving_events or [],
             occupancy=serving_load_tracks.get("occupancy"),
             queue_depth=serving_load_tracks.get("queue_depth"),
@@ -881,6 +275,7 @@ def write_perfetto_trace(telemetry: Optional[PipelineTelemetry], path: str,
             page_fragmentation=serving_load_tracks.get(
                 "page_fragmentation"),
             acceptance=serving_load_tracks.get("acceptance")))
+    trace = {"traceEvents": rows, "displayTimeUnit": "ms"}
     with open(path, "w") as fh:
         json.dump(trace, fh)
     return path
@@ -1016,8 +411,8 @@ def _spec_summary_fields(result) -> Dict[str, Any]:
 class RunReport:
     """Counters / timers / gauges + JSONL events + a single JSON manifest.
 
-    One instance per run (a ``fit`` call, a sweep row, a bench
-    invocation). With ``out_dir`` set, :meth:`event` streams to
+    One instance per run (a ``fit`` call, a sweep row, a serving
+    script). With ``out_dir`` set, :meth:`event` streams to
     ``events.jsonl`` as it happens (crash-safe partial record) and
     :meth:`write` drops ``report.json``; without it everything stays
     in-memory and :meth:`manifest` returns the same schema for embedding.
@@ -1037,7 +432,6 @@ class RunReport:
         self.gauges: Dict[str, Any] = {}
         self.timers: Dict[str, float] = {}
         self.events: List[Dict[str, Any]] = []
-        self.telemetry: Optional[Dict[str, Any]] = None
         self.serving: List[Dict[str, Any]] = []
         self.serving_load: Optional[Dict[str, Any]] = None
         self.resilience: Optional[Dict[str, Any]] = None
@@ -1091,10 +485,6 @@ class RunReport:
                                       + "\n")
                 self._events_fh.flush()
 
-    def attach_telemetry(self, telemetry: PipelineTelemetry) -> None:
-        """Embed a measured-timeline section (:meth:`PipelineTelemetry.report`)."""
-        self.telemetry = telemetry.report()
-
     def attach_serving(self, summary: Dict[str, Any]) -> None:
         """Append one serving-run latency summary
         (:func:`serving_summary`) to the manifest's ``serving`` list —
@@ -1129,8 +519,8 @@ class RunReport:
     def attach_cost_model(self, section: Dict[str, Any]) -> None:
         """Embed the roofline accounting
         (:func:`analysis.cost_model.cost_model_section`: predicted vs
-        measured step time, bubble fractions, ppermute hops, MFU/HFU,
-        critical-path attribution) as the manifest's ``cost_model``
+        measured step time, bubble fractions, ppermute hops, MFU/HFU)
+        as the manifest's ``cost_model``
         block — the record ``scripts/regress.py`` reads."""
         self.cost_model = dict(section)
 
@@ -1146,8 +536,8 @@ class RunReport:
         """Embed the HBM accounting
         (:func:`analysis.memory_model.memory_model_section` /
         ``serving_memory_section``: analytic per-device bytes from the
-        verifier's slot peaks, AOT-compiled ``memory_analysis()``, live
-        watermark summary and their reconciliation) as the manifest's
+        verifier's slot peaks, AOT-compiled ``memory_analysis()`` and
+        their reconciliation) as the manifest's
         ``memory`` block — the bytes-domain record ``scripts/regress.py``
         guards."""
         self.memory = dict(section)
@@ -1177,8 +567,6 @@ class RunReport:
             out["events_path"] = os.path.join(self.out_dir, "events.jsonl")
         else:
             out["events"] = _jsonable(self.events)
-        if self.telemetry is not None:
-            out["telemetry"] = _jsonable(self.telemetry)
         if self.serving:
             out["serving"] = _jsonable(self.serving)
         if self.serving_load is not None:
@@ -1238,7 +626,10 @@ def _jsonable(x: Any) -> Any:
 
 def validate_report(manifest: Dict[str, Any]) -> None:
     """Schema check for a RunReport manifest (hand-rolled: the container
-    has no jsonschema). Raises ``ValueError`` on the first violation."""
+    has no jsonschema). Raises ``ValueError`` on the first violation.
+    Keys it does not know are ignored, so a manifest written before PR 32
+    (with a ``telemetry`` section, a ``memory.live`` leg or a
+    ``cost_model.attribution`` table) still validates."""
     def fail(msg: str):
         raise ValueError(f"invalid run report: {msg}")
 
@@ -1278,15 +669,6 @@ def validate_report(manifest: Dict[str, Any]) -> None:
                 fail("each event needs a str 'kind' and numeric 't'")
     elif not isinstance(manifest.get("events_path"), str):
         fail("manifest needs either inline 'events' or an 'events_path'")
-    tel = manifest.get("telemetry")
-    if tel is not None:
-        if not isinstance(tel, dict):
-            fail("telemetry must be a dict")
-        if "timeline" in tel:
-            if not isinstance(tel["timeline"], list) or not all(
-                    isinstance(r, dict) and "duration_s" in r
-                    and "n_ticks" in r for r in tel["timeline"]):
-                fail("telemetry.timeline rows need duration_s and n_ticks")
     serving = manifest.get("serving")
     if serving is not None:
         if not isinstance(serving, list):
@@ -1435,13 +817,6 @@ def validate_report(manifest: Dict[str, Any]) -> None:
             for key in ("step_s", "mfu"):
                 if not isinstance(measured.get(key), (int, float)):
                     fail(f"cost_model.measured.{key} must be a number")
-        attrib = cm.get("attribution")
-        if attrib is not None:
-            if not isinstance(attrib, dict):
-                fail("cost_model.attribution must be a dict")
-            for key in ("compute_s", "comm_s", "bubble_s"):
-                if not isinstance(attrib.get(key), (int, float)):
-                    fail(f"cost_model.attribution.{key} must be a number")
     mem = manifest.get("memory")
     if mem is not None:
         if not isinstance(mem, dict):
@@ -1477,13 +852,6 @@ def validate_report(manifest: Dict[str, Any]) -> None:
                 for key in ("argument_bytes", "output_bytes", "temp_bytes"):
                     if not isinstance(comp.get(key), (int, float)):
                         fail(f"memory.compiled.{key} must be a number")
-        live = mem.get("live")
-        if live is not None:
-            if not isinstance(live, dict) or not isinstance(
-                    live.get("available"), bool):
-                fail("memory.live needs a bool 'available'")
-            if not isinstance(live.get("per_device"), list):
-                fail("memory.live.per_device must be a list")
     dyn = manifest.get("dynamics")
     if dyn is not None:
         if not isinstance(dyn, dict):

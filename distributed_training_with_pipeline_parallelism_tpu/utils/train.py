@@ -48,7 +48,7 @@ def make_train_step(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig,
                     sp_attn_impl: str = "ring",
                     tp_vocab_parallel: bool = False,
                     fsdp: bool = False, remat_backward=None,
-                    unroll_ticks=None, telemetry=None,
+                    unroll_ticks=None,
                     guard=None, fault_plan=None, dynamics=None,
                     ) -> Callable[[Pytree, Any, jax.Array, jax.Array],
                                   Tuple[Pytree, Any, jax.Array]]:
@@ -71,10 +71,7 @@ def make_train_step(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig,
     ``unroll_ticks`` picks the tick-executor formulation (None = auto:
     unrolled up to 64 table rows, phase-compressed scan beyond; also
     ``True``/``False``/``"phases"`` — compile-time economics in
-    :func:`..parallel.pipeline.make_pipeline_grad_fn`). ``telemetry``
-    (opt-in ``utils.telemetry.PipelineTelemetry``) records a measured
-    tick/phase timeline for the grad program; None (default) compiles
-    zero instrumentation.
+    :func:`..parallel.pipeline.make_pipeline_grad_fn`).
 
     ``guard`` (a ``utils.resilience.AnomalyGuard``) switches to the
     *guarded* step: ``(params, opt_state, tokens, targets[, rng],
@@ -113,7 +110,6 @@ def make_train_step(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig,
                                     tp_vocab_parallel=tp_vocab_parallel,
                                     fsdp=fsdp, remat_backward=remat_backward,
                                     unroll_ticks=unroll_ticks,
-                                    telemetry=telemetry,
                                     dynamics=want_gns)
     n_stages = mesh.shape[PIPE_AXIS] * sched.n_virtual
     resting = param_shardings(cfg, mesh, moe=moe, fsdp=fsdp,
@@ -468,7 +464,6 @@ def fit(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig, params: Pytree,
         profile_steps: Tuple[int, int] = (2, 5),
         grad_accum: int = 1,
         report_dir: Optional[str] = None,
-        telemetry=None,
         keep_last: Optional[int] = None,
         guard=None, fault_plan=None,
         handle_preemption: bool = False,
@@ -519,10 +514,7 @@ def fit(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig, params: Pytree,
       eval point) plus a final ``report.json`` manifest (config, mesh
       shape, schedule, compile_s, jax/jaxlib versions, final metrics) in
       the schema ``telemetry.validate_report`` checks — the same schema
-      sweep rows and ``bench.py`` emit (docs/observability.md).
-    - ``telemetry``: opt-in ``telemetry.PipelineTelemetry`` wired into the
-      compiled step (measured tick/phase timeline); its analysis is
-      embedded in the report manifest when ``report_dir`` is also set.
+      sweep rows emit (docs/observability.md).
 
     Resilience (docs/resilience.md; all opt-in, off by default):
 
@@ -584,7 +576,6 @@ def fit(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig, params: Pytree,
                               tp_vocab_parallel=tp_vocab_parallel,
                               fsdp=fsdp, remat_backward=remat_backward,
                               unroll_ticks=unroll_ticks,
-                              telemetry=telemetry,
                               guard=guard, fault_plan=fault_plan,
                               dynamics=dcfg)
     report = None
@@ -716,7 +707,7 @@ def fit(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig, params: Pytree,
     profiling = False
     preempted = False
     last_done = start_step - 1  # newest step whose outputs params hold
-    data_shape = None  # (batch, seq) of the first batch, for the cost model
+    data_shape = None  # (batch, seq) of the first batch, for the memory model
 
     def _finalize_report():
         if report is None:
@@ -724,46 +715,10 @@ def fit(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig, params: Pytree,
         report.count("steps", max(last_done - start_step + 1, 0))
         if history:
             report.gauge("final_loss", history[-1][1])
-        if telemetry is not None:
-            report.attach_telemetry(telemetry)
-            # close the predicted<->measured loop: roofline section over
-            # the same compiled table the stamps were recorded against
-            # (docs/observability.md "Cost model & MFU"); never lets an
-            # accounting error take down the run's report
-            if telemetry.events and data_shape is not None:
-                try:
-                    from ..analysis.calibration import (
-                        calibration_section_from_cost_model,
-                        maybe_load_default_corrections)
-                    from ..analysis.cost_model import cost_model_section
-                    from ..parallel.schedules import compile_schedule
-                    cs = compile_schedule(sched.name, mesh.shape["pipe"],
-                                          sched.n_virtual,
-                                          sched.n_microbatches)
-                    if (telemetry.table is not None
-                            and cs.table.shape == telemetry.table.shape):
-                        corrections = maybe_load_default_corrections()
-                        cm = cost_model_section(
-                            cs, cfg, batch_size=data_shape[0],
-                            seq_length=data_shape[1],
-                            remat_backward=remat_backward,
-                            telemetry=telemetry, correction=corrections)
-                        report.attach_cost_model(cm)
-                        # the run's own predicted-vs-measured point
-                        # (docs/observability.md §9)
-                        cal = calibration_section_from_cost_model(
-                            cm, backend=jax.devices()[0].platform,
-                            name=f"train_{sched.name}",
-                            correction=corrections)
-                        if cal is not None:
-                            report.attach_calibration(cal)
-                except Exception as e:
-                    report.event("cost_model_error", error=str(e))
         if data_shape is not None:
-            # bytes-domain twin of the cost-model attach: analytic HBM
-            # from the verifier's slot peaks (+ AdamW's two fp32 moments)
-            # plus any live watermarks the stamps sampled — same
-            # never-take-down-the-run discipline
+            # analytic HBM from the verifier's slot peaks (+ AdamW's two
+            # fp32 moments); an accounting error never takes down the
+            # run's report
             try:
                 from ..analysis.memory_model import memory_model_section
                 from ..parallel.schedules import compile_schedule
@@ -773,7 +728,7 @@ def fit(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig, params: Pytree,
                     cs, cfg, batch_size=data_shape[0],
                     seq_length=data_shape[1],
                     remat_backward=remat_backward,
-                    optimizer_slots=2, telemetry=telemetry))
+                    optimizer_slots=2))
             except Exception as e:
                 report.event("memory_model_error", error=str(e))
         if dcfg is not None:

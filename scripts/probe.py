@@ -10,9 +10,8 @@ The measured leg of the calibration observatory (docs/observability.md
   under the fit, append the rows to ``results/calibration.jsonl``,
   persist the fitted corrections as a versioned fingerprinted artifact
   (``results/calibration_corrections.json``), and write a RunReport
-  whose ``calibration`` section passes ``validate_report`` plus a
-  Perfetto trace whose per-tick slices carry predicted-vs-measured
-  args. ``--check`` turns the report into a gate: the corrected
+  whose ``calibration`` section passes ``validate_report``.
+  ``--check`` turns the report into a gate: the corrected
   predictions must beat the raw ones (median |relative error|) — a
   hard failure on real hardware, a warning on the CPU proxy (tier-1
   runs it warn-only; a sim mesh measures the host, not the model).
@@ -39,7 +38,7 @@ sys.path.insert(0, ROOT)
 def parse_args(argv):
     p = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     p.add_argument("out_dir", nargs="?", default="/tmp/probe_smoke",
-                   help="report/trace output directory")
+                   help="report output directory")
     p.add_argument("--grid", default="smoke",
                    help="probe grid name (analysis.calibration._GRIDS)")
     p.add_argument("--seed", type=int, default=0,
@@ -143,10 +142,8 @@ def run_grid(args) -> int:
 
     import jax
 
-    from distributed_training_with_pipeline_parallelism_tpu.analysis.cost_model import (
-        predicted_tick_seconds)
     from distributed_training_with_pipeline_parallelism_tpu.utils.telemetry import (
-        RunReport, validate_report, write_perfetto_trace)
+        RunReport, validate_report)
 
     backend = jax.devices()[0].platform
     ledger = _resolve(args.ledger, cal.DEFAULT_LEDGER_PATH)
@@ -155,16 +152,13 @@ def run_grid(args) -> int:
 
     print(f"probe: {len(specs)} probes ({args.grid} grid, seed "
           f"{args.seed}) on backend={backend}")
-    measured, detail = [], {}
+    measured = []
     for i, spec in enumerate(specs):
         t_start = time.time()
-        # stash live objects from ring probes: the unrolled executor's
-        # telemetry has the per-tick timeline the annotated trace needs
-        sink = detail if spec.comm_overlap == "ring" else None
         row = cal.run_probe(spec, seed=args.seed,
                             num_iterations=args.iterations,
                             warmup_iterations=args.warmup,
-                            t=t_start, detail=sink)
+                            t=t_start)
         measured.append((spec, row))
         err = (row.get("rel_err") or {}).get("step_s")
         print(f"probe [{i + 1}/{len(specs)}] {spec.label}: measured "
@@ -195,33 +189,6 @@ def run_grid(args) -> int:
     report.count("probes", len(rows))
     report.attach_calibration(section)
 
-    trace_ok = False
-    if detail:
-        # annotated Perfetto trace from a real ring probe: every
-        # per-tick slice carries predicted_tick_s / measured_tick_s /
-        # rel_err under the corrected roofline
-        cm, cs = detail["cost_model"], detail["compiled_schedule"]
-        report.attach_cost_model(cm)
-        report.attach_memory(detail["memory"])
-        hwd = cm["hardware"]
-        unit = cm["flops"]["unit"]
-        unit_sec = (unit["F"] / hwd["peak_flops"],
-                    unit["B"] / hwd["peak_flops"],
-                    unit["W"] / hwd["peak_flops"])
-        hop_s = cm["comm"]["bytes_per_hop"] / hwd["ici_bytes_per_s"]
-        pred_tick = predicted_tick_seconds(
-            cs.table, unit_sec, hop_s,
-            correction=corrections.get(hwd["name"]))
-        trace_path = write_perfetto_trace(
-            detail["telemetry"], os.path.join(args.out_dir, "trace.json"),
-            predicted_tick_s=pred_tick)
-        with open(trace_path) as fh:
-            trace = json.load(fh)
-        n_pred = trace.get("otherData", {}).get("n_predicted_ticks", 0)
-        trace_ok = n_pred > 0
-        print(f"probe: trace with {n_pred} predicted-vs-measured ticks "
-              f"at {trace_path}")
-
     manifest = report.write()
     validate_report(manifest)
 
@@ -246,9 +213,6 @@ def run_grid(args) -> int:
     elif not cor_err < raw_err:
         failures.append(f"corrected median |rel err| {cor_err:.4f} is not "
                         f"below raw {raw_err:.4f}")
-    if not trace_ok:
-        failures.append("Perfetto trace carries no predicted-vs-measured "
-                        "tick annotations")
 
     # artifact byte-roundtrip: load -> rebuild -> identical bytes on disk
     loaded = cal.load_correction_artifact(corrections_path)

@@ -1,7 +1,7 @@
 """Perf-regression sentinel: compare a run report against its history.
 
-Reads one or more RunReport manifests (``report.json`` from
-``fit``/``bench.py``/``scripts/telemetry_smoke.py`` — anything carrying
+Reads one or more RunReport manifests (``report.json`` from ``fit``, a
+sweep row, ``scripts/probe.py`` or a serving script — anything carrying
 gauges and/or a ``cost_model`` section), extracts the headline perf
 numbers, appends them as one JSON line each to ``results/history.jsonl``,
 and fails when a number regresses against the median of prior runs of
@@ -9,11 +9,10 @@ the same (name, backend, schedule) group:
 
 - ``tokens_per_sec`` drops by more than ``--threshold`` (default 10%),
 - ``mfu`` drops by more than the threshold,
-- ``bubble`` (measured bubble fraction when the report has telemetry,
-  else the table-exact prediction) rises by more than the threshold,
+- ``bubble`` (the table-exact prediction) rises by more than the
+  threshold,
 - ``peak_temp_bytes`` (XLA's compiled scratch high-water mark from the
-  report's ``memory`` section) or ``peak_live_bytes`` (the sampled
-  ``memory_stats()`` watermark) grows by more than the threshold — the
+  report's ``memory`` section) grows by more than the threshold — the
   HBM guard: a schedule or remat change that silently inflates memory
   fails here before it OOMs a real chip,
 - ``max_sustainable_load`` (from the report's ``serving_load`` section:
@@ -31,11 +30,6 @@ the same (name, backend, schedule) group:
   ``acceptance_rate`` (drop), ``spec_tokens_per_sec`` (drop) and
   ``spec_tick_gain`` (drop — the tick-domain capacity headline of the
   serve_spec leg) under the same discipline,
-- ``overlap_tokens_per_sec`` (bench's ``overlap_on`` pair row — the
-  double-buffered ring executor, docs/performance.md "Comm/compute
-  overlap") drops by more than the threshold: a change that silently
-  re-serializes the early-issued hops fails here. CPU-proxy runs stay
-  warn-only like every wall-clock gate below,
 - ``abs_rel_err`` (|predicted - measured| / measured step time, from the
   ``cost_model`` section or the ``rel_err`` gauge) or
   ``calib_abs_err_corrected`` (the ``calibration`` section's corrected
@@ -68,7 +62,7 @@ accelerator stack is the thing that broke.
 
 Usage::
 
-    python scripts/regress.py --report /tmp/telemetry_smoke/report.json \
+    python scripts/regress.py --report /tmp/probe_smoke/report.json \
         [--history results/history.jsonl] [--threshold 0.1] \
         [--window 20] [--warn-only]
 """
@@ -122,14 +116,12 @@ def extract_metrics(manifest) -> dict:
             "predicted_step_s": pred.get("step_s"),
             "measured_step_s": None,
             "peak_temp_bytes": None,
-            "peak_live_bytes": None,
             "grad_norm_final": None,
             "gns": None,
             "n_skipped_attributed": None,
             "max_sustainable_load": None,
             "serve_ttft_p99_ref": None,
             "prefix_hit_rate": None,
-            "overlap_tokens_per_sec": None,
             "acceptance_rate": None,
             "spec_tokens_per_sec": None,
             "spec_tick_gain": None,
@@ -153,13 +145,9 @@ def extract_metrics(manifest) -> dict:
         mfu = float(gauges["headline_mfu"])
     if mfu is None and isinstance(gauges.get("mfu"), (int, float)):
         mfu = float(gauges["mfu"])
-    bubble = _get(manifest, "telemetry", "stage_breakdown",
-                  "bubble_measured_mean")
-    if bubble is None:
-        bubble = _get(cm, "predicted", "bubble_table_exact")
+    bubble = _get(cm, "predicted", "bubble_table_exact")
     mem = manifest.get("memory")
     peak_temp = _get(mem, "compiled", "temp_bytes")
-    peak_live = _get(mem, "live", "peak_bytes_in_use")
     # model-health metrics: the fit manifest's dynamics section, else the
     # sweep-row gauges (both carry the same column names)
     dyn = manifest.get("dynamics")
@@ -221,14 +209,9 @@ def extract_metrics(manifest) -> dict:
         acceptance = _num(gauges.get("acceptance_rate"))
     spec_tps = _num(gauges.get("spec_on_tokens_per_sec"))
     spec_tick_gain = _num(gauges.get("spec_tick_gain"))
-    # comm/compute overlap pair (bench.py): the overlap-on throughput is
-    # guarded like the headline; on a cpu-proxy backend all throughput
-    # gates are already warn-only, so the jittery serialized-tick number
-    # never hard-fails the sentinel
-    overlap_tps = _num(gauges.get("overlap_on_tokens_per_sec"))
     # calibration observatory (docs/observability.md §9): the model-trust
     # axes — per-run signed error from the cost_model section (or the
-    # first-class sweep/bench gauge), plus the probe grid's raw and
+    # first-class sweep gauge), plus the probe grid's raw and
     # corrected medians from the calibration section
     rel_err = _num(_get(cm, "measured", "rel_err"))
     if rel_err is None:
@@ -251,7 +234,6 @@ def extract_metrics(manifest) -> dict:
         "predicted_step_s": predicted_step_s,
         "measured_step_s": _get(cm, "measured", "step_s"),
         "peak_temp_bytes": peak_temp,
-        "peak_live_bytes": peak_live,
         "grad_norm_final": grad_norm_final,
         "gns": gns,
         "n_skipped_attributed": (int(n_skipped)
@@ -260,7 +242,6 @@ def extract_metrics(manifest) -> dict:
         "max_sustainable_load": max_sustainable,
         "serve_ttft_p99_ref": ttft_ref,
         "prefix_hit_rate": prefix_hit,
-        "overlap_tokens_per_sec": overlap_tps,
         "acceptance_rate": acceptance,
         "spec_tokens_per_sec": spec_tps,
         "spec_tick_gain": spec_tick_gain,
@@ -315,11 +296,9 @@ def check(row, history, threshold, window) -> list:
     problems = []
     for key, direction in (("tokens_per_sec", "down"), ("mfu", "down"),
                            ("bubble", "up"), ("peak_temp_bytes", "up"),
-                           ("peak_live_bytes", "up"),
                            ("max_sustainable_load", "down"),
                            ("serve_ttft_p99_ref", "up"),
                            ("prefix_hit_rate", "down"),
-                           ("overlap_tokens_per_sec", "down"),
                            # speculative guards: a draft/verify change
                            # that quietly rejects more proposals or
                            # shrinks the tick-domain capacity win fails

@@ -203,7 +203,7 @@ def main(argv=None) -> int:
     # at; engine.run resets the series each replay)
     last = section["curve"][-1]["summary"]
     trace_path = write_perfetto_trace(
-        None, os.path.join(out_dir, "requests_trace.json"),
+        os.path.join(out_dir, "requests_trace.json"),
         serving_events=report.events,
         serving_load_tracks={"occupancy": last.get("occupancy"),
                              "queue_depth": last.get("queue_depth"),

@@ -1,7 +1,7 @@
 """Serving smoke: continuous batching on a CPU mesh, oracle-checked.
 
 The tier-1 liveness check for the serving layer (scripts/tier1.sh runs
-it after the telemetry smoke; CI uploads the resulting report as an
+it ahead of the suite; CI uploads the resulting report as an
 artifact): drive a small request mix through the slot-level
 :class:`ServingEngine` on an 8-device simulated CPU mesh and require
 
@@ -235,7 +235,7 @@ def main() -> int:
     from distributed_training_with_pipeline_parallelism_tpu.utils.telemetry import (
         write_perfetto_trace)
     trace_path = write_perfetto_trace(
-        None, os.path.join(out_dir, "requests_trace.json"),
+        os.path.join(out_dir, "requests_trace.json"),
         serving_events=report.events)
     import json
 

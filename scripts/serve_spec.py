@@ -146,7 +146,7 @@ def main(argv=None) -> int:
                   "s_per_tick": last.get("s_per_tick"),
                   "acceptance": last.get("acceptance_series")}
     trace_path = write_perfetto_trace(
-        None, os.path.join(out_dir, "requests_trace.json"),
+        os.path.join(out_dir, "requests_trace.json"),
         serving_events=report.events, serving_load_tracks=tracks)
 
     print(f"serve_spec: OK — gamma={args.gamma}, "

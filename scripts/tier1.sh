@@ -27,39 +27,19 @@ if ! timeout -k 10 300 \
   exit 1
 fi
 echo "CHECK=ok"
-# Telemetry liveness next (own small budget, not charged to the suite's):
-# one instrumented pipeline step must produce a validated run report —
-# the observability layer's equivalent of "does it import" — including
-# a memory section whose analytic bytes match the verifier's slot
-# peaks to the integer and reconcile with XLA's AOT accounting. The
-# report lands in /tmp/telemetry_smoke for CI artifact upload.
-if ! timeout -k 10 300 env JAX_PLATFORMS=cpu \
-    python scripts/telemetry_smoke.py /tmp/telemetry_smoke; then
-  echo "TELEMETRY_SMOKE=fail"
-  exit 1
-fi
-echo "TELEMETRY_SMOKE=ok"
-# Perf-regression sentinel on the smoke's report (warn-only: CI hosts
-# are shared, so wall-clock gating would flake — the appended
-# results/history.jsonl rides the CI artifacts for offline triage;
-# docs/performance.md "Regression sentinel").
-if ! timeout -k 10 60 \
-    python scripts/regress.py --report /tmp/telemetry_smoke/report.json \
-    --history results/history.jsonl --warn-only; then
-  echo "REGRESS=fail"
-  exit 1
-fi
-echo "REGRESS=ok"
 # Calibration observatory next (own budget): the measured micro-probe
 # harness runs the smoke grid (GPipe/1F1B/Interleaved/ZBH1 x
 # stored/remat/split x overlap on/off on a simulated 2-device mesh),
 # fits per-hardware correction factors, and --check gates the contract:
 # corrected median |rel err| strictly below raw, byte-deterministic
-# correction-artifact roundtrip, ledger rows read back verbatim, and a
-# Perfetto trace carrying predicted-vs-measured per-tick annotations.
+# correction-artifact roundtrip, ledger rows read back verbatim.
 # On cpu backends a gate miss downgrades to a warning inside probe.py
 # (shared-host wall clocks flake); ledger + corrections land in
 # /tmp/probe_smoke for CI artifact upload (docs/observability.md §9).
+# The perf-regression sentinel then reads the report (warn-only: CI
+# hosts are shared, so wall-clock gating would flake — the appended
+# results/history.jsonl rides the CI artifacts for offline triage;
+# docs/performance.md "Regression sentinel").
 if ! timeout -k 10 480 env JAX_PLATFORMS=cpu \
     python scripts/probe.py /tmp/probe_smoke --grid smoke --check \
     --ledger /tmp/probe_smoke/calibration.jsonl \

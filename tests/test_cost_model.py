@@ -1,4 +1,4 @@
-"""Cost-model observatory: roofline accounting, trace export, sentinel.
+"""Cost-model observatory: roofline accounting, sentinel.
 
 The contract under test (docs/observability.md "Cost model & MFU"):
 
@@ -9,30 +9,18 @@ The contract under test (docs/observability.md "Cost model & MFU"):
 - the *weighted* bubble equals ``schedules.simulated_bubble`` under the
   resolved backward policy's weights, and the *closed-form* bubble
   equals ``schedules.analytic_bubble_fraction``;
-- MFU divides by the same chip peaks ``bench.chip_peak_flops`` uses
-  (the tool and the benchmark can never disagree about utilization);
-- the Perfetto exporter emits valid Chrome-trace JSON: sorted
-  timestamps, complete X slices for every table cell, one s->f flow
-  pair per ring-hop store with unique matched ids;
-- the critical-path walker's compute/comm/bubble seconds tile the
-  measured window;
+- a device that is not in the table of peaks is an error, not a default;
 - the ``cost_model`` manifest section round-trips ``validate_report``;
-- ``scripts/regress.py`` fails on a regression, warn-only on CPU proxy;
-- ``scripts/profile_breakdown.py --from-report`` degrades gracefully on
-  reports missing sections;
-- ``bench.py`` measures a TPU or nothing: no TPU is a non-zero exit
-  with no result, a backend that fails to come up fails the run, and an
-  unknown device kind has no peak.
+- the requests/dynamics Perfetto writer emits loadable Chrome-trace JSON;
+- ``scripts/regress.py`` fails on a regression, warn-only on CPU proxy.
 """
 
 import importlib.util
 import json
 import os
 
-import numpy as np
 import pytest
 
-import jax
 
 import distributed_training_with_pipeline_parallelism_tpu as dtpp
 from distributed_training_with_pipeline_parallelism_tpu.analysis.cost_model import (
@@ -43,11 +31,9 @@ from distributed_training_with_pipeline_parallelism_tpu.analysis.cost_model impo
 from distributed_training_with_pipeline_parallelism_tpu.analysis.table_check import (
     check_table)
 from distributed_training_with_pipeline_parallelism_tpu.parallel.schedules import (
-    analytic_bubble_fraction, compile_schedule, compress_schedule,
-    simulated_bubble, table_unit_activity)
+    analytic_bubble_fraction, compile_schedule, simulated_bubble)
 from distributed_training_with_pipeline_parallelism_tpu.utils.telemetry import (
-    PHASE_END, PHASE_START, PipelineTelemetry, RunReport, critical_path,
-    perfetto_trace, validate_report, write_perfetto_trace)
+    RunReport, validate_report, write_perfetto_trace)
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -113,32 +99,19 @@ def test_policy_resolution_matches_executor_rules():
     assert resolve_backward_policy(gp, n_devices=1) == "stored"
 
 
-def test_hardware_presets_match_bench_peaks(monkeypatch):
-    import bench
-    for key, peak in bench._PEAK_FLOPS.items():
-        assert hardware_spec_for(key).peak_flops == peak
+def test_hardware_presets():
+    for key, spec in TPU_PRESETS.items():
+        assert hardware_spec_for(key) is spec
     assert hardware_spec_for("cpu") is CPU_PROXY
     assert hardware_spec_for("TPU v5 lite").peak_flops == 197e12
-    # a device that is not in the table is an error, not a default —
-    # here and in bench alike
+    # a device that is not in the table is an error, not a default
     for unknown in ("tpu v99", ""):
         with pytest.raises(ValueError, match="no hardware preset"):
             hardware_spec_for(unknown)
 
-    class FakeDevice:
-        platform = "tpu"
-        device_kind = "TPU v99"
 
-    monkeypatch.setattr(jax, "devices", lambda *a, **kw: [FakeDevice()])
-    with pytest.raises(ValueError, match="no bf16 peak on record"):
-        bench.chip_peak_flops()
-
-
-def test_bench_flops_delegates_to_cost_model():
-    import bench
+def test_train_flops_is_three_forwards():
     cfg = dtpp.ModelConfig(**CFG)
-    assert bench.train_flops_per_token(cfg, 16) == \
-        train_flops_per_token(cfg, 16)
     assert train_flops_per_token(cfg, 16) == 3.0 * fwd_flops_per_token(
         cfg, 16)
 
@@ -198,111 +171,33 @@ def test_serving_section_schema():
 
 
 # ---------------------------------------------------------------------------
-# Perfetto export + critical path (satellite c): synthetic stamps over
-# real compiled tables — deterministic, no jax execution
+# Perfetto export: the requests and dynamics tracks
 # ---------------------------------------------------------------------------
 
 
-def _synthetic_telemetry(cs):
-    """A phase-executor telemetry with fabricated monotonic stamps: one
-    PHASE_START/PHASE_END pair per compressed phase, 1 ms per tick."""
-    tel = PipelineTelemetry()
-    phases = compress_schedule(cs.table)
-    tel.attach(cs.table, phases, "phases")
-    t = 0.0
-    for j, ph in enumerate(phases):
-        tel.events.append((PHASE_START, j, t))
-        t += 1e-3 * ph.length
-        tel.events.append((PHASE_END, j, t))
-    return tel
-
-
-def _expected_trace_shape(table):
-    """(n_X_slices, n_flow_pairs) the exporter must emit for a table."""
-    activity = table_unit_activity(table)
-    n_x = int(activity.sum())  # unit cells + one idle slice per empty cell
-    from distributed_training_with_pipeline_parallelism_tpu.parallel.schedules import (
-        COL_STORE_B_POS_SLOT, COL_STORE_B_SLOT, COL_STORE_F_NEG_SLOT,
-        COL_STORE_F_SLOT)
-    cols = [COL_STORE_F_SLOT, COL_STORE_B_SLOT, COL_STORE_F_NEG_SLOT,
-            COL_STORE_B_POS_SLOT]
-    n_flows = int((table[1:][:, :, cols] >= 0).sum())
-    return n_x, n_flows
-
-
-@pytest.mark.parametrize("name,D,V,M",
-                         [("GPipe", 4, 1, 4), ("Interleaved1F1B", 4, 2, 8)])
-def test_perfetto_trace_schema(name, D, V, M):
-    cs = compile_schedule(name, D, V, M)
-    tel = _synthetic_telemetry(cs)
-    trace = json.loads(json.dumps(perfetto_trace(tel)))  # JSON round-trip
-
-    events = trace["traceEvents"]
-    assert trace["displayTimeUnit"] == "ms"
-    assert [e["ts"] for e in events] == sorted(e["ts"] for e in events)
-
-    by_ph = {}
-    for e in events:
-        by_ph.setdefault(e["ph"], []).append(e)
-    # track metadata: one process name + one thread name per device
-    names = {e["args"]["name"] for e in by_ph["M"]}
-    assert {f"device {d}" for d in range(D)} <= names
-    # complete slices: every table cell accounted for, durations >= 0
-    n_x, n_flows = _expected_trace_shape(cs.table)
-    assert len(by_ph["X"]) == n_x
-    assert all(e["dur"] >= 0 and 0 <= e["tid"] < D for e in by_ph["X"])
-    cats = {e["cat"] for e in by_ph["X"]}
-    assert "F" in cats and "B" in cats
-    if V > 1:  # virtual stage visible in slice names
-        assert any(" v1 " in e["name"] for e in by_ph["X"])
-    # flow arrows: one s->f pair per ring-hop store, ids matched 1:1
-    s_ids = sorted(e["id"] for e in by_ph.get("s", []))
-    f_ids = sorted(e["id"] for e in by_ph.get("f", []))
-    assert len(s_ids) == n_flows and s_ids == f_ids
-    assert len(set(s_ids)) == n_flows
-    assert trace["otherData"]["n_flows"] == n_flows
-    assert all(e["cat"] == "ppermute" for e in by_ph.get("s", []))
-
-
 def test_write_perfetto_trace_roundtrip(tmp_path):
-    cs = compile_schedule("GPipe", 4, 1, 4)
-    tel = _synthetic_telemetry(cs)
-    path = write_perfetto_trace(tel, str(tmp_path / "trace.json"))
+    serving = [
+        {"kind": "serve_admit", "t": 10.0, "rid": 0, "slot": 1, "tick": 2,
+         "prompt_len": 4, "budget": 8},
+        {"kind": "serve_finish", "t": 10.5, "rid": 0, "tick": 9,
+         "n_tokens": 8, "ttft_ticks": 3}]
+    dynamics = [{"kind": "dynamics", "t": 11.0, "grad_norm": 1.5,
+                 "grad_norm_per_stage": [1.0, 0.5]}]
+    path = write_perfetto_trace(str(tmp_path / "trace.json"),
+                                serving_events=serving,
+                                dynamics_events=dynamics)
     trace = json.loads(open(path).read())
-    assert trace["traceEvents"]
-
-
-def test_critical_path_tiles_the_window():
-    cs = compile_schedule("1F1B", 4, 1, 8)
-    tel = _synthetic_telemetry(cs)
-    cp = critical_path(tel)
-    T = cs.table.shape[0]
-    assert cp["n_ticks"] == T and len(cp["per_tick"]) == T
-    assert {r["class"] for r in cp["per_tick"]} <= \
-        {"compute", "comm", "bubble"}
-    assert cp["compute_s"] + cp["comm_s"] + cp["bubble_s"] == \
-        pytest.approx(cp["total_s"])
-    assert cp["total_s"] == pytest.approx(1e-3 * T)
-    assert 0 <= cp["straggler_device"] < 4
-    # a pipeline schedule computes on some ticks — never all-bubble
-    assert cp["compute_s"] > 0
-
-
-def test_cost_model_attribution_from_telemetry():
-    cs = compile_schedule("GPipe", 4, 1, 4)
-    cfg = dtpp.ModelConfig(**CFG)
-    tel = _synthetic_telemetry(cs)
-    sec = cost_model_section(cs, cfg, batch_size=8, seq_length=16,
-                             hardware=CPU_PROXY, telemetry=tel)
-    attr = sec["attribution"]
-    assert attr["n_ticks"] == cs.table.shape[0]
-    # measured_step_s defaulted from the telemetry timeline
-    assert sec["measured"]["step_s"] == pytest.approx(
-        1e-3 * cs.table.shape[0])
-    assert "bubble_measured_mean" in sec["measured"]
-    report = RunReport(name="attr")
-    report.attach_cost_model(sec)
-    validate_report(report.manifest())
+    by_ph = {}
+    for e in trace["traceEvents"]:
+        by_ph.setdefault(e["ph"], []).append(e)
+    # one async slice per request, closed by its finish row
+    (begin,), (end,) = by_ph["b"], by_ph["e"]
+    assert begin["id"] == end["id"] == 0 and begin["tid"] == 1
+    assert end["ts"] - begin["ts"] == pytest.approx(0.5e6)
+    assert begin["args"]["ttft_ticks"] == 3
+    # one counter per global norm and per stage
+    assert sorted(e["name"] for e in by_ph["C"]) == [
+        "grad_norm", "grad_norm stage 0", "grad_norm stage 1"]
 
 
 # ---------------------------------------------------------------------------
@@ -364,76 +259,3 @@ def test_regress_missing_report(tmp_path):
     assert rc == 2
     assert regress.main(["--report", str(tmp_path / "nope.json"),
                          "--history", hist, "--warn-only"]) == 0
-
-
-# ---------------------------------------------------------------------------
-# scripts/profile_breakdown.py --from-report degrades gracefully
-# (satellite b): missing sections are a message, not a traceback
-# ---------------------------------------------------------------------------
-
-
-def test_profile_breakdown_graceful_degradation(capsys):
-    pb = _load_script("profile_breakdown")
-    with pytest.raises(SystemExit, match="neither"):
-        pb.report_breakdown({"meta": {"name": "empty"}})
-    # partial telemetry (no timeline, no stage_breakdown): prints a note
-    pb.report_breakdown({"meta": {"name": "p"},
-                         "telemetry": {"executor": "phases"}})
-    assert "no measured timeline" in capsys.readouterr().out
-    # cost_model only (e.g. a sweep row without instrumented stamps)
-    cs = compile_schedule("GPipe", 4, 1, 4)
-    sec = cost_model_section(cs, dtpp.ModelConfig(**CFG), batch_size=8,
-                             seq_length=16, hardware=CPU_PROXY)
-    pb.report_breakdown({"meta": {"name": "cm"}, "cost_model": sec})
-    out = capsys.readouterr().out
-    assert "cost model: GPipe" in out and "bubble" in out
-
-
-def test_profile_breakdown_renders_full_report(tmp_path, capsys):
-    cs = compile_schedule("1F1B", 4, 1, 8)
-    cfg = dtpp.ModelConfig(**CFG)
-    tel = _synthetic_telemetry(cs)
-    report = RunReport(out_dir=str(tmp_path), name="full")
-    report.set_meta(backend="cpu")
-    report.attach_telemetry(tel)
-    report.attach_cost_model(cost_model_section(
-        cs, cfg, batch_size=8, seq_length=16, hardware=CPU_PROXY,
-        telemetry=tel))
-    report.write()
-    pb = _load_script("profile_breakdown")
-    pb.report_breakdown(json.loads((tmp_path / "report.json").read_text()))
-    out = capsys.readouterr().out
-    assert "critical path" in out and "MFU" in out
-
-
-# ---------------------------------------------------------------------------
-# bench measures a TPU or nothing: no CPU fallback, no swallowed init error
-# ---------------------------------------------------------------------------
-
-
-def test_bench_without_tpu_exits_nonzero_with_no_result(capsys):
-    """The suite runs on the CPU backend — exactly the case that used to
-    switch to a proxy headline and exit 0."""
-    import bench
-    assert jax.devices()[0].platform == "cpu"
-    for mode in (bench.run, bench.run_serve):
-        with pytest.raises(SystemExit) as exit_info:
-            mode()
-        assert exit_info.value.code not in (0, None)
-        assert "needs a TPU" in str(exit_info.value.code)
-    assert capsys.readouterr().out == ""  # no result line
-
-
-def test_bench_backend_init_errors_reraise(monkeypatch):
-    """A backend that fails to come up fails the run, whatever it says —
-    UNAVAILABLE used to be retried and then answered from the CPU."""
-    import bench
-
-    for msg in ("UNAVAILABLE: TPU backend setup/compile error (transient)",
-                "something unrelated exploded"):
-        def broken_devices(*a, msg=msg, **kw):
-            raise RuntimeError(msg)
-
-        monkeypatch.setattr(jax, "devices", broken_devices)
-        with pytest.raises(RuntimeError, match=msg.split(":")[0]):
-            bench.run()

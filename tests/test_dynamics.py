@@ -150,7 +150,9 @@ def test_per_stage_norms_match_oracle(problem, name, M):
     sched = dtpp.ScheduleConfig(name=name, n_microbatches=M)
     fn = make_pipeline_grad_fn(CFG, mesh, sched, remat_backward=True,
                                unroll_ticks=True, dynamics=True)
-    loss, grads, sq_mb = fn(params, tokens, targets)
+    # jitted: called bare, the unjitted unrolled tick program runs op by
+    # op through an eager shard_map (minutes, not seconds)
+    loss, grads, sq_mb = jax.jit(fn)(params, tokens, targets)
     assert float(jnp.abs(loss - ref_loss)) < 1e-5
     assert sq_mb.shape == (M,)
 

@@ -1,4 +1,4 @@
-"""Memory observatory: analytic/compiled/live HBM accounting.
+"""Memory observatory: analytic and compiled HBM accounting.
 
 The contract under test (docs/observability.md "Memory observatory"):
 
@@ -15,14 +15,12 @@ The contract under test (docs/observability.md "Memory observatory"):
   layout; documented tolerance 10% for padded real-chip layouts);
 - the ``memory`` RunReport section round-trips ``validate_report`` and
   malformed sections are rejected;
-- telemetry-off steps still trace with zero host callbacks (the
-  watermark sampler rides the existing stamp callback — no new ones);
+- the default step traces with zero host callbacks;
 - the sweep's OOM preflight prices a config *before* compiling and
   returns a ``skip_reason="predicted_oom"`` row instead of crashing;
 - ``schedule_search`` accepts bytes-denominated budgets and resolves
   them to the same winner as the equivalent slot budget;
-- the Perfetto exporters emit a per-device HBM counter track and a
-  per-request async-span track;
+- the Perfetto exporter emits a per-request async-span track;
 - ``scripts/regress.py`` guards peak HBM per (name, backend, schedule).
 """
 
@@ -47,8 +45,7 @@ from distributed_training_with_pipeline_parallelism_tpu.analysis.table_check imp
 from distributed_training_with_pipeline_parallelism_tpu.parallel.schedules import (
     ScheduleError, compile_schedule)
 from distributed_training_with_pipeline_parallelism_tpu.utils.telemetry import (
-    PipelineTelemetry, RunReport, perfetto_request_events, perfetto_trace,
-    validate_report)
+    RunReport, perfetto_request_events, validate_report)
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -250,11 +247,11 @@ def test_serving_memory_section_prices_kv_cache():
 
 
 # ---------------------------------------------------------------------------
-# Telemetry: zero new callbacks, watermark summary, counter track
+# No host callbacks; the requests track
 # ---------------------------------------------------------------------------
 
 
-def test_telemetry_off_step_has_zero_callbacks():
+def test_default_step_has_zero_callbacks():
     import jax.numpy as jnp
 
     from distributed_training_with_pipeline_parallelism_tpu.models import (
@@ -267,59 +264,13 @@ def test_telemetry_off_step_has_zero_callbacks():
     cfg = dtpp.ModelConfig(**CFG)
     mesh = make_mesh(n_pipe=4)
     sched = dtpp.ScheduleConfig(name="GPipe", n_microbatches=4)
-    step = make_pipeline_step(cfg, mesh, sched)  # telemetry=None
+    step = make_pipeline_step(cfg, mesh, sched)
     params = tfm.transformer_init(jax.random.key(0), cfg)
     tokens = jnp.zeros((8, 16), jnp.int32)
     targets = jnp.zeros((8, 16), jnp.int32)
     jaxpr = jax.make_jaxpr(step)(params, tokens, targets)
-    # the watermark sampler rides the stamp callback: telemetry off must
-    # still mean a callback-free jaxpr (the jaxpr-audit contract)
+    # the jaxpr-audit contract, read off the text
     assert "callback" not in str(jaxpr)
-
-
-def test_memory_summary_and_counter_track():
-    from distributed_training_with_pipeline_parallelism_tpu.parallel.schedules import (
-        compress_schedule)
-    from distributed_training_with_pipeline_parallelism_tpu.utils.telemetry import (
-        PHASE_END, PHASE_START)
-
-    # a phase-executor telemetry with fabricated monotonic stamps plus
-    # what a memory_stats()-capable backend would have sampled
-    cs = compile_schedule("GPipe", 4, 1, 4)
-    tel = PipelineTelemetry()
-    phases = compress_schedule(cs.table)
-    tel.attach(cs.table, phases, "phases")
-    t = 1.0
-    for j, ph in enumerate(phases):
-        tel.events.append((PHASE_START, j, t))
-        t += 1e-3 * ph.length
-        tel.events.append((PHASE_END, j, t))
-    tel.memory_samples = [
-        {"kind": "step_start", "device": 0, "t": 1.0,
-         "bytes_in_use": 100, "peak_bytes_in_use": 100},
-        {"kind": "step_end", "device": 0, "t": t,
-         "bytes_in_use": 150, "peak_bytes_in_use": 300},
-        {"kind": "step_end", "device": 1, "t": t,
-         "bytes_in_use": 80, "peak_bytes_in_use": 90},
-    ]
-    summ = tel.memory_summary()
-    assert summ["available"]
-    assert summ["peak_bytes_in_use"] == 300
-    by_dev = {r["device"]: r for r in summ["per_device"]}
-    assert by_dev[0]["peak_bytes_in_use"] == 300
-    assert by_dev[0]["last_bytes_in_use"] == 150
-    assert by_dev[1]["n_samples"] == 1
-
-    trace = perfetto_trace(tel)
-    counters = [e for e in trace["traceEvents"] if e.get("ph") == "C"]
-    assert len(counters) == 3
-    assert {e["name"] for e in counters} == {"HBM device 0", "HBM device 1"}
-    assert all(e["ts"] >= 0 for e in counters)
-    assert trace["otherData"]["n_memory_counters"] == 3
-
-    tel.reset()
-    assert tel.memory_samples == []
-    assert not tel.memory_summary()["available"]
 
 
 def test_perfetto_requests_track():
@@ -425,20 +376,14 @@ def test_regress_guards_peak_hbm():
         "meta": {"name": "fit", "backend": "tpu",
                  "schedule": {"name": "1F1B"}},
         "memory": {"schedule": "1F1B",
-                   "compiled": {"temp_bytes": 1000.0},
-                   "live": {"available": True, "per_device": [],
-                            "peak_bytes_in_use": 2000}},
+                   "compiled": {"temp_bytes": 1000.0}},
     }
     row = regress.extract_metrics(manifest)
     assert row["peak_temp_bytes"] == 1000.0
-    assert row["peak_live_bytes"] == 2000
     history = [dict(row) for _ in range(3)]
     grown = dict(row, peak_temp_bytes=1200.0)
     problems = regress.check(grown, history, 0.1, 20)
     assert any("peak_temp_bytes" in p for p in problems)
-    live_grown = dict(row, peak_live_bytes=3000)
-    problems = regress.check(live_grown, history, 0.1, 20)
-    assert any("peak_live_bytes" in p for p in problems)
     # shrinking memory is an improvement, not a regression
     assert not regress.check(dict(row, peak_temp_bytes=900.0),
                              history, 0.1, 20)

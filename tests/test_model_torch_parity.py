@@ -157,7 +157,7 @@ def test_gpt2_causality():
 
 # ---------------------------------------------------------------------------
 # unroll_layers: the straight-line layer loop must match the lax.scan path
-# (bench.py's GPT-2 rungs run through it — docs/performance.md "MFU sprint")
+# (the GPT-2 configurations run through it — docs/performance.md "MFU sprint")
 # ---------------------------------------------------------------------------
 
 

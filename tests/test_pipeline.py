@@ -216,7 +216,7 @@ def test_unrolled_ticks_match_scan(problem, name, V, M):
 
 def test_auto_unroll_past_32_rows_matches_scan(problem):
     """Round 5 (VERDICT r4 item 1): _UNROLL_TICKS_LIMIT was raised 32->64
-    from chip measurements (results/unroll_crossover.json), so
+    from chip measurements (docs/performance.md "Unroll-vs-scan crossover"), so
     ladder-scale tables (>32 rows) now AUTO-unroll. The auto path must
     equal the explicit scan form and the single-device oracle at a table
     size the old limit would have scanned. GPipe D=2 M=16 is 33 rows; the
@@ -381,3 +381,62 @@ def test_phase_executor_trace_count(problem):
     # the compile-cost invariant: more microbatches = more ticks but the
     # SAME set of tick bodies (steady state grows in reps, not patterns)
     assert counts[8] == counts[16], counts
+
+
+# ---------------------------------------------------------------------------
+# Every executor form: no host callback, ever
+# ---------------------------------------------------------------------------
+
+# form -> (pipe degree, make_pipeline_grad_fn kwargs, the schedules it
+# accepts). The device's time is read from the profiler's trace by the
+# names of the program's regions (tests/test_telemetry.py holds each form
+# to those names), never stamped from inside the program.
+EXECUTOR_FORMS = {
+    "fused": (1, {}, ("GPipe", "1F1B")),
+    "unrolled": (2, {"unroll_ticks": True},
+                 ("GPipe", "1F1B", "Interleaved1F1B", "BFS", "ZBH1", "ZBV")),
+    "phases": (2, {"unroll_ticks": "phases"},
+               ("GPipe", "1F1B", "Interleaved1F1B", "BFS", "ZBH1", "ZBV")),
+    "scan": (2, {"unroll_ticks": False},
+             ("GPipe", "1F1B", "Interleaved1F1B", "BFS", "ZBH1", "ZBV")),
+    # remat_backward=False: all-F-then-all-B schedules differentiate
+    # through the forward tick scan; a one-stage pipe forced onto the
+    # tick path takes the same program by default
+    "phase_stored": (2, {"remat_backward": False}, ("GPipe", "BFS")),
+    "phase_stored_d1": (1, {"force_tick_executor": True}, ("1F1B",)),
+    # ... every other non-split schedule banks vjp residuals in slots
+    "slot_stored": (2, {"remat_backward": False},
+                    ("1F1B", "Interleaved1F1B")),
+}
+_FORM_CFG = dtpp.ModelConfig(dim=16, n_layers=4, n_heads=2, vocab_size=32,
+                             ffn_dim=32, max_seq_len=8)
+_TWO_CHUNK = ("Interleaved1F1B", "BFS", "ZBV")
+
+
+def build_executor_form(form, name):
+    """``(grad_fn, mesh, args)`` of one executor form at a tiny size, for
+    tests that trace or lower it and run nothing."""
+    from distributed_training_with_pipeline_parallelism_tpu.parallel.pipeline import (
+        make_pipeline_grad_fn)
+    D, kw, _ = EXECUTOR_FORMS[form]
+    mesh = make_mesh(n_pipe=D)
+    sched = dtpp.ScheduleConfig(
+        name=name, n_microbatches=4,
+        n_virtual=2 if D > 1 and name in _TWO_CHUNK else 1)
+    fn = make_pipeline_grad_fn(_FORM_CFG, mesh, sched, **kw)
+    params = tfm.transformer_init(jax.random.key(0), _FORM_CFG)
+    tokens = jnp.zeros((4, 8), jnp.int32)
+    return fn, mesh, (params, tokens, tokens)
+
+
+@pytest.mark.parametrize("form,name", [
+    (form, name) for form, (_, _, names) in EXECUTOR_FORMS.items()
+    for name in names])
+def test_no_host_callback_in_any_executor(form, name):
+    from distributed_training_with_pipeline_parallelism_tpu.analysis.jaxpr_audit import (
+        audit_fn)
+    fn, mesh, args = build_executor_form(form, name)
+    audit = audit_fn(fn, *args, mesh_axes=tuple(mesh.axis_names),
+                     expect_no_callbacks=True)
+    assert audit.n_callbacks == 0
+    assert audit.ok, audit.problems

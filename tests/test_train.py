@@ -31,6 +31,20 @@ def test_model_registry():
     assert gpt2_config("small", n_layers=8).n_layers == 8
 
 
+@pytest.mark.parametrize("where,name", [
+    ("utils.train", "fit"), ("utils.train", "make_train_step"),
+    ("parallel.pipeline", "make_pipeline_step"),
+    ("parallel.pipeline", "make_pipeline_grad_fn")])
+def test_training_path_takes_no_telemetry(where, name):
+    """One clock: the device's time is read from the profiler's trace
+    (docs/observability.md §1). The option that planted host stamps inside
+    the executors went in PR 32 and does not grow back."""
+    import importlib
+    import inspect
+    fn = getattr(importlib.import_module(f"{dtpp.__name__}.{where}"), name)
+    assert "telemetry" not in inspect.signature(fn).parameters
+
+
 def test_training_reduces_loss():
     # A pipelined model must actually learn on a fixed batch.
     cfg = dtpp.ModelConfig(dim=32, n_layers=4, n_heads=4, vocab_size=64,
