@@ -44,8 +44,12 @@ BATCH, SEQ = 8, 1024  # every training phase: 8 sequences of 1024 tokens
 # gpt2-medium at that batch (packed kernels, static causal strips); a
 # gpt2-xl pipeline microbatch (25 heads: odd, so the classic form, strips
 # too); then the ragged length whose backward the v5e compiler used to
-# refuse (one block spans the row, no whole strips: the one-tile form)
-FLASH_SHAPES = ((BATCH, SEQ, 16, 64), (2, SEQ, 25, 64), (2, 1000, 12, 64))
+# refuse (one block spans the row, no whole strips: the one-tile form);
+# then gpt2-medium's 8192 tokens as short rows, where 'auto' takes the
+# kernels since PR 33 (two strips of 128 at 256; two of 256 forward and four
+# of 128 backward at 512)
+FLASH_SHAPES = ((BATCH, SEQ, 16, 64), (2, SEQ, 25, 64), (2, 1000, 12, 64),
+                (32, 256, 16, 64), (16, 512, 16, 64))
 XENT_SHAPE = (BATCH * SEQ, 50257)
 
 # bf16 keeps 8 bits of mantissa (eps = 2**-8 = 3.9e-3). A kernel and its

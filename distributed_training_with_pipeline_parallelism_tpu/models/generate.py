@@ -106,7 +106,7 @@ def _layer_step(cfg: ModelConfig, lp: Pytree, h: jax.Array, k_cache: jax.Array,
     self-attention over the new block. Under that promise the call is
     eligible for the Pallas flash kernel with the training path's exact
     fallback discipline (``cfg.flash_for``: 'auto' = causal TPU
-    sequences >= 1024, dense elsewhere); sites with traced offsets —
+    sequences >= 256, dense elsewhere); sites with traced offsets —
     decode steps, the serving engine's chunked prefill — must keep the
     default and stay on the cached dense path."""
     b, s, _ = h.shape

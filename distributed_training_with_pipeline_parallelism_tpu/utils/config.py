@@ -70,11 +70,11 @@ class ModelConfig:
     # (the reference's regime).
     pad_token_id: Optional[int] = None
     # Attention kernel routing: True forces the Pallas flash kernel, False
-    # forces dense XLA softmax-matmuls, "auto" (default) picks flash exactly
-    # where it measures faster end-to-end on TPU — causal attention at
-    # seq >= 1024 with no attention-prob dropout (docs/performance.md: the
-    # flash backward is 1.15-24x the XLA dense backward there) — and dense
-    # everywhere else (short sequences, non-causal ref_decoder, CPU CI).
+    # forces dense XLA softmax-matmuls, "auto" (default) picks flash where
+    # the whole train step measured faster with it on the v5e — causal
+    # attention at seq >= 256 with no attention-prob dropout (the rule and
+    # the measurements that set it: :meth:`flash_for`) — and dense
+    # everywhere else (shorter sequences, non-causal ref_decoder, CPU CI).
     use_flash_attention: Union[bool, str] = "auto"
     use_fused_xent: bool = False  # route the loss through the Pallas fused-CE kernel
     remat_layers: bool = False  # jax.checkpoint each layer: trade FLOPs for HBM
@@ -244,14 +244,35 @@ class ModelConfig:
 
     def flash_for(self, causal: bool, seq_len: int) -> bool:
         """Resolve ``use_flash_attention`` for one attention call site.
-        'auto' = flash exactly where it measured faster end-to-end on real
-        TPU (docs/performance.md): causal, seq >= 1024, no attention-prob
-        dropout. Non-TPU backends resolve to dense — the kernel only runs
-        in (slow) interpreter mode there."""
+        'auto' = the Pallas kernels for causal attention without
+        attention-prob dropout at ``seq_len >= 256`` on a TPU, dense
+        elsewhere (other backends would run the kernel in slow interpret
+        mode). A function of what the call site passes, nothing to tune.
+
+        The bound is where the WHOLE train step measured faster with the
+        kernels on one v5e (PR 33; gpt2-medium, bf16 over fp32 masters,
+        AdamW, tokens/s dense -> flash, one seed a pair, ``chiprun_out/pr33``;
+        docs/performance.md has the table): 32 x 256 41 525 -> 51 457
+        (+23.9%, seeds 2147485301 and 2500000133: dense stores [b, h, s, s]
+        scores in every layer, and XLA, short of HBM, then runs the head's
+        matmul three times); 8 x 256 +6.4% by the step's median; a ragged
+        8 x 300 33 877 -> 37 176 (+9.7%); 16 x 512 and 8 x 1000 run only
+        with the kernels (the compiler refuses dense: 18.7 and 19.6 of
+        15.75 GB); 1024 and up as ever. Under 256 dense stays: 64 x 128 and
+        8 x 128 read +0.9% and +0.8% with the kernels (level), 8 x 192
+        +4.3% and 40 x 192 +14.7% — a training step would gain from 192 —
+        but a forward-only caller (the pipelined decode's whole-prompt
+        prefill, ``models/generate.py:_layer_step``) reads the same rule and
+        cannot say that no backward follows: whole-model prefill of 8
+        prompts of 256 is 9.4% SLOWER with the kernel (300: 2.6%, 512: 1.0%;
+        one prompt is 2-7% faster at every length, 8 x 700 17%, 8 x 900
+        24%), and nothing forward-only is measured under 256. Move the
+        bound only on a chip measurement of the whole step, and write it
+        here."""
         if self.use_flash_attention is True:
             return True
         if self.use_flash_attention == "auto":
-            if self.dropout > 0.0 or not causal or seq_len < 1024:
+            if self.dropout > 0.0 or not causal or seq_len < 256:
                 return False
             import jax
             return jax.devices()[0].platform == "tpu"

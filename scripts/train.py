@@ -89,8 +89,8 @@ def main(argv=None):
     ap.add_argument("--flash", default=None, nargs="?", const="on",
                     choices=["on", "off", "auto"],
                     help="Pallas fused flash attention (bare --flash means "
-                         "on; default auto: on for causal seq>=1024 on TPU, "
-                         "where it measures faster; see "
+                         "on; default auto: on for causal seq>=256 on TPU, "
+                         "where the whole step measures faster; see "
                          "docs/performance.md)")
     ap.add_argument("--fused-xent", action="store_true",
                     help="Pallas fused cross-entropy loss")

@@ -11,6 +11,9 @@ a module fixture — only the xdist worker that is handed this file loads
 libtpu, and it skips, not errors, where none can be described.
 """
 
+import base64
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -87,8 +90,12 @@ def _bytes(compiled):
     # nemotron_h's attention layer: a whole row's q, do and f32 dq pass the
     # default 16 MiB of VMEM in the backward, which asks for more (PR 30)
     ((2, 8192, 32, 128), None),
+    # gpt2-medium's 8192 tokens as short rows: 'auto' takes the kernels from
+    # seq 256 (PR 33), strips of 128 / of 256 forward and 128 backward
+    ((32, 256, 16, 64), None),
+    ((16, 512, 16, 64), None),
 ], ids=["medium", "xl-25-heads", "small", "ragged-1000", "window-1024",
-        "nemotron-8192x128"])
+        "nemotron-8192x128", "medium-b32s256", "medium-b16s512"])
 def test_flash_fwd_bwd_compiles(one_chip, mosaic, shape, window):
     x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
 
@@ -162,21 +169,39 @@ def lower_train_step(cfg, mesh, sched, batch, seq):
     return step.lower(params, opt_state, tokens, tokens), params
 
 
-def test_gpt2_medium_train_step_fits_one_chip(topo, mosaic):
-    """Published width and depth, bf16 compute / fp32 master / AdamW, batch
-    8 x seq 1024: the compiler counts 15.4 GB of 15.75 with the update in
-    place, and 17.1 GB without the donation."""
+@pytest.mark.parametrize("batch,seq", [(8, 1024), (32, 256)],
+                         ids=["b8s1024", "b32s256"])
+def test_gpt2_medium_train_step_fits_one_chip(topo, mosaic, batch, seq):
+    """Published width and depth, bf16 compute / fp32 master / AdamW, 8192
+    tokens a step as 8 x 1024 and as 32 x 256: the compiler counts 15.4 GB
+    of 15.75 with the update in place (17.1 GB without the donation), both
+    flash kernels are in the program, and its own rematerialisation pass
+    has cloned nothing. At 32 x 256 that is what ``flash_for``'s 'auto'
+    taking the kernels from seq 256 buys (here it sees the CPU, so flash is
+    forced): dense attention stores [32, 16, 256, 256] scores in every
+    layer, the count is 18.5 GB, and XLA runs the head's 1024 x 50257
+    matmul three times (``fusion.N.remat``, ``.remat2``: 12% of the step on
+    the chip, PERF.md section 6, PR 33)."""
     cfg = gpt2_config("medium", dtype="bfloat16", param_dtype="float32",
                       use_flash_attention=True, use_fused_xent=True)
     mesh = make_mesh(n_pipe=1, devices=topo.devices[:1])
     lowered, _ = lower_train_step(
-        cfg, mesh, ScheduleConfig(name="1F1B", n_microbatches=4), 8, 1024)
+        cfg, mesh, ScheduleConfig(name="1F1B", n_microbatches=4), batch, seq)
     compiled = lowered.compile()
     b = _bytes(compiled)
     assert b["alias"] > 0.9 * b["argument"], b  # params + moments in place
     assert (b["argument"] + b["output"] + b["temp"] - b["alias"]
             < HBM_BYTES), b
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    # a Mosaic call carries its kernel as MLIR bytecode, the name in clear
+    kernels = set()
+    for body in re.findall(r'"body":"([A-Za-z0-9+/=]+)"', text):
+        kernels.update(re.findall(rb"_flash_(?:fwd|bwd)_kernel",
+                                  base64.b64decode(body)))
+    assert kernels == {b"_flash_fwd_kernel", b"_flash_bwd_kernel"}, kernels
+    names = re.findall(r"^\s*(?:ROOT )?%?([\w.\-]+) = ", text, re.M)
+    assert len(names) > 1000, len(names)
+    assert [n for n in names if ".remat" in n] == []
 
 
 def test_gpt2_xl_width_pipe4_rests_sharded(topo, mosaic):
