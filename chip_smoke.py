@@ -47,9 +47,11 @@ BATCH, SEQ = 8, 1024  # every training phase: 8 sequences of 1024 tokens
 # refuse (one block spans the row, no whole strips: the one-tile form);
 # then gpt2-medium's 8192 tokens as short rows, where 'auto' takes the
 # kernels since PR 33 (two strips of 128 at 256; two of 256 forward and four
-# of 128 backward at 512)
+# of 128 backward at 512); last, latent attention's two widths (a fifth
+# number: the values' head width; PR 34) in blocks of 256 and in one block
 FLASH_SHAPES = ((BATCH, SEQ, 16, 64), (2, SEQ, 25, 64), (2, 1000, 12, 64),
-                (32, 256, 16, 64), (16, 512, 16, 64))
+                (32, 256, 16, 64), (16, 512, 16, 64),
+                (1, 2048, 8, 192, 128), (2, SEQ, 8, 192, 128))
 XENT_SHAPE = (BATCH * SEQ, 50257)
 
 # bf16 keeps 8 bits of mantissa (eps = 2**-8 = 3.9e-3). A kernel and its
@@ -155,15 +157,17 @@ def check_kernels(seed: int) -> None:
         b, s, h, dh = q.shape
 
         def flat(x):
-            return x.transpose(0, 2, 1, 3).reshape(b * h, s, dh)
+            return x.transpose(0, 2, 1, 3).reshape(b * h, s, x.shape[-1])
 
         out = pallas_attention._dense_attention(flat(q), flat(k), flat(v),
                                                 True)
-        return out.reshape(b, h, s, dh).transpose(0, 2, 1, 3)
+        return out.reshape(b, h, s, v.shape[-1]).transpose(0, 2, 1, 3)
 
     for shape in FLASH_SHAPES:
-        q, k, v, do = (jax.random.normal(kk, shape, jnp.bfloat16)
-                       for kk in jax.random.split(jax.random.key(seed), 4))
+        qk, values = shape[:4], shape[:3] + shape[-1:]
+        q, k, v, do = (jax.random.normal(kk, sh, jnp.bfloat16)
+                       for kk, sh in zip(jax.random.split(
+                           jax.random.key(seed), 4), (qk, qk, values, values)))
         compiled, secs = compile_for_chip(with_vjp(flash), q, k, v, do)
         got = compiled(q, k, v, do)
         want = jax.jit(with_vjp(dense))(q, k, v, do)

@@ -10,7 +10,9 @@ and a ``moe=`` argument through the executors. The newer layer,
 uses NONE of this module's routing: sigmoid scores over the router's whole
 width, top-k, nothing dispatched and so nothing dropped (every held expert
 on every token, gated), one expert-parallel rank's share, on the normal path
-with no ``moe=``. This module and ``parallel/expert_parallel.py`` stay
+with no ``moe=``; its expert function comes in two forms, relu^2 (two
+matrices) and the gated silu one (three; PR 34), where this module's experts
+are a gelu MLP. This module and ``parallel/expert_parallel.py`` stay
 until that layer has its exchange across chips (ROADMAP R1, D7).
 
 

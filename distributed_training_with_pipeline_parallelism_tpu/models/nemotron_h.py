@@ -1,16 +1,30 @@
-"""The ``nemotron_h`` family (NVIDIA Nemotron-H / Nemotron-3 hybrids): a stack
-of pre-norm residual layers ``h <- h + mixer_l(RMSNorm_l(h))`` in which every
-layer is ONE mixer, chosen by a letter of ``cfg.hybrid_override_pattern``:
+"""The patterned stack (``arch="nemotron_h"``, after the family that brought
+it: NVIDIA Nemotron-H / Nemotron-3 hybrids): pre-norm residual layers
+``h <- h + mixer_l(RMSNorm_l(h))`` in which every layer is ONE mixer, chosen
+by a letter of ``cfg.hybrid_override_pattern``:
 
 - ``M`` — Mamba-2 (:mod:`..ops.mamba2`);
 - ``*`` — causal grouped-query attention, no bias, no positional encoding
   (the state-space layers carry position; ``rope_theta`` is carried by the
   source's config and unused), through :func:`..ops.attention.mha_apply` and
   so through the Pallas flash kernels where ``cfg.flash_for`` says so;
-- ``E`` — routed and shared relu^2 experts (:mod:`..ops.experts`), as the
+- ``L`` — causal multi-head latent attention (:func:`..ops.attention.
+  mla_apply`): low-rank query and key-value paths, RoPE on the
+  ``qk_rope_head_dim`` columns only, scores over ``qk_nope_head_dim +
+  qk_rope_head_dim`` and values over ``v_head_dim``, through the same
+  kernels at the two widths;
+- ``-`` — a dense MLP of width ``cfg.ffn_dim`` (:func:`..ops.experts.
+  mlp_apply`);
+- ``E`` — routed and shared experts (:mod:`..ops.experts`), as the
   expert-parallel rank that holds ``cfg.held_experts`` computes them.
 
-Parameters: ``layers`` is ``{"mamba": .., "attn": .., "moe": ..}``, each the
+``-`` and ``E`` take the form ``cfg.mlp_hidden_act`` names: ``relu2``
+(Nemotron-H) or the gated ``silu``. A DeepSeek-style block — two pre-norm
+residual sublayers, attention then FFN — is two letters: ``L-`` a leading
+dense layer, ``LE`` an expert layer.
+
+Parameters: ``layers`` is ``{"mamba": .., "attn": .., "mla": .., "mlp": ..,
+"moe": ..}`` (the kinds the pattern has), each the
 layers of one kind stacked on axis 0 in pattern order; the stack is walked in
 pattern order as straight-line code (layers of different kinds share no
 scan), each layer under ``jax.checkpoint`` where ``cfg.remat_layers``.
@@ -31,14 +45,14 @@ from typing import Dict, List, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..ops.attention import mha_apply, mha_init
-from ..ops.experts import experts_apply, experts_init
+from ..ops.attention import mha_apply, mha_init, mla_apply, mla_init
+from ..ops.experts import experts_apply, experts_init, mlp_apply, mlp_init
 from ..ops.layers import rms_norm_apply, rms_norm_init
 from ..ops.mamba2 import mamba2_apply, mamba2_init
 from ..utils.config import ModelConfig
 
 #: pattern letter -> the key of its stack under ``params["layers"]``
-KINDS = {"M": "mamba", "*": "attn", "E": "moe"}
+KINDS = {"M": "mamba", "*": "attn", "L": "mla", "-": "mlp", "E": "moe"}
 #: leaves that stay in the storage dtype under mixed precision: the
 #: recurrence's decay parameters and the whole router are float32 whatever
 #: ``cfg.dtype`` is
@@ -52,13 +66,36 @@ def nemotron_h_config(name: str = "stage", **overrides) -> ModelConfig:
     language model at published widths as one rank of 16-way expert
     parallelism holds them (8 of 128 experts, an eighth of the vocabulary:
     667 M parameters; ``benchmark/configs/nemotron-twotower-30b-a3b.json``
-    has the source and the arithmetic). ``debug``: every kind of layer at toy
-    widths, for the CPU."""
+    has the source and the arithmetic). ``debug``: those kinds of layer at toy
+    widths, for the CPU. ``joyai-stage``: the first eight layers of
+    JoyAI-LLM-Flash (latent attention; a dense gated MLP, then seven layers
+    of gated experts) at published widths as one rank of 32-way expert
+    parallelism holds them (8 of 256 experts, an eighth of the vocabulary:
+    622 M parameters; ``benchmark/configs/joyai-llm-flash.json``).
+    ``joyai-debug``: its kinds of layer at toy widths."""
     sizes = {
         "stage": dict(dim=2688, n_heads=32, n_kv_heads=2,
                       head_dim_override=128, vocab_size=16384,
                       max_seq_len=262144, hybrid_override_pattern="MEMEM*EME",
                       experts_held=tuple(range(8))),
+        "joyai-stage": dict(dim=2048, n_heads=32, vocab_size=16160,
+                            max_seq_len=131072, rms_eps=1e-6,
+                            rope_theta=32e6, ffn_dim=7168,
+                            hybrid_override_pattern="L-" + "LE" * 7,
+                            mlp_hidden_act="silu", n_routed_experts=256,
+                            experts_held=tuple(range(8)),
+                            num_experts_per_tok=8, moe_intermediate_size=768,
+                            moe_shared_expert_intermediate_size=768),
+        "joyai-debug": dict(dim=64, n_heads=4, vocab_size=256,
+                            max_seq_len=4096, rms_eps=1e-6, rope_theta=32e6,
+                            ffn_dim=96, hybrid_override_pattern="L-LELE",
+                            mlp_hidden_act="silu", n_routed_experts=16,
+                            experts_held=(0, 1, 2, 3), num_experts_per_tok=3,
+                            moe_intermediate_size=32,
+                            moe_shared_expert_intermediate_size=32,
+                            q_lora_rank=48, kv_lora_rank=32,
+                            qk_nope_head_dim=16, qk_rope_head_dim=8,
+                            v_head_dim=16),
         "debug": dict(dim=64, n_heads=4, n_kv_heads=2, head_dim_override=16,
                       vocab_size=256, max_seq_len=4096,
                       hybrid_override_pattern="MEM*E", mamba_num_heads=8,
@@ -90,6 +127,7 @@ def layer_plan(cfg: ModelConfig) -> List[Tuple[str, int]]:
 
 def mixer_init(key: jax.Array, cfg: ModelConfig, kind: str) -> Dict:
     norm = rms_norm_init(cfg.dim)
+    gated = cfg.mlp_hidden_act == "silu"  # the form of "mlp" and "moe"
     if kind == "mamba":
         return {"norm": norm, **mamba2_init(
             key, cfg.dim, cfg.mamba_num_heads, cfg.mamba_head_dim,
@@ -99,11 +137,18 @@ def mixer_init(key: jax.Array, cfg: ModelConfig, kind: str) -> Dict:
         return {"norm": norm, "attn": mha_init(
             key, cfg.dim, cfg.n_heads, cfg.n_kv_heads, bias=False,
             head_dim=cfg.head_dim)}
+    if kind == "mla":
+        return {"norm": norm, "attn": mla_init(
+            key, cfg.dim, cfg.n_heads, cfg.q_lora_rank, cfg.kv_lora_rank,
+            cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim)}
+    if kind == "mlp":
+        return {"norm": norm, **mlp_init(jax.random.split(key, 3), cfg.dim,
+                                         cfg.ffn_dim, gated)}
     if kind == "moe":
         return {"norm": norm, **experts_init(
             key, cfg.dim, cfg.n_routed_experts, len(cfg.held_experts),
             cfg.moe_intermediate_size,
-            cfg.moe_shared_expert_intermediate_size)}
+            cfg.moe_shared_expert_intermediate_size, gated)}
     raise ValueError(f"unknown layer kind {kind!r}")
 
 
@@ -117,11 +162,12 @@ def stack_init(key: jax.Array, cfg: ModelConfig) -> Dict:
         mine = jnp.stack([keys[i] for i, (k, _) in enumerate(plan)
                           if k == kind])
         stacks[kind] = jax.vmap(lambda k: mixer_init(k, cfg, kind))(mine)
-    _log.info("nemotron_h: pattern %s (%s); experts held %s of %d",
-              cfg.hybrid_override_pattern,
+    _log.info("nemotron_h: pattern %s (%s); experts held %s of %d; MLPs and "
+              "experts %s", cfg.hybrid_override_pattern,
               ", ".join(f"{sum(k == kind for k, _ in plan)} {kind}"
                         for kind in stacks),
-              list(cfg.held_experts), cfg.n_routed_experts)
+              list(cfg.held_experts), cfg.n_routed_experts,
+              cfg.mlp_hidden_act)
     return stacks
 
 
@@ -135,6 +181,12 @@ def mixer(cfg: ModelConfig, kind: str, params: Dict, x: jax.Array):
     if kind == "attn":
         return mha_apply(params["attn"], x, x, cfg.n_heads, causal=True,
                          flash=cfg.flash_for(True, x.shape[1])), None
+    if kind == "mla":
+        return mla_apply(params["attn"], x, cfg.n_heads, cfg.qk_rope_head_dim,
+                         cfg.rope_theta, cfg.rms_eps,
+                         flash=cfg.flash_for(True, x.shape[1])), None
+    if kind == "mlp":
+        return mlp_apply(params, x), None
     if kind == "moe":
         b, t, d = x.shape
         out, counts = experts_apply(
@@ -145,7 +197,8 @@ def mixer(cfg: ModelConfig, kind: str, params: Dict, x: jax.Array):
 
 
 #: the profiler region of a layer's norm and mixer, by kind
-SCOPES = {"mamba": "model/ssm", "attn": "model/attn", "moe": "model/moe"}
+SCOPES = {"mamba": "model/ssm", "attn": "model/attn", "mla": "model/attn",
+          "mlp": "model/mlp", "moe": "model/moe"}
 
 
 def mixer_apply(cfg: ModelConfig, kind: str, params: Dict, h: jax.Array):
@@ -195,7 +248,8 @@ def check_mesh(cfg: ModelConfig, mesh) -> None:
     for axis, what in (("model", "tensor-parallel"), ("seq", "sequence-"
                        "parallel"), ("expert", "expert-parallel exchange of")):
         if mesh.shape.get(axis, 1) > 1:
-            missing.append(f"{what} Mamba-2 and expert layers ('{axis}' axis)")
+            missing.append(f"{what} Mamba-2, latent-attention and expert "
+                           f"layers ('{axis}' axis)")
     if missing:
         raise NotImplementedError(
             "arch='nemotron_h' runs on one pipeline stage; not written: "
@@ -240,4 +294,6 @@ def not_served(what: str, cfg: ModelConfig) -> None:
             f"{what}: arch='nemotron_h' is not written for generation — a "
             "decode step of a Mamba-2 layer needs its convolution window and "
             "state-space state cached beside the attention layers' keys and "
-            "values, and an expert layer a decode path")
+            "values, a latent-attention layer its compressed key-value "
+            "latent and shared rotary key (and the absorbed products that "
+            "read them), and an expert layer a decode path")

@@ -13,10 +13,11 @@ Three block families selected by ``ModelConfig.arch``:
 - ``llama`` — pre-RMSNorm, RoPE, grouped-query causal attention, SwiGLU MLP,
   no biases.
 - ``nemotron_h`` — ONE mixer a layer, chosen by ``cfg.hybrid_override_pattern``
-  (Mamba-2, attention, experts): the layers and their stack are
-  :mod:`.nemotron_h`; ``layers`` is then a dict of per-kind stacks walked in
-  pattern order, not one scan (regions ``model/ssm``, ``model/ssm_scan``,
-  ``model/moe``, ``model/moe_experts`` beside ``model/attn``).
+  (Mamba-2, attention, latent attention, a dense MLP, experts): the layers
+  and their stack are :mod:`.nemotron_h`; ``layers`` is then a dict of
+  per-kind stacks walked in pattern order, not one scan (regions
+  ``model/ssm``, ``model/ssm_scan``, ``model/moe``, ``model/moe_experts``,
+  ``model/mla_latent`` beside ``model/attn`` and ``model/mlp``).
 
 Every block names itself for the profiler with ``jax.named_scope`` —
 ``model/embed``, ``model/layers``, ``model/attn``, ``model/mlp``,

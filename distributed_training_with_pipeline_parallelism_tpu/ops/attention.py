@@ -7,7 +7,10 @@ stay natural; the torch-parity test splits torch's packed ``in_proj_weight``
 into these leaves.
 
 Supports grouped-query attention (n_kv_heads < n_heads) and an optional RoPE
-rotation for the Llama family.
+rotation for the Llama family. Below it, multi-head latent attention in its
+training form (:func:`mla_apply`: low-rank query and key-value paths, RoPE in
+interleaved pairs on the rotary columns only, query/key heads wider than
+value heads).
 """
 
 from __future__ import annotations
@@ -18,7 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from .layers import (dropout_apply, linear_init, linear_apply,
-                     sharded_dropout_apply)
+                     rms_norm_apply, rms_norm_init, sharded_dropout_apply)
 
 
 def mha_init(key: jax.Array, dim: int, n_heads: int, n_kv_heads: Optional[int] = None,
@@ -195,3 +198,94 @@ def mha_apply(params: Dict, q_in: jax.Array, kv_in: jax.Array, n_heads: int,
             head_shard=(tp_axis, tp_size) if tp_axis is not None else None)
     out = out.reshape(q_in.shape[0], q_in.shape[1], -1)
     return tp_output_projection(params["o"], out, tp_axis)
+
+
+# ---------------------------------------------------------------------------
+# Multi-head latent attention (DeepSeek-V2/V3-style MLA), training form
+# ---------------------------------------------------------------------------
+
+
+def apply_rope_interleaved(x: jax.Array, angles: jax.Array) -> jax.Array:
+    """Rotate [b, s, h, d] by per-position angles [s, d//2] in the
+    INTERLEAVED convention (``rope_interleave``): the pair ``(x[2i],
+    x[2i+1])`` turns by angle ``i`` and stays where it was
+    (:func:`apply_rope` pairs ``x[i]`` with ``x[i + d/2]``). A pair's
+    partner ``(x0, x1) -> (-x1, x0)`` is a product with a constant signed
+    permutation: exact in any dtype, and no ``[.., d/2, 2]`` reshape, whose
+    two-wide minor dimension a TPU tiles to 128 lanes."""
+    d = x.shape[-1]
+    even = jnp.arange(0, d, 2)
+    swap = (jnp.zeros((d, d), x.dtype).at[even + 1, even].set(-1)
+            .at[even, even + 1].set(1))
+    partner = jnp.einsum("bshd,de->bshe", x, swap,
+                         precision=jax.lax.Precision.HIGHEST)
+    cos = jnp.repeat(jnp.cos(angles), 2, axis=-1)[None, :, None, :]
+    sin = jnp.repeat(jnp.sin(angles), 2, axis=-1)[None, :, None, :]
+    return (x * cos + partner * sin).astype(x.dtype)  # f32 inside, as apply_rope
+
+
+def mla_init(key: jax.Array, dim: int, n_heads: int, q_lora_rank: int,
+             kv_lora_rank: int, qk_nope_head_dim: int, qk_rope_head_dim: int,
+             v_head_dim: int) -> Dict:
+    """The two low-rank paths (``*_a`` down, a norm on the latent, ``*_b`` up
+    per head) and the output projection; no biases. ``kv_a`` also gives the
+    one ``qk_rope_head_dim``-wide key every head shares."""
+    kqa, kqb, kka, kkb, ko = jax.random.split(key, 5)
+    qk = qk_nope_head_dim + qk_rope_head_dim
+    return {
+        "q_a": linear_init(kqa, dim, q_lora_rank, bias=False),
+        "q_norm": rms_norm_init(q_lora_rank),
+        "q_b": linear_init(kqb, q_lora_rank, n_heads * qk, bias=False),
+        "kv_a": linear_init(kka, dim, kv_lora_rank + qk_rope_head_dim,
+                            bias=False),
+        "kv_norm": rms_norm_init(kv_lora_rank),
+        "kv_b": linear_init(kkb, kv_lora_rank,
+                            n_heads * (qk_nope_head_dim + v_head_dim),
+                            bias=False),
+        "o": linear_init(ko, n_heads * v_head_dim, dim, bias=False),
+    }
+
+
+@jax.named_scope("model/mla_latent")
+def mla_project(params: Dict, x: jax.Array, n_heads: int,
+                qk_rope_head_dim: int, rope_theta: float, eps: float):
+    """Everything between the normed input ``x`` [b, s, d] and the attention
+    core's operands: ``q``, ``k`` [b, s, h, nope + rope] and ``v`` [b, s, h,
+    v_head_dim] (the widths are the parameters'). ``c_q = norm(x W_qa)``, ``[q_nope | q_pe] = c_q W_qb``;
+    ``[c_kv | k_pe] = x W_kva``, ``[k_nope | v] = norm(c_kv) W_kvb``; the
+    ``rope`` parts rotated (interleaved pairs, angles in float32, no
+    scaling), ``k_pe`` one head for all."""
+    b, s, _ = x.shape
+    rope = qk_rope_head_dim
+    c_q = rms_norm_apply(params["q_norm"], linear_apply(params["q_a"], x), eps)
+    q = linear_apply(params["q_b"], c_q).reshape(b, s, n_heads, -1)
+    nope = q.shape[-1] - rope
+    c_kv = linear_apply(params["kv_a"], x)
+    kv_rank = c_kv.shape[-1] - rope
+    kv = linear_apply(params["kv_b"], rms_norm_apply(
+        params["kv_norm"], c_kv[..., :kv_rank], eps)).reshape(b, s, n_heads, -1)
+    angles = rope_frequencies(rope, s, rope_theta)
+    q_pe = apply_rope_interleaved(q[..., nope:], angles)
+    k_pe = apply_rope_interleaved(c_kv[:, :, None, kv_rank:], angles)
+    q = jnp.concatenate([q[..., :nope], q_pe], axis=-1)
+    k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(
+        k_pe, (b, s, n_heads, rope))], axis=-1)
+    return q, k, kv[..., nope:]
+
+
+def mla_apply(params: Dict, x: jax.Array, n_heads: int, qk_rope_head_dim: int,
+              rope_theta: float, eps: float, flash: bool = False) -> jax.Array:
+    """Causal latent attention on the normed ``x`` [b, s, d]: scores over
+    ``nope + rope`` columns scaled by ``1/sqrt(nope + rope)``, values and
+    output over ``v_head_dim`` — through the Pallas kernels at the two widths
+    as they are where ``flash`` (:func:`.pallas_attention.flash_attention`),
+    dense otherwise."""
+    b, s, _ = x.shape
+    q, k, v = mla_project(params, x, n_heads, qk_rope_head_dim, rope_theta,
+                          eps)
+    if flash:
+        from .pallas_attention import flash_attention
+        out = flash_attention(q, k, v, causal=True)
+    else:
+        out = scaled_dot_attention(q, k, v, band_mask(s, s)[None, None])
+    return linear_apply(params["o"], out.reshape(b, s, -1))

@@ -330,7 +330,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
     # so the per-element softmax path has no multiplies (module docstring)
     q = (q_ref[0].astype(jnp.float32) * (scale * LOG2E)).astype(q_ref.dtype)
     block_q = q.shape[0]
-    dh = q.shape[1]
+    dv = v_ref.shape[2]  # the values' width: o's, and the accumulator's
 
     n_kv = pl.cdiv(seq_len, block_k)  # seq_len is padded to a block multiple
     if causal:
@@ -387,7 +387,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
 
     carry0 = (jnp.full((block_q,), NEG_INF, jnp.float32),
               jnp.zeros((block_q,), jnp.float32),
-              jnp.zeros((block_q, dh), jnp.float32))
+              jnp.zeros((block_q, dv), jnp.float32))
     if diag_split:
         # diagonal tile: rc >= 0 is instance-invariant at bq == bk
         diag_add = _causal_tile(block_q)
@@ -413,12 +413,14 @@ def _pad_to_blocks(s: int, block_q: int, block_k: int) -> int:
 def _flash_fwd(q: jax.Array, k: jax.Array, v: jax.Array, causal: bool,
                block_q: int, block_k: int,
                window: Optional[int] = None):
-    """q, k, v: [bh, s, dh] -> (out [bh, s, dh], lse [bh, 1, s_pad]). Ragged s
+    """q, k: [bh, s, dh], v: [bh, s, dv] -> (out [bh, s, dv], lse [bh, 1,
+    s_pad]); ``dv`` is ``dh`` everywhere but under latent attention. Ragged s
     (not a block multiple) is zero-padded up front; padded key columns are
     masked dead in-kernel and padded query rows are sliced off the output
     (the lse stays padded — it only feeds the backward kernels, which slice
     consistently)."""
     bh, s, dh = q.shape
+    dv = v.shape[-1]
     scale = 1.0 / (dh ** 0.5)
     block_q = min(block_q, s)
     block_k = min(block_k, s)
@@ -433,15 +435,15 @@ def _flash_fwd(q: jax.Array, k: jax.Array, v: jax.Array, causal: bool,
         strip=_strip_rows(s, block_q, block_k, causal, window, "fwd"))
     out, lse = pl.pallas_call(
         kernel,
-        out_shape=(jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=(jax.ShapeDtypeStruct(v.shape, q.dtype),
                    jax.ShapeDtypeStruct((bh, 1, s_pad), jnp.float32)),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, dh), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, s_pad, dh), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, s_pad, dh), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((1, s_pad, dv), lambda i, j: (i, 0, 0)),
         ],
-        out_specs=(pl.BlockSpec((1, block_q, dh), lambda i, j: (i, j, 0)),
+        out_specs=(pl.BlockSpec((1, block_q, dv), lambda i, j: (i, j, 0)),
                    pl.BlockSpec((1, 1, block_q), lambda i, j: (i, 0, j))),
         interpret=_use_interpret(),
     )(q, k, v)
@@ -546,7 +548,7 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         return body
 
     dk0 = jnp.zeros((block_k, dh), jnp.float32)
-    dv0 = jnp.zeros((block_k, dh), jnp.float32)
+    dv0 = jnp.zeros(v.shape, jnp.float32)
     # Static diagonal split, mirroring the forward: with bq == bk on the
     # plain causal/full path this instance's FIRST live q block (qi == ki)
     # is the diagonal — an instance-invariant additive tile — and every
@@ -571,18 +573,19 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 _DEFAULT_SCOPED_VMEM_BYTES = 16 * 1024 * 1024
 
 
-def _bwd_vmem(s_pad: int, dh: int, itemsize: int) -> dict:
+def _bwd_vmem(s_pad: int, dh: int, dv: int, itemsize: int) -> dict:
     """``pallas_call`` keywords for the classic backward's VMEM. The kernel
-    keeps a whole row's q and do and the float32 dq accumulator resident,
-    each double-buffered by the pipeline: ``2 * s * dh * (2 * itemsize +
-    4)`` bytes, 16 MiB at s = 8192, dh = 128 in bf16 — with the blocks and
+    keeps a whole row's q (``dh`` wide) and do (``dv`` wide) and the float32
+    dq accumulator resident, each double-buffered by the pipeline: ``2 * s *
+    (dh * (itemsize + 4) + dv * itemsize)`` bytes, 16 MiB at s = 8192,
+    dh = dv = 128 in bf16 (22 MiB at 192 and 128) — with the blocks and
     the row statistics, over the default scoped limit, and the compiler
     refuses (seen compiling the nemotron_h step for a described v5e). Where
     the residency passes three quarters of the default the limit is asked
     for explicitly, half as much again (a v5e core has 128 MiB); everywhere
     else — every shape that compiled before — nothing is passed and the
     program is the one it was."""
-    resident = 2 * s_pad * dh * (2 * itemsize + 4)
+    resident = 2 * s_pad * (dh * (itemsize + 4) + dv * itemsize)
     if resident <= 0.75 * _DEFAULT_SCOPED_VMEM_BYTES:
         return {}
     from jax.experimental.pallas import tpu as pltpu
@@ -592,10 +595,11 @@ def _bwd_vmem(s_pad: int, dh: int, itemsize: int) -> dict:
 
 def _flash_bwd(q, k, v, o, lse, g, causal, block_q, block_k, window):
     """Blockwise dq/dk/dv from saved (o, lse): the [s, s] matrix never
-    materializes. Inputs [bh, s, dh] unpadded; lse [bh, 1, s_pad] (padded,
-    log2-domain, from the forward). One fused kernel produces all three
-    grads (see _flash_bwd_kernel)."""
+    materializes. Inputs unpadded, q and k [bh, s, dh], v, o and g [bh, s,
+    dv]; lse [bh, 1, s_pad] (padded, log2-domain, from the forward). One
+    fused kernel produces all three grads (see _flash_bwd_kernel)."""
     bh, s, dh = q.shape
+    dv = v.shape[-1]
     scale = 1.0 / (dh ** 0.5)
     block_q = min(block_q, s)
     block_k = min(block_k, s)
@@ -619,8 +623,8 @@ def _flash_bwd(q, k, v, o, lse, g, causal, block_q, block_k, window):
         in_specs=[
             pl.BlockSpec((1, s_pad, dh), lambda i, j: (i, 0, 0)),     # q
             pl.BlockSpec((1, block_k, dh), lambda i, j: (i, j, 0)),   # k
-            pl.BlockSpec((1, block_k, dh), lambda i, j: (i, j, 0)),   # v
-            pl.BlockSpec((1, s_pad, dh), lambda i, j: (i, 0, 0)),     # do
+            pl.BlockSpec((1, block_k, dv), lambda i, j: (i, j, 0)),   # v
+            pl.BlockSpec((1, s_pad, dv), lambda i, j: (i, 0, 0)),     # do
             pl.BlockSpec((1, 1, s_pad), lambda i, j: (i, 0, 0)),      # lse
             pl.BlockSpec((1, 1, s_pad), lambda i, j: (i, 0, 0)),      # delta
         ],
@@ -628,10 +632,10 @@ def _flash_bwd(q, k, v, o, lse, g, causal, block_q, block_k, window):
             # dq: revisited across the k-grid axis (accumulator)
             pl.BlockSpec((1, s_pad, dh), lambda i, j: (i, 0, 0)),
             pl.BlockSpec((1, block_k, dh), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, block_k, dh), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((1, block_k, dv), lambda i, j: (i, j, 0)),
         ),
         interpret=_use_interpret(),
-        **_bwd_vmem(s_pad, dh, q.dtype.itemsize),
+        **_bwd_vmem(s_pad, dh, dv, q.dtype.itemsize),
     )(q, k, v, g, lse, delta)
     # the deferred `scale` fold (see kernel docstring); XLA fuses it into
     # the cast + transpose that follow
@@ -954,6 +958,11 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     block_k: Optional[int] = None,
                     window: Optional[int] = None) -> jax.Array:
     """Fused attention: q, k, v [batch, seq, heads, head_dim] -> same shape.
+    ``v`` may have a head width of its own (latent attention multiplies
+    scores over 192 and values over 128): the output has ``v``'s, the
+    softmax scale is the queries' ``1/sqrt(head_dim)``, and the classic
+    kernels run the two widths as they are — no zero columns in HBM or in
+    a product. Equal widths compile the programs they compiled before.
 
     Drop-in replacement for the dense attention inside
     ``ops.attention.mha_apply`` (GQA repeat must happen before the call);
@@ -983,6 +992,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     if window is not None and (not causal or window < 1):
         raise ValueError("window requires causal attention and window >= 1")
     b, s, h, dh = q.shape
+    dv = v.shape[-1]
     # AUTO blocks clamp to the sequence so short full-length rows
     # (s <= 1024, where _auto_block returns 1024) still satisfy
     # _packed_ok's s % block_q == 0 and take the transpose-free packed
@@ -999,13 +1009,14 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                 f"clamp to the sequence)")
     block_q = block_q or min(_auto_block(s), s)
     block_k = block_k or min(_auto_block(s), s)
-    packed = _packed_ok(s, h, dh, causal, window, block_q, block_k,
-                        q.dtype.itemsize)
+    packed = dv == dh and _packed_ok(s, h, dh, causal, window, block_q,
+                                     block_k, q.dtype.itemsize)
     strips = {kernel: _strip_rows(s, block_q, block_k, causal, window, kernel)
               for kernel in ("fwd", "bwd")}
     logger.info(
-        "flash_attention: %s kernels, %d x %d x %d x %d, blocks %d x %d, %s",
-        "packed" if packed else "classic", b, s, h, dh, block_q, block_k,
+        "flash_attention: %s kernels, %d x %d x %d x %s, blocks %d x %d, %s",
+        "packed" if packed else "classic", b, s, h,
+        dh if dv == dh else "%d (values %d)" % (dh, dv), block_q, block_k,
         "; ".join("%s strips of %d rows, %d of %d tiles of the causal square"
                   % (kernel, t, *causal_strips(s, t))
                   for kernel, t in strips.items() if t) or "no strips")
@@ -1020,7 +1031,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         return out.reshape(b, s, h, dh)
 
     def flat(x):
-        return x.transpose(0, 2, 1, 3).reshape(b * h, s, dh)
+        return x.transpose(0, 2, 1, 3).reshape(b * h, s, x.shape[-1])
 
     out = _flash(flat(q), flat(k), flat(v), causal, block_q, block_k, window)
-    return out.reshape(b, h, s, dh).transpose(0, 2, 1, 3)
+    return out.reshape(b, h, s, dv).transpose(0, 2, 1, 3)

@@ -31,7 +31,8 @@ class ModelConfig:
         tied-free output head.
       - "nemotron_h": pre-RMSNorm residual layers of ONE mixer each, chosen
         per layer by ``hybrid_override_pattern`` (``M`` Mamba-2, ``*`` causal
-        GQA attention without positions, ``E`` routed + shared experts), as
+        GQA attention without positions, ``L`` latent attention with RoPE,
+        ``-`` a dense MLP, ``E`` routed + shared experts), as
         one expert-parallel rank holds it (``experts_held``). One pipeline
         stage without tensor/sequence/fsdp axes; training and eval only
         (``models/nemotron_h.py``).
@@ -144,6 +145,18 @@ class ModelConfig:
     # all. A token's weights are normalised over ALL its chosen experts and
     # only the held ones' outputs are computed (``ops/experts.py``).
     experts_held: Optional[Tuple[int, ...]] = None
+    # The form of the ``-`` and ``E`` layers' MLPs: "relu2" (two matrices,
+    # Nemotron-H's ``mlp_hidden_act``) or "silu" (gated, three matrices: the
+    # ``hidden_act`` of DeepSeek-V3-style families). ``-`` is ``ffn_dim`` wide.
+    mlp_hidden_act: str = "relu2"
+    # The ``L`` layers (multi-head latent attention), under the source's
+    # names; ``n_heads`` heads, RoPE at ``rope_theta`` in interleaved pairs
+    # on the ``qk_rope_head_dim`` columns, no scaling.
+    q_lora_rank: int = 1536
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
 
     def __post_init__(self):
         if self.dim % self.n_heads != 0:
@@ -206,12 +219,19 @@ class ModelConfig:
         pattern = self.hybrid_override_pattern
         if not pattern:
             raise ValueError("arch='nemotron_h' needs hybrid_override_pattern "
-                             "(one of 'M', '*', 'E' a layer)")
-        unknown = sorted(set(pattern) - set("M*E"))
+                             "(one of 'M', '*', 'L', '-', 'E' a layer)")
+        unknown = sorted(set(pattern) - set("M*L-E"))
         if unknown:
             raise ValueError(
                 f"hybrid_override_pattern {pattern!r}: unknown layer kind(s) "
-                f"{unknown}; 'M' is Mamba-2, '*' attention, 'E' experts")
+                f"{unknown}; 'M' is Mamba-2, '*' attention, 'L' latent "
+                "attention, '-' a dense MLP, 'E' experts")
+        if self.mlp_hidden_act not in ("relu2", "silu"):
+            raise ValueError(f"mlp_hidden_act={self.mlp_hidden_act!r} must "
+                             "be 'relu2' or 'silu' (gated)")
+        if self.qk_rope_head_dim % 2:
+            raise ValueError(f"qk_rope_head_dim={self.qk_rope_head_dim} must "
+                             "be even: RoPE turns pairs")
         if self.n_layers != len(pattern):
             raise ValueError(f"n_layers={self.n_layers} is not the length of "
                              f"hybrid_override_pattern {pattern!r}")

@@ -81,17 +81,21 @@ def annotated_steps(steps: Iterable[int],
 #: regions too, wherever no name of this list is further in.
 REGIONS = ("model/embed", "model/layers", "model/attn", "model/mlp",
            "model/head_loss", "train/optimizer")
-#: The ``nemotron_h`` layers' own four, beside ``model/attn`` for the
-#: attention layer, disjoint by the innermost-wins rule: ``model/ssm``
+#: The patterned stack's own five, beside ``model/attn`` for its attention
+#: layers and ``model/mlp`` for its dense MLP, disjoint by the
+#: innermost-wins rule: ``model/ssm``
 #: (``models/nemotron_h.py``: a Mamba-2 mixer but its scan — norm, both
 #: projections, convolution, gate, group norm), ``model/ssm_scan``
 #: (``ops/mamba2.py:ssd_chunked``), ``model/moe`` (norm, router, top-k,
-#: the gate, shared expert) and ``model/moe_experts``
-#: (``ops/experts.py:held_experts``, the held experts' two gated
-#: products). Kept apart from :data:`REGIONS`, which every dense step names
+#: the gate, shared expert), ``model/moe_experts``
+#: (``ops/experts.py:held_experts``, the held experts' gated products) and
+#: ``model/mla_latent`` (``ops/attention.py:mla_project``, inside a latent-
+#: attention layer's ``model/attn``: both down-projections, the latent
+#: norms, both up-projections, RoPE, building ``k`` — everything between the
+#: normed input and the attention core's operands). Kept apart from :data:`REGIONS`, which every dense step names
 #: whole (``benchmark/tests/test_bench_scopes.py`` holds it to that).
 HYBRID_REGIONS = ("model/ssm", "model/ssm_scan", "model/moe",
-                  "model/moe_experts")
+                  "model/moe_experts", "model/mla_latent")
 UNSCOPED = "unscoped"
 
 #: In this order, the first mark an op_name holds gives its phase. JAX writes

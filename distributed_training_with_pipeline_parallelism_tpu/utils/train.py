@@ -367,8 +367,9 @@ def adamw(learning_rate: float = 3e-4, weight_decay: float = 0.01,
     cannot distinguish these in the stacked-layer layout (a per-layer bias
     stack is 2-D), so the mask keys off this framework's naming
     convention: matrices live under "w" (linear/attention/router, and the
-    nemotron_h layers' projections and convolution) and "w1"/"w2" (expert
-    stacks, ``models/moe.py``'s and ``ops/experts.py``'s). The nemotron_h
+    nemotron_h layers' projections and convolution, latent attention's five
+    matrices) and "w1"/"w2"/"w3" (expert stacks, ``models/moe.py``'s and
+    ``ops/experts.py``'s; ``w3`` is the gated form's linear branch). The nemotron_h
     leaves that are no matrices — ``A_log``, ``D``, ``dt_bias``, the
     router's ``bias`` buffer, norm scales — carry other names and are not
     decayed."""
@@ -378,7 +379,8 @@ def adamw(learning_rate: float = 3e-4, weight_decay: float = 0.01,
 
     def decay_mask(params):
         return jax.tree_util.tree_map_with_path(
-            lambda path, _: getattr(path[-1], "key", None) in ("w", "w1", "w2"),
+            lambda path, _: getattr(path[-1], "key", None) in ("w", "w1", "w2",
+                                                             "w3"),
             params)
 
     return optax.chain(

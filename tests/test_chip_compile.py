@@ -108,6 +108,33 @@ def test_flash_fwd_bwd_compiles(one_chip, mosaic, shape, window):
     assert text.count("tpu_custom_call") >= 2  # forward and backward kernels
 
 
+@pytest.mark.parametrize("seq", [8192, 1024],
+                         ids=["blocks-of-256", "one-block-strips"])
+def test_flash_two_widths_compile(one_chip, mosaic, seq):
+    """Latent attention's geometry, 32 heads of 192 for q and k and of 128
+    for v, at the benchmark cell's 8192 rows (the backward asks for 33 MiB
+    of VMEM there) and in one block: Mosaic takes the two widths as they
+    are. O and dV come out 128 wide and dQ, dK 192: nothing is padded to a
+    common width in HBM. (v padded to 192 through the equal-width kernels is
+    REFUSED at 8192 rows: the forward's resident k and v rows pass the
+    default 16 MiB; PR 34.)"""
+    q = jax.ShapeDtypeStruct((2, seq, 32, 192), jnp.bfloat16, sharding=one_chip)
+    v = jax.ShapeDtypeStruct((2, seq, 32, 128), jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        return pallas_attention.flash_attention(
+            q, k, v, causal=True).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        q, q, v).compile().as_text()
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert len(calls) == 2, calls
+    fwd, bwd = sorted(calls, key=lambda line: "f32[64,%d,192]" % seq in line)
+    assert f"(bf16[64,{seq},128]" in fwd            # O
+    assert (f"(f32[64,{seq},192]" in bwd and f"bf16[64,{seq},192]" in bwd
+            and f"bf16[64,{seq},128]" in bwd)       # dQ, dK, dV
+
+
 def test_fused_xent_compiles(one_chip, mosaic):
     logits = jax.ShapeDtypeStruct((8192, 50257), jnp.bfloat16,
                                   sharding=one_chip)
@@ -145,6 +172,36 @@ def test_nemotron_h_layers_compile_at_published_widths(one_chip):
                 jnp.float32).sum()
 
         jax.jit(jax.grad(loss)).lower(params, h).compile()
+
+
+@pytest.mark.parametrize("kind", ["mla", "mlp", "moe"])
+def test_joyai_llm_flash_layers_compile_at_published_widths(one_chip, mosaic,
+                                                            kind):
+    """One sublayer of each kind the benchmark's joyai-llm-flash
+    configuration has — latent attention through the flash kernels at
+    192 | 128, the dense gated MLP, the gated experts (8 of 256 held) —
+    forward and backward at batch 2 x seq 8192, the cell's shapes. The whole
+    16-sublayer step is ``test_joyai_llm_flash_train_step_fits_one_chip``
+    (``slow``: a minute of the compiler on every core)."""
+    from distributed_training_with_pipeline_parallelism_tpu.models import (
+        nemotron_h)
+    cfg = nemotron_h.nemotron_h_config(
+        "joyai-stage", dtype="bfloat16", param_dtype="float32",
+        use_flash_attention=True)
+    h = jax.ShapeDtypeStruct((2, 8192, cfg.dim), jnp.bfloat16,
+                             sharding=one_chip)
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, jnp.bfloat16,
+                                       sharding=one_chip),
+        jax.eval_shape(lambda: nemotron_h.mixer_init(jax.random.key(0), cfg,
+                                                     kind)))
+
+    def loss(p, x):
+        return nemotron_h.mixer_apply(cfg, kind, p, x)[0].astype(
+            jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss)).lower(params, h).compile().as_text()
+    assert (text.count("tpu_custom_call") == 2) == (kind == "mla")
 
 
 def lower_train_step(cfg, mesh, sched, batch, seq):
@@ -202,6 +259,42 @@ def test_gpt2_medium_train_step_fits_one_chip(topo, mosaic, batch, seq):
     names = re.findall(r"^\s*(?:ROOT )?%?([\w.\-]+) = ", text, re.M)
     assert len(names) > 1000, len(names)
     assert [n for n in names if ".remat" in n] == []
+
+
+@pytest.mark.slow
+def test_joyai_llm_flash_train_step_fits_one_chip(topo, mosaic):
+    """The benchmark cell ``joyai-llm-flash.train-b2s8192`` as it runs: the
+    first eight layers at published widths (latent attention, a dense gated
+    MLP, seven layers of gated experts; 622.0 M parameters), bf16 over fp32
+    masters, AdamW, batch 2 x seq 8192, ``remat_layers`` — which this count
+    decides: the plain program is refused ('Used 19.90G of 15.75G hbm',
+    compiled by hand with :func:`lower_train_step`, PR 34), this one counts
+    12.109 GB. Both flash kernels are in it at the two widths. ``slow``: it
+    takes the chip's compiler a minute on every core the suite shares (and
+    half of that is spent whatever the depth); tier 1 compiles each kind of
+    sublayer at these shapes
+    (``test_joyai_llm_flash_layers_compile_at_published_widths``)."""
+    from distributed_training_with_pipeline_parallelism_tpu.models.nemotron_h import (
+        nemotron_h_config)
+    cfg = nemotron_h_config(
+        "joyai-stage", dtype="bfloat16", param_dtype="float32",
+        use_flash_attention=True, use_fused_xent=True, remat_layers=True)
+    mesh = make_mesh(n_pipe=1, devices=topo.devices[:1])
+    lowered, params = lower_train_step(
+        cfg, mesh, ScheduleConfig(name="1F1B", n_microbatches=2), 2, 8192)
+    assert sum(x.size for x in jax.tree.leaves(params)) == 621_989_632
+    compiled = lowered.compile()
+    b = _bytes(compiled)
+    assert b["alias"] > 0.9 * b["argument"], b  # params + moments in place
+    total = b["argument"] + b["output"] + b["temp"] - b["alias"]
+    assert 11.9e9 < total < 12.4e9 < HBM_BYTES, b  # the cell's sized_by: 12.109
+    text = compiled.as_text()
+    kernels = set()
+    for body in re.findall(r'"body":"([A-Za-z0-9+/=]+)"', text):
+        kernels.update(re.findall(rb"_flash_(?:fwd|bwd)_kernel",
+                                  base64.b64decode(body)))
+    assert kernels == {b"_flash_fwd_kernel", b"_flash_bwd_kernel"}, kernels
+    assert "bf16[64,8192,192]" in text and "bf16[64,8192,128]" in text
 
 
 def test_gpt2_xl_width_pipe4_rests_sharded(topo, mosaic):

@@ -150,12 +150,12 @@ def test_adamw_decays_matrices_only():
     params = tfm.transformer_init(jax.random.key(0), cfg)
     opt = train.adamw(learning_rate=1e-2, weight_decay=0.1, warmup_steps=0,
                       total_steps=10)
-    state = opt.init(params)
     zero_grads = jax.tree.map(jnp.zeros_like, params)
-    updates, _ = opt.update(zero_grads, state, params)
+    updates, _ = jax.jit(lambda g, p: opt.update(g, opt.init(p), p))(
+        zero_grads, params)
     moved = jax.tree.map(lambda u: float(jnp.max(jnp.abs(u))) > 0, updates)
     for path, did_move in jax.tree_util.tree_leaves_with_path(moved):
-        is_matrix = getattr(path[-1], "key", None) in ("w", "w1", "w2")
+        is_matrix = getattr(path[-1], "key", None) in ("w", "w1", "w2", "w3")
         assert did_move == is_matrix, path
 
 
@@ -167,8 +167,8 @@ def test_adamw_decay_set_matches_golden_list():
     params = tfm.transformer_init(jax.random.key(0), cfg)
     opt = train.adamw(learning_rate=1e-2, weight_decay=0.1, warmup_steps=0,
                       total_steps=10)
-    updates, _ = opt.update(jax.tree.map(jnp.zeros_like, params),
-                            opt.init(params), params)
+    updates, _ = jax.jit(lambda g, p: opt.update(g, opt.init(p), p))(
+        jax.tree.map(jnp.zeros_like, params), params)
     decayed = {jax.tree_util.keystr(p)
                for p, u in jax.tree_util.tree_leaves_with_path(updates)
                if float(jnp.max(jnp.abs(u))) > 0}
