@@ -27,7 +27,9 @@ Parameters: ``layers`` is ``{"mamba": .., "attn": .., "mla": .., "mlp": ..,
 "moe": ..}`` (the kinds the pattern has), each the
 layers of one kind stacked on axis 0 in pattern order; the stack is walked in
 pattern order as straight-line code (layers of different kinds share no
-scan), each layer under ``jax.checkpoint`` where ``cfg.remat_layers``.
+scan), each layer under ``jax.checkpoint`` where ``cfg.remat_layers``
+(:func:`..ops.layers.remat_layer`: all but the flash kernels' output and
+log-sum-exp is recomputed in the backward).
 ``transformer_init`` / ``body_apply`` of :mod:`.transformer` dispatch here,
 so ``transformer_loss``, ``train.init_params`` and ``train.make_train_step``
 run this family on the normal path.
@@ -47,7 +49,7 @@ import jax.numpy as jnp
 
 from ..ops.attention import mha_apply, mha_init, mla_apply, mla_init
 from ..ops.experts import experts_apply, experts_init, mlp_apply, mlp_init
-from ..ops.layers import rms_norm_apply, rms_norm_init
+from ..ops.layers import remat_layer, rms_norm_apply, rms_norm_init
 from ..ops.mamba2 import mamba2_apply, mamba2_init
 from ..utils.config import ModelConfig
 
@@ -211,13 +213,17 @@ def mixer_apply(cfg: ModelConfig, kind: str, params: Dict, h: jax.Array):
 
 def stack_apply(cfg: ModelConfig, layers: Dict, h: jax.Array,
                 ) -> Tuple[jax.Array, List[jax.Array]]:
-    """Walk the pattern -> (h, the expert layers' counts in order)."""
+    """Walk the pattern -> (h, the expert layers' counts in order). Under
+    ``cfg.remat_layers`` every layer is recomputed in the backward from its
+    input, but for what :func:`..ops.layers.remat_layer` keeps: the flash
+    kernels' output and log-sum-exp of an attention layer."""
+    plan = layer_plan(cfg)
+    one = mixer_apply
+    if cfg.remat_layers:
+        one = remat_layer(one, len(plan), static_argnums=(0, 1))
     counts = []
-    for kind, i in layer_plan(cfg):
-        one = lambda p, x, kind=kind: mixer_apply(cfg, kind, p, x)  # noqa: E731
-        if cfg.remat_layers:
-            one = jax.checkpoint(one)
-        h, c = one(jax.tree.map(lambda x: x[i], layers[kind]), h)
+    for kind, i in plan:
+        h, c = one(cfg, kind, jax.tree.map(lambda x: x[i], layers[kind]), h)
         if c is not None:
             counts.append(c)
     return h, counts

@@ -42,8 +42,8 @@ import jax.numpy as jnp
 from ..ops.attention import mha_apply, mha_init, rope_frequencies
 from ..ops.layers import (dropout_apply, embedding_apply, embedding_init,
                           layer_norm_apply, layer_norm_init, linear_apply,
-                          linear_init, rms_norm_apply, rms_norm_init,
-                          select_xent, sharded_dropout_apply)
+                          linear_init, remat_layer, rms_norm_apply,
+                          rms_norm_init, select_xent, sharded_dropout_apply)
 from ..utils.config import ModelConfig
 from . import nemotron_h
 
@@ -339,6 +339,10 @@ def body_apply(cfg: ModelConfig, layers: Dict, h: jax.Array,
     nemotron_h: ``layers`` is the dict of per-kind stacks and the WHOLE
     pattern is walked (:func:`.nemotron_h.stack_apply`); a stack of one kind
     keeps the scan below.
+
+    ``cfg.remat_layers``: each layer is recomputed in the backward from its
+    input, all but the flash kernels' output and log-sum-exp, which are
+    kept where the kernels run (:func:`..ops.layers.remat_layer`).
     """
     if cfg.arch == "nemotron_h":
         if tp_axis is not None or rng is not None:
@@ -363,7 +367,7 @@ def body_apply(cfg: ModelConfig, layers: Dict, h: jax.Array,
                                tp_size=tp_size, rng=rng_l)
 
         if cfg.remat_layers:
-            one = jax.checkpoint(one, static_argnums=(2,))
+            one = remat_layer(one, n, static_argnums=(2,))
         for i in range(n):
             h = one(jax.tree.map(lambda x: x[i], layers), h, i)
         return h
@@ -376,8 +380,9 @@ def body_apply(cfg: ModelConfig, layers: Dict, h: jax.Array,
 
     if cfg.remat_layers:
         # rematerialize each layer in backward: activation memory drops from
-        # O(layers x intermediates) to O(layers) block inputs
-        step = jax.checkpoint(step)
+        # O(layers x intermediates) to O(layers) block inputs, and the flash
+        # kernels' output and log-sum-exp where they run
+        step = remat_layer(step, n)
     out, _ = jax.lax.scan(step, h, (layers, jnp.arange(n)))
     return out
 

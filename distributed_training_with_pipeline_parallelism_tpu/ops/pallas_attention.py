@@ -99,6 +99,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 
 logger = logging.getLogger(__name__)
@@ -865,6 +866,18 @@ def _flash_bwd_packed(q, k, v, o, lse, g, h, block_q, block_k):
     return (dq * scale).astype(q.dtype), dk, dv
 
 
+#: what both forward rules call the two residuals that only the forward kernel
+#: can give back: a ``jax.checkpoint`` policy that keeps these names
+#: (:func:`..ops.layers.remat_layer`) spares a rematerialised layer the
+#: kernel's second run. Outside such a policy the name lowers to nothing.
+FLASH_OUT = "flash_out"
+FLASH_LSE = "flash_lse"
+
+
+def _named(out, lse):
+    return checkpoint_name(out, FLASH_OUT), checkpoint_name(lse, FLASH_LSE)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def _flash_packed(q, k, v, h, block_q, block_k):
     out, _ = _flash_fwd_packed(q, k, v, h, block_q, block_k)
@@ -872,7 +885,7 @@ def _flash_packed(q, k, v, h, block_q, block_k):
 
 
 def _flash_packed_vjp_fwd(q, k, v, h, block_q, block_k):
-    out, lse = _flash_fwd_packed(q, k, v, h, block_q, block_k)
+    out, lse = _named(*_flash_fwd_packed(q, k, v, h, block_q, block_k))
     return out, (q, k, v, out, lse)
 
 
@@ -904,7 +917,7 @@ def _flash(q, k, v, causal, block_q, block_k, window):
 
 
 def _flash_vjp_fwd(q, k, v, causal, block_q, block_k, window):
-    out, lse = _flash_fwd(q, k, v, causal, block_q, block_k, window)
+    out, lse = _named(*_flash_fwd(q, k, v, causal, block_q, block_k, window))
     return out, (q, k, v, out, lse)
 
 

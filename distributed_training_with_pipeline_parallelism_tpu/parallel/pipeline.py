@@ -49,7 +49,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from ..models.transformer import (body_apply, compute_cast, embed_apply,
                                   head_apply, head_norm_apply,
                                   transformer_loss)
-from ..ops.layers import (global_pad_scale, linear_apply,
+from ..ops.layers import (global_pad_scale, linear_apply, remat_layer,
                           select_masked_xent_sum, select_xent)
 from ..utils.config import ModelConfig, ScheduleConfig
 from .mesh import (DATA_AXIS, EXPERT_AXIS, MODEL_AXIS, PIPE_AXIS,
@@ -1123,7 +1123,7 @@ def make_pipeline_grad_fn(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig,
                     return (h, aux + a), None
 
                 if cfg.remat_layers:
-                    mstep = jax.checkpoint(mstep)
+                    mstep = remat_layer(mstep, lps)
                 (y, aux), _ = jax.lax.scan(mstep, (x, zero),
                                            (layer_p, jnp.arange(lps)))
                 return y, aux

@@ -24,7 +24,8 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from ..models.transformer import ModelConfig
 from ..ops.layers import (select_xent, embedding_apply,
-                          layer_norm_apply, linear_apply, rms_norm_apply)
+                          layer_norm_apply, linear_apply, remat_layer,
+                          rms_norm_apply)
 from .mesh import SEQ_AXIS
 from .pipeline import _shard_map
 from .ring_attention import local_rope_angles, ring_mha_apply
@@ -163,7 +164,7 @@ def sp_body_apply(cfg: ModelConfig, layers, h: jax.Array, axis_name: str,
                               sp_size=sp_size), None
 
     if cfg.remat_layers:
-        step = jax.checkpoint(step)
+        step = remat_layer(step, n)
     h, _ = jax.lax.scan(step, h, (layers, jnp.arange(n)))
     return h
 

@@ -78,7 +78,10 @@ class ModelConfig:
     # everywhere else (shorter sequences, non-causal ref_decoder, CPU CI).
     use_flash_attention: Union[bool, str] = "auto"
     use_fused_xent: bool = False  # route the loss through the Pallas fused-CE kernel
-    remat_layers: bool = False  # jax.checkpoint each layer: trade FLOPs for HBM
+    # jax.checkpoint each layer, trading FLOPs for HBM: the backward recomputes
+    # a layer from its input, all but the flash kernels' output and
+    # log-sum-exp, which are kept (ops/layers.py:remat_layer)
+    remat_layers: bool = False
     # Unroll the per-layer scan into straight-line code: XLA fuses across
     # layers and backward residuals avoid the scan-boundary HBM round-trip
     # (measured +5-12% train-step throughput on one v5e chip at GPT-2

@@ -174,15 +174,9 @@ def test_nemotron_h_layers_compile_at_published_widths(one_chip):
         jax.jit(jax.grad(loss)).lower(params, h).compile()
 
 
-@pytest.mark.parametrize("kind", ["mla", "mlp", "moe"])
-def test_joyai_llm_flash_layers_compile_at_published_widths(one_chip, mosaic,
-                                                            kind):
-    """One sublayer of each kind the benchmark's joyai-llm-flash
-    configuration has — latent attention through the flash kernels at
-    192 | 128, the dense gated MLP, the gated experts (8 of 256 held) —
-    forward and backward at batch 2 x seq 8192, the cell's shapes. The whole
-    16-sublayer step is ``test_joyai_llm_flash_train_step_fits_one_chip``
-    (``slow``: a minute of the compiler on every core)."""
+def _joyai_sublayer(one_chip, kind):
+    """(cfg, abstract params, abstract input) of one sublayer of the
+    benchmark's joyai-llm-flash configuration at batch 2 x seq 8192."""
     from distributed_training_with_pipeline_parallelism_tpu.models import (
         nemotron_h)
     cfg = nemotron_h.nemotron_h_config(
@@ -195,6 +189,21 @@ def test_joyai_llm_flash_layers_compile_at_published_widths(one_chip, mosaic,
                                        sharding=one_chip),
         jax.eval_shape(lambda: nemotron_h.mixer_init(jax.random.key(0), cfg,
                                                      kind)))
+    return cfg, params, h
+
+
+@pytest.mark.parametrize("kind", ["mla", "mlp", "moe"])
+def test_joyai_llm_flash_layers_compile_at_published_widths(one_chip, mosaic,
+                                                            kind):
+    """One sublayer of each kind the benchmark's joyai-llm-flash
+    configuration has — latent attention through the flash kernels at
+    192 | 128, the dense gated MLP, the gated experts (8 of 256 held) —
+    forward and backward at batch 2 x seq 8192, the cell's shapes. The whole
+    16-sublayer step is ``test_joyai_llm_flash_train_step_fits_one_chip``
+    (``slow``: a minute of the compiler on every core)."""
+    from distributed_training_with_pipeline_parallelism_tpu.models import (
+        nemotron_h)
+    cfg, params, h = _joyai_sublayer(one_chip, kind)
 
     def loss(p, x):
         return nemotron_h.mixer_apply(cfg, kind, p, x)[0].astype(
@@ -202,6 +211,32 @@ def test_joyai_llm_flash_layers_compile_at_published_widths(one_chip, mosaic,
 
     text = jax.jit(jax.grad(loss)).lower(params, h).compile().as_text()
     assert (text.count("tpu_custom_call") == 2) == (kind == "mla")
+
+
+@pytest.mark.parametrize("wrap,calls", [("remat_layer", 2), ("bare", 3)])
+def test_joyai_llm_flash_remat_layer_runs_the_forward_kernel_once(
+        one_chip, mosaic, wrap, calls):
+    """The latent-attention sublayer as the cell runs it, rematerialised:
+    under ``ops.layers.remat_layer`` the compiled gradient holds the forward
+    kernel once and the backward once; under the bare ``jax.checkpoint`` (the
+    cell until PR 35) it holds the forward a second time, only to get back
+    the output and the log-sum-exp the backward kernel reads."""
+    from distributed_training_with_pipeline_parallelism_tpu.models import (
+        nemotron_h)
+    from distributed_training_with_pipeline_parallelism_tpu.ops.layers import (
+        remat_layer)
+    cfg, params, h = _joyai_sublayer(one_chip, "mla")
+    layer = lambda p, x: nemotron_h.mixer_apply(cfg, "mla", p, x)[0]  # noqa: E731
+    layer = (remat_layer(layer, 1) if wrap == "remat_layer"
+             else jax.checkpoint(layer))
+
+    def loss(p, x):
+        return layer(p, x).astype(jnp.float32).sum()
+
+    # the value too: a gradient alone leaves the first forward dead
+    text = jax.jit(jax.value_and_grad(loss)).lower(params, h).compile(
+        ).as_text()
+    assert text.count("tpu_custom_call") == calls
 
 
 def lower_train_step(cfg, mesh, sched, batch, seq):
@@ -269,11 +304,19 @@ def test_joyai_llm_flash_train_step_fits_one_chip(topo, mosaic):
     masters, AdamW, batch 2 x seq 8192, ``remat_layers`` — which this count
     decides: the plain program is refused ('Used 19.90G of 15.75G hbm',
     compiled by hand with :func:`lower_train_step`, PR 34), this one counts
-    12.109 GB. Both flash kernels are in it at the two widths. ``slow``: it
-    takes the chip's compiler a minute on every core the suite shares (and
-    half of that is spent whatever the depth); tier 1 compiles each kind of
-    sublayer at these shapes
-    (``test_joyai_llm_flash_layers_compile_at_published_widths``)."""
+    13.121 GB: 12.109 for every layer recomputed from its input, and 8 x
+    (134 MB + 2 MB) for the flash kernels' output and log-sum-exp, which
+    ``remat_layer`` keeps (PR 35). So each of the eight attention sublayers
+    runs the forward kernel once and the backward once: 16 calls of the two
+    kernels at the two widths, 24 while the forward ran again in every
+    backward. ``slow``: it takes the chip's compiler a minute on every core
+    the suite shares (and half of that is spent whatever the depth); tier 1
+    compiles each kind of sublayer at these shapes
+    (``test_joyai_llm_flash_layers_compile_at_published_widths``) and the
+    rematerialised attention sublayer
+    (``test_joyai_llm_flash_remat_layer_runs_the_forward_kernel_once``).
+    The ``nemotron`` step, compiled the same way by hand (PR 35): 14.654 GB
+    (14.517 before), one forward and one backward call."""
     from distributed_training_with_pipeline_parallelism_tpu.models.nemotron_h import (
         nemotron_h_config)
     cfg = nemotron_h_config(
@@ -287,13 +330,14 @@ def test_joyai_llm_flash_train_step_fits_one_chip(topo, mosaic):
     b = _bytes(compiled)
     assert b["alias"] > 0.9 * b["argument"], b  # params + moments in place
     total = b["argument"] + b["output"] + b["temp"] - b["alias"]
-    assert 11.9e9 < total < 12.4e9 < HBM_BYTES, b  # the cell's sized_by: 12.109
+    assert 12.9e9 < total < 13.4e9 < HBM_BYTES, b  # 13.121 (PR 35)
     text = compiled.as_text()
-    kernels = set()
+    kernels = []
     for body in re.findall(r'"body":"([A-Za-z0-9+/=]+)"', text):
-        kernels.update(re.findall(rb"_flash_(?:fwd|bwd)_kernel",
-                                  base64.b64decode(body)))
-    assert kernels == {b"_flash_fwd_kernel", b"_flash_bwd_kernel"}, kernels
+        kernels += re.findall(rb"_flash_(?:fwd|bwd)_kernel",
+                              base64.b64decode(body))
+    assert (kernels.count(b"_flash_fwd_kernel"),
+            kernels.count(b"_flash_bwd_kernel")) == (8, 8), kernels
     assert "bf16[64,8192,192]" in text and "bf16[64,8192,128]" in text
 
 

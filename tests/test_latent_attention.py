@@ -168,29 +168,33 @@ def test_flash_logs_the_two_widths(caplog):
 # parent of PR 34 (commit 3ff3fec) in this installation, by this test's own
 # function run in that tree under this suite's conftest (the text holds the
 # jitted function's name and the device count): a call whose widths are equal
-# compiles the program it compiled before the kernels learnt two.
+# compiles the program it compiled before the kernels learnt two. The number
+# the lowering puts on a private function's name is taken off first
+# (``@_where_48``): naming the forward rules' residuals (PR 35,
+# ``checkpoint_name``: it lowers to nothing) moved that number by one and
+# nothing else, and these are that commit's texts read so.
 EQUAL_WIDTH_TEXTS = {
     "packed-strips": (
         (1, 256, 2, 64), "bfloat16", {},
-        "fd3ee74cfe2c6f2d976880277f76f5bb23c78414886ee644822525c9564b84dd"),
+        "09121f6b48183e2e63ca4b2441d82330344a374f90f3c72ae4b6d4fdf1be2323"),
     "packed-multi-block": (
         (1, 256, 2, 64), "bfloat16", dict(block_q=128, block_k=128),
-        "0817fe4c1e3dd4196fb920b31a747c1c79db67fcc4820bd56eb0fa53776ff6a2"),
+        "4943f5319127116922e30ac082e80368d8d7a08ce2101e53b9eba8b06528be5b"),
     "classic-strips": (
         (1, 256, 3, 64), "bfloat16", {},
-        "e8ca17932167ee181f8652e9abba44b07a7718e38bbde155caa1e97a3eb0ac3d"),
+        "1a150e9122ea7ba0f3590c3744d198f0538cbb37aa2df39dd36ca6d66f059c16"),
     "classic-multi-block": (
         (1, 256, 3, 32), "float32", dict(block_q=64, block_k=64),
-        "546ece396e0cba24b7173d93769add9a5e21625ee6ce77950bb6557adce38dcb"),
+        "4610dfc4c9d3042d3fe000a48c53b9ba8f8caa56a991625f19f098d906d14e70"),
     "classic-ragged": (
         (1, 200, 3, 64), "float32", dict(block_q=64, block_k=64),
-        "229147db1d1446ecd1804f3f90f81d7d2d6b59aebe949f6c7a11f6049abe8f9c"),
+        "2c8ce1f12b58687acb5addbbf28a7f0cd7322d463e61945088f3fd1cdd4f8c04"),
     "classic-window": (
         (1, 256, 2, 128), "bfloat16", dict(block_q=64, block_k=64, window=96),
-        "ac0c50161f810cba8d07da0523dd56daafe58a7a573ca357e27ac37762c39fa3"),
+        "d3d9e03d41facb9b4bc181b2e27823483a1547e6fb63e852576d7490a0c072e5"),
     "classic-noncausal": (
         (1, 128, 3, 64), "float32", dict(block_q=64, block_k=64, causal=False),
-        "aae21d79764cf3b5d3ae22865da69e0ceb8d5705569e40b8afead4e0aea353ce"),
+        "140495ae8fe34bc31c064289c9f551f951430539fcb8d373422ff821e2a4f88d"),
 }
 
 
@@ -211,6 +215,7 @@ def test_equal_width_calls_lower_to_the_parents_text(case):
 
 def _text_sha256(both, x):
     text = jax.jit(both).lower(x, x, x, x).as_text()
+    text = re.sub(r"@(\w+?)_\d+\b", r"@\1", text)
     return hashlib.sha256(text.encode()).hexdigest()
 
 

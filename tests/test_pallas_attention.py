@@ -331,3 +331,85 @@ def test_flash_causal_strips_count_and_log(caplog):
     assert ("classic kernels, 2 x 1024 x 3 x 64, blocks 1024 x 1024, "
             "fwd strips of 512 rows, 3 of 4 tiles of the causal square; "
             "bwd strips of 128 rows, 36 of 64 tiles") in caplog.text
+
+
+def _kernel_calls(jaxpr, out=None):
+    """Kernel function name -> how many ``pallas_call``s of it a jaxpr holds,
+    at any depth (remat, custom_vjp, scan and jit bodies)."""
+    out = {} if out is None else out
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            name = eqn.params["jaxpr"].debug_info.func_name
+            out[name] = out.get(name, 0) + 1
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _kernel_calls(sub, out)
+    return out
+
+
+@pytest.mark.parametrize("h,dh,dv,flash,fwd,bwd", [
+    (3, 64, 64, True, "_flash_fwd_kernel", "_flash_bwd_kernel"),
+    (2, 64, 64, True, "_flash_fwd_kernel_packed", "_flash_bwd_kernel_packed"),
+    (2, 24, 16, True, "_flash_fwd_kernel", "_flash_bwd_kernel"),
+    (2, 64, 64, False, None, None),
+], ids=["classic", "packed", "two-widths", "dense"])
+def test_remat_layer_keeps_flash_out_and_lse(capsys, caplog, h, dh, dv, flash,
+                                             fwd, bwd):
+    """What ``cfg.remat_layers`` wraps a layer in
+    (:func:`ops.layers.remat_layer`): the backward recomputes the layer but
+    not the flash forward kernel, whose output and log-sum-exp both forward
+    rules name and the policy keeps. Under the bare ``jax.checkpoint`` (all
+    there was until PR 35) the gradient ran that kernel twice a layer. The
+    gradients are the same numbers either way, and those of the layer
+    without any checkpoint; a dense-attention layer has no such names and
+    keeps what it kept. The helper says so at INFO."""
+    from jax.ad_checkpoint import print_saved_residuals
+    from distributed_training_with_pipeline_parallelism_tpu.ops.layers import (
+        remat_layer)
+    b, s, d = 2, 128, 32
+    keys = jax.random.split(jax.random.key(35), 5)
+    w = {"q": jax.random.normal(keys[0], (d, h * dh)) / d ** 0.5,
+         "k": jax.random.normal(keys[1], (d, h * dh)) / d ** 0.5,
+         "v": jax.random.normal(keys[2], (d, h * dv)) / d ** 0.5,
+         "o": jax.random.normal(keys[3], (h * dv, d)) / (h * dv) ** 0.5}
+    x = jax.random.normal(keys[4], (b, s, d))
+
+    def layer(w, x):
+        q, k, v = ((x @ w[n]).reshape(b, s, h, -1) for n in "qkv")
+        o = (flash_attention(q, k, v, causal=True) if flash
+             else _full(q, k, v, True))
+        return x + o.reshape(b, s, h * dv) @ w["o"]
+
+    with caplog.at_level("INFO"):
+        forms = {"kept": remat_layer(layer, 1), "bare": jax.checkpoint(layer),
+                 "none": layer}
+        grads = {name: jax.grad(lambda w, x, f=f: jnp.sum(f(w, x) ** 2),
+                                argnums=(0, 1)) for name, f in forms.items()}
+        calls = {name: _kernel_calls(jax.make_jaxpr(g)(w, x).jaxpr)
+                 for name, g in grads.items()}
+    assert "remat_layers: 1 layers recomputed" in caplog.text
+    assert caplog.text.count("a layer keeps flash_") == (2 if flash else 0)
+
+    def saved(form):
+        print_saved_residuals(forms[form], w, x)
+        return capsys.readouterr().out.splitlines()
+
+    # beyond w and x: the two kept (a kept value that the forward also reads
+    # is listed as the no-op reduce_precision jax.checkpoint puts on it)
+    extra = saved("kept")[len(saved("bare")):]
+    if flash:
+        assert calls["kept"] == calls["none"] == {fwd: 1, bwd: 1}
+        assert calls["bare"] == {fwd: 2, bwd: 1}
+        assert len(extra) == 2 and "named 'flash_lse'" in extra[1], extra
+        assert extra[0].startswith(f"f32[{b * h},{s},{dv}] " if fwd.endswith(
+            "kernel") else f"f32[{b},{s},{h * dv}] "), extra
+    else:
+        assert calls == {"kept": {}, "bare": {}, "none": {}}
+        assert extra == [] and len(saved("kept")) == 5  # w's four and x
+    got = {name: jax.jit(g)(w, x) for name, g in grads.items()}
+    for other in ("bare", "none"):
+        for a, b_ in zip(jax.tree.leaves(got["kept"]),
+                         jax.tree.leaves(got[other])):
+            assert jnp.array_equal(a, b_), other
