@@ -4,10 +4,15 @@ it: NVIDIA Nemotron-H / Nemotron-3 hybrids): pre-norm residual layers
 by a letter of ``cfg.hybrid_override_pattern``:
 
 - ``M`` — Mamba-2 (:mod:`..ops.mamba2`);
-- ``*`` — causal grouped-query attention, no bias, no positional encoding
-  (the state-space layers carry position; ``rope_theta`` is carried by the
-  source's config and unused), through :func:`..ops.attention.mha_apply` and
-  so through the Pallas flash kernels where ``cfg.flash_for`` says so;
+- ``C`` — a gated short convolution (:mod:`..ops.shortconv`; LFM2's ``conv``
+  operator);
+- ``*`` — causal grouped-query attention, no bias, through
+  :func:`..ops.attention.mha_apply` and so through the Pallas flash kernels
+  where ``cfg.flash_for`` says so. As Nemotron-H has it, no positional
+  encoding (the state-space layers carry position; ``rope_theta`` is carried
+  by the source's config and unused); as LFM2 has it, an RMSNorm over every
+  query and key head (``cfg.qk_layernorm``: the leaves are there or not)
+  and then RoPE in split halves (``cfg.attn_rope``);
 - ``L`` — causal multi-head latent attention (:func:`..ops.attention.
   mla_apply`): low-rank query and key-value paths, RoPE on the
   ``qk_rope_head_dim`` columns only, scores over ``qk_nope_head_dim +
@@ -15,16 +20,18 @@ by a letter of ``cfg.hybrid_override_pattern``:
   kernels at the two widths;
 - ``-`` — a dense MLP of width ``cfg.ffn_dim`` (:func:`..ops.experts.
   mlp_apply`);
-- ``E`` — routed and shared experts (:mod:`..ops.experts`), as the
-  expert-parallel rank that holds ``cfg.held_experts`` computes them.
+- ``E`` — routed experts, and a shared expert where
+  ``cfg.moe_shared_expert_intermediate_size`` is not 0 (:mod:`..ops.experts`),
+  as the expert-parallel rank that holds ``cfg.held_experts`` computes them.
 
 ``-`` and ``E`` take the form ``cfg.mlp_hidden_act`` names: ``relu2``
 (Nemotron-H) or the gated ``silu``. A DeepSeek-style block — two pre-norm
 residual sublayers, attention then FFN — is two letters: ``L-`` a leading
-dense layer, ``LE`` an expert layer.
+dense layer, ``LE`` an expert layer; an LFM2 block likewise (``C-``, ``CE``,
+``*E``).
 
-Parameters: ``layers`` is ``{"mamba": .., "attn": .., "mla": .., "mlp": ..,
-"moe": ..}`` (the kinds the pattern has), each the
+Parameters: ``layers`` is ``{"mamba": .., "shortconv": .., "attn": ..,
+"mla": .., "mlp": .., "moe": ..}`` (the kinds the pattern has), each the
 layers of one kind stacked on axis 0 in pattern order; the stack is walked in
 pattern order as straight-line code (layers of different kinds share no
 scan), each layer under ``jax.checkpoint`` where ``cfg.remat_layers``
@@ -47,14 +54,17 @@ from typing import Dict, List, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..ops.attention import mha_apply, mha_init, mla_apply, mla_init
+from ..ops.attention import (mha_apply, mha_init, mla_apply, mla_init,
+                             rope_frequencies)
 from ..ops.experts import experts_apply, experts_init, mlp_apply, mlp_init
 from ..ops.layers import remat_layer, rms_norm_apply, rms_norm_init
 from ..ops.mamba2 import mamba2_apply, mamba2_init
+from ..ops.shortconv import shortconv_apply, shortconv_init
 from ..utils.config import ModelConfig
 
 #: pattern letter -> the key of its stack under ``params["layers"]``
-KINDS = {"M": "mamba", "*": "attn", "L": "mla", "-": "mlp", "E": "moe"}
+KINDS = {"M": "mamba", "C": "shortconv", "*": "attn", "L": "mla",
+         "-": "mlp", "E": "moe"}
 #: leaves that stay in the storage dtype under mixed precision: the
 #: recurrence's decay parameters and the whole router are float32 whatever
 #: ``cfg.dtype`` is
@@ -74,7 +84,17 @@ def nemotron_h_config(name: str = "stage", **overrides) -> ModelConfig:
     of gated experts) at published widths as one rank of 32-way expert
     parallelism holds them (8 of 256 experts, an eighth of the vocabulary:
     622 M parameters; ``benchmark/configs/joyai-llm-flash.json``).
-    ``joyai-debug``: its kinds of layer at toy widths."""
+    ``joyai-debug``: its kinds of layer at toy widths. ``lfm2-stage``: layers
+    0 and 2-6 of LFM2-8B-A1B (gated short convolutions and grouped-query
+    attention with q/k norms and RoPE, over a dense gated MLP and then five
+    layers of gated experts without a shared expert) at published widths as
+    one rank of 4-way expert parallelism holds them (8 of 32 experts, a
+    quarter of the vocabulary: 640 M parameters;
+    ``benchmark/configs/lfm2-8b-a1b.json``). ``lfm2-debug``: its kinds of
+    layer at toy widths."""
+    lfm2 = dict(mlp_hidden_act="silu", moe_shared_expert_intermediate_size=0,
+                routed_scaling_factor=1.0, router_norm_eps=1e-6,
+                rope_theta=1e6, qk_layernorm=True, attn_rope=True)
     sizes = {
         "stage": dict(dim=2688, n_heads=32, n_kv_heads=2,
                       head_dim_override=128, vocab_size=16384,
@@ -98,6 +118,16 @@ def nemotron_h_config(name: str = "stage", **overrides) -> ModelConfig:
                             q_lora_rank=48, kv_lora_rank=32,
                             qk_nope_head_dim=16, qk_rope_head_dim=8,
                             v_head_dim=16),
+        "lfm2-stage": dict(lfm2, dim=2048, n_heads=32, n_kv_heads=8,
+                           vocab_size=16384, max_seq_len=128000, ffn_dim=7168,
+                           hybrid_override_pattern="C-*E" + "CE" * 3 + "*E",
+                           n_routed_experts=32, experts_held=tuple(range(8)),
+                           num_experts_per_tok=4, moe_intermediate_size=1792),
+        "lfm2-debug": dict(lfm2, dim=64, n_heads=4, n_kv_heads=2,
+                           vocab_size=256, max_seq_len=4096, ffn_dim=96,
+                           hybrid_override_pattern="C-*ECE",
+                           n_routed_experts=8, experts_held=(0, 1, 2, 3),
+                           num_experts_per_tok=2, moe_intermediate_size=32),
         "debug": dict(dim=64, n_heads=4, n_kv_heads=2, head_dim_override=16,
                       vocab_size=256, max_seq_len=4096,
                       hybrid_override_pattern="MEM*E", mamba_num_heads=8,
@@ -135,10 +165,13 @@ def mixer_init(key: jax.Array, cfg: ModelConfig, kind: str) -> Dict:
             key, cfg.dim, cfg.mamba_num_heads, cfg.mamba_head_dim,
             cfg.n_groups, cfg.ssm_state_size, cfg.conv_kernel,
             cfg.time_step_min, cfg.time_step_max, cfg.time_step_floor)}
+    if kind == "shortconv":
+        return {"norm": norm, **shortconv_init(key, cfg.dim, cfg.conv_L_cache,
+                                               cfg.conv_bias)}
     if kind == "attn":
         return {"norm": norm, "attn": mha_init(
             key, cfg.dim, cfg.n_heads, cfg.n_kv_heads, bias=False,
-            head_dim=cfg.head_dim)}
+            head_dim=cfg.head_dim, qk_norm=cfg.qk_layernorm)}
     if kind == "mla":
         return {"norm": norm, "attn": mla_init(
             key, cfg.dim, cfg.n_heads, cfg.q_lora_rank, cfg.kv_lora_rank,
@@ -180,8 +213,13 @@ def mixer(cfg: ModelConfig, kind: str, params: Dict, x: jax.Array):
         return mamba2_apply(
             params, x, cfg.mamba_num_heads, cfg.mamba_head_dim, cfg.n_groups,
             cfg.ssm_state_size, cfg.chunk_size, cfg.rms_eps), None
+    if kind == "shortconv":
+        return shortconv_apply(params, x), None
     if kind == "attn":
+        angles = (rope_frequencies(cfg.head_dim, x.shape[1], cfg.rope_theta)
+                  if cfg.attn_rope else None)
         return mha_apply(params["attn"], x, x, cfg.n_heads, causal=True,
+                         rope_angles=angles, norm_eps=cfg.rms_eps,
                          flash=cfg.flash_for(True, x.shape[1])), None
     if kind == "mla":
         return mla_apply(params["attn"], x, cfg.n_heads, cfg.qk_rope_head_dim,
@@ -193,14 +231,16 @@ def mixer(cfg: ModelConfig, kind: str, params: Dict, x: jax.Array):
         b, t, d = x.shape
         out, counts = experts_apply(
             params, x.reshape(b * t, d), cfg.held_experts,
-            cfg.num_experts_per_tok, cfg.routed_scaling_factor)
+            cfg.num_experts_per_tok, cfg.routed_scaling_factor,
+            cfg.router_norm_eps)
         return out.reshape(b, t, d), counts
     raise ValueError(f"unknown layer kind {kind!r}")
 
 
 #: the profiler region of a layer's norm and mixer, by kind
-SCOPES = {"mamba": "model/ssm", "attn": "model/attn", "mla": "model/attn",
-          "mlp": "model/mlp", "moe": "model/moe"}
+SCOPES = {"mamba": "model/ssm", "shortconv": "model/shortconv",
+          "attn": "model/attn", "mla": "model/attn", "mlp": "model/mlp",
+          "moe": "model/moe"}
 
 
 def mixer_apply(cfg: ModelConfig, kind: str, params: Dict, h: jax.Array):
@@ -254,8 +294,8 @@ def check_mesh(cfg: ModelConfig, mesh) -> None:
     for axis, what in (("model", "tensor-parallel"), ("seq", "sequence-"
                        "parallel"), ("expert", "expert-parallel exchange of")):
         if mesh.shape.get(axis, 1) > 1:
-            missing.append(f"{what} Mamba-2, latent-attention and expert "
-                           f"layers ('{axis}' axis)")
+            missing.append(f"{what} Mamba-2, short-convolution, latent-"
+                           f"attention and expert layers ('{axis}' axis)")
     if missing:
         raise NotImplementedError(
             "arch='nemotron_h' runs on one pipeline stage; not written: "
@@ -300,6 +340,7 @@ def not_served(what: str, cfg: ModelConfig) -> None:
             f"{what}: arch='nemotron_h' is not written for generation — a "
             "decode step of a Mamba-2 layer needs its convolution window and "
             "state-space state cached beside the attention layers' keys and "
-            "values, a latent-attention layer its compressed key-value "
+            "values, a short-convolution layer its window of gated inputs, "
+            "a latent-attention layer its compressed key-value "
             "latent and shared rotary key (and the absorbed products that "
             "read them), and an expert layer a decode path")

@@ -6,8 +6,10 @@ separate q/k/v weight leaves so stage-stacking and tensor-parallel sharding
 stay natural; the torch-parity test splits torch's packed ``in_proj_weight``
 into these leaves.
 
-Supports grouped-query attention (n_kv_heads < n_heads) and an optional RoPE
-rotation for the Llama family. Below it, multi-head latent attention in its
+Supports grouped-query attention (n_kv_heads < n_heads), an optional RoPE
+rotation (the Llama family; the patterned stack's attention where
+``cfg.attn_rope``) and an optional RMSNorm over every query and key head
+before it (LFM2). Below it, multi-head latent attention in its
 training form (:func:`mla_apply`: low-rank query and key-value paths, RoPE in
 interleaved pairs on the rotary columns only, query/key heads wider than
 value heads).
@@ -26,21 +28,28 @@ from .layers import (dropout_apply, linear_init, linear_apply,
 
 def mha_init(key: jax.Array, dim: int, n_heads: int, n_kv_heads: Optional[int] = None,
              bias: bool = True, o_bias: Optional[bool] = None,
-             head_dim: Optional[int] = None) -> Dict:
+             head_dim: Optional[int] = None, qk_norm: bool = False) -> Dict:
     """``bias`` covers q/k/v; ``o_bias`` the output projection (defaults to
     ``bias`` — Qwen2-family blocks set bias=True, o_bias=False).
     ``head_dim`` decouples per-head width from ``dim // n_heads``
-    (Gemma-family blocks)."""
+    (Gemma-family blocks). ``qk_norm`` adds ``q_layernorm`` and
+    ``k_layernorm`` (LFM2's names): one RMSNorm scale over the ``head_dim``
+    columns, shared by every query head, and one by every key head, which
+    :func:`qkv_project` applies where the leaves are."""
     n_kv_heads = n_kv_heads or n_heads
     head_dim = head_dim or dim // n_heads
     kq, kk, kv, ko = jax.random.split(key, 4)
-    return {
+    params = {
         "q": linear_init(kq, dim, n_heads * head_dim, bias=bias),
         "k": linear_init(kk, dim, n_kv_heads * head_dim, bias=bias),
         "v": linear_init(kv, dim, n_kv_heads * head_dim, bias=bias),
         "o": linear_init(ko, n_heads * head_dim, dim,
                          bias=bias if o_bias is None else o_bias),
     }
+    if qk_norm:
+        params["q_layernorm"] = rms_norm_init(head_dim)
+        params["k_layernorm"] = rms_norm_init(head_dim)
+    return params
 
 
 def _split_heads(x: jax.Array, n_heads: int) -> jax.Array:
@@ -144,9 +153,11 @@ def scaled_dot_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
 def qkv_project(params: Dict, q_in: jax.Array, kv_in: jax.Array, n_heads: int,
                 rope_angles: Optional[jax.Array] = None,
-                expand_gqa: bool = True):
+                expand_gqa: bool = True, norm_eps: float = 1e-5):
     """Shared attention prologue: linear q/k/v projections, head split,
-    optional RoPE, optional GQA expansion. Used by the dense path
+    the per-head q/k RMSNorm where the parameters hold one
+    (:func:`mha_init`'s ``qk_norm``; BEFORE the rotation, as the source
+    applies it), optional RoPE, optional GQA expansion. Used by the dense path
     (:func:`mha_apply`) and both sequence-parallel wrappers
     (``parallel.ring_attention`` / ``parallel.ulysses``) so the projection
     conventions cannot drift between them."""
@@ -155,6 +166,9 @@ def qkv_project(params: Dict, q_in: jax.Array, kv_in: jax.Array, n_heads: int,
     q = _split_heads(linear_apply(params["q"], q_in), n_heads)
     k = _split_heads(linear_apply(params["k"], kv_in), n_kv)
     v = _split_heads(linear_apply(params["v"], kv_in), n_kv)
+    if "q_layernorm" in params:
+        q = rms_norm_apply(params["q_layernorm"], q, norm_eps)
+        k = rms_norm_apply(params["k_layernorm"], k, norm_eps)
     if rope_angles is not None:
         q = apply_rope(q, rope_angles)
         k = apply_rope(k, rope_angles)
@@ -167,8 +181,11 @@ def mha_apply(params: Dict, q_in: jax.Array, kv_in: jax.Array, n_heads: int,
               causal: bool = False, rope_angles: Optional[jax.Array] = None,
               flash: bool = False, tp_axis: Optional[str] = None,
               window: Optional[int] = None, dropout_rate: float = 0.0,
-              dropout_rng=None, tp_size: int = 1) -> jax.Array:
+              dropout_rng=None, tp_size: int = 1,
+              norm_eps: float = 1e-5) -> jax.Array:
     """Attention: queries from ``q_in``, keys/values from ``kv_in`` (both [b, s, d]).
+    ``norm_eps`` is the q/k norms', where the parameters hold them
+    (:func:`qkv_project`).
 
     ``flash=True`` routes the core attention through the fused Pallas kernel
     (:mod:`.pallas_attention`) instead of dense XLA softmax-matmuls.
@@ -181,7 +198,8 @@ def mha_apply(params: Dict, q_in: jax.Array, kv_in: jax.Array, n_heads: int,
     """
     from .collectives import tp_attention_inputs, tp_output_projection
     q_in, kv_in = tp_attention_inputs(q_in, kv_in, tp_axis)
-    q, k, v = qkv_project(params, q_in, kv_in, n_heads, rope_angles)
+    q, k, v = qkv_project(params, q_in, kv_in, n_heads, rope_angles,
+                          norm_eps=norm_eps)
     if flash:
         if dropout_rng is not None and dropout_rate > 0.0:
             raise ValueError("flash attention does not support attention-prob "

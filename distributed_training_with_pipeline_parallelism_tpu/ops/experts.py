@@ -3,11 +3,13 @@
 The patterned stack's expert layer (``models/nemotron_h.py``; DeepSeek-V3-
 style routing, as the sources' ``config.json``s declare it): sigmoid scores
 over ALL ``n_routed_experts``, the ``top_k`` largest of ``score + bias``
-chosen (the bias is ``e_score_correction_bias``, a buffer that no gradient
-reaches), weights ``scale * s_e / (sum over the chosen of s + 1e-20)``, plus
-one shared expert that every token takes::
+chosen (the bias is ``e_score_correction_bias`` / LFM2's ``expert_bias``, a
+buffer that no gradient reaches), weights ``scale * s_e / (sum over the
+chosen of s + eps)`` (``eps`` the source's: ``ModelConfig.router_norm_eps``),
+plus, where the source has one (a ``shared`` leaf), one shared expert that
+every token takes::
 
-    out = sum_{e chosen and held here} w_e f_e(x)  +  f_shared(x)
+    out = sum_{e chosen and held here} w_e f_e(x)  [+  f_shared(x)]
 
 The expert function ``f`` comes in the two forms the benchmark's
 configurations have (``ModelConfig.mlp_hidden_act``): ``"relu2"``,
@@ -37,8 +39,9 @@ same whatever is routed.
 It pays for ``held`` expert passes a token where the routing asks for
 ``top_k * held / n_routed`` (8 against 0.375 in the benchmark's ``nemotron``
 cell: 95% of the gated columns are zeros; 8 against 0.25, 97%, in its
-``joyai-llm-flash`` cell). That is the price of the worst case, and the
-worst case is what one rank's share of training meets (PR 30, on the chip,
+``joyai-llm-flash`` cell; 8 against 1, 87.5%, in its ``lfm2-8b-a1b`` cell).
+That is the price of the worst case, and the worst case is what one rank's
+share of training meets (PR 30, on the chip,
 with ``lax.ragged_dot`` over a sorted buffer): on random tokens AdamW moved a
 layer's local assignments 7876 -> 12757 in 20 steps and the whole batch came
 to pick the same experts within ~30, so every buffer short of a row a token
@@ -96,13 +99,15 @@ def experts_init(key: jax.Array, dim: int, n_routed: int, n_held: int,
     if gated:
         experts["w3"] = jax.random.uniform(k3, (n_held, dim, width),
                                            minval=-b1, maxval=b1)
-    return {
+    params = {
         "router": {"w": jax.random.uniform(kr, (dim, n_routed), minval=-b1,
                                            maxval=b1),
                    "bias": jnp.zeros((n_routed,))},
         "experts": experts,
-        "shared": mlp_init((ku, kd, kg), dim, shared_width, gated),
     }
+    if shared_width:  # a source without a shared expert: no such leaf
+        params["shared"] = mlp_init((ku, kd, kg), dim, shared_width, gated)
+    return params
 
 
 def relu2(x: jax.Array) -> jax.Array:
@@ -125,17 +130,18 @@ def mlp_apply(params: Dict, x: jax.Array) -> jax.Array:
     return linear_apply(params["down"], h)
 
 
-def route(router: Dict, x: jax.Array, top_k: int, scale: float):
+def route(router: Dict, x: jax.Array, top_k: int, scale: float,
+          norm_eps: float = 1e-20):
     """``x`` [T, d] -> (ids [T, k] of the chosen experts, weights [T, k]),
     float32. The weights are normalised over all ``k`` chosen, wherever they
-    live."""
+    live, their sum guarded by the source's ``norm_eps``."""
     logits = jnp.dot(x.astype(jnp.float32), router["w"].astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
     scores = jax.nn.sigmoid(logits)
     bias = jax.lax.stop_gradient(router["bias"].astype(jnp.float32))
     _, ids = jax.lax.top_k(scores + bias, top_k)
     chosen = jnp.take_along_axis(scores, ids, axis=-1)
-    weights = scale * chosen / (chosen.sum(-1, keepdims=True) + 1e-20)
+    weights = scale * chosen / (chosen.sum(-1, keepdims=True) + norm_eps)
     return ids, weights
 
 
@@ -156,14 +162,17 @@ def held_experts(experts: Dict, x: jax.Array, gate: jax.Array) -> jax.Array:
 
 
 def experts_apply(params: Dict, x: jax.Array, held: Sequence[int],
-                  top_k: int, scale: float):
+                  top_k: int, scale: float, norm_eps: float = 1e-20):
     """``x`` [T, d] (already normed) -> (out [T, d], the assignments each
     held expert got [held]).
 
     ``held``: static ids of the experts whose weights ``params["experts"]``
-    stacks, in that order."""
-    ids, weights = route(params["router"], x, top_k, scale)
+    stacks, in that order. The shared expert is added where the parameters
+    hold one."""
+    ids, weights = route(params["router"], x, top_k, scale, norm_eps)
     chose = ids[:, :, None] == jnp.asarray(held, ids.dtype)    # [T, k, held]
     gate = jnp.where(chose, weights[:, :, None], 0.0).sum(1)
-    routed = held_experts(params["experts"], x, gate)
-    return routed + mlp_apply(params["shared"], x), chose.sum((0, 1))
+    out = held_experts(params["experts"], x, gate)
+    if "shared" in params:
+        out = out + mlp_apply(params["shared"], x)
+    return out, chose.sum((0, 1))

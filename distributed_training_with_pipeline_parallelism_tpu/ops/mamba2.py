@@ -27,7 +27,7 @@ mixer is the caller's ``model/ssm``.
 from __future__ import annotations
 
 import math
-from typing import Dict
+from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -66,14 +66,17 @@ def mamba2_init(key: jax.Array, dim: int, n_heads: int, head_dim: int,
     }
 
 
-def causal_conv1d(x: jax.Array, w: jax.Array, b: jax.Array) -> jax.Array:
+def causal_conv1d(x: jax.Array, w: jax.Array,
+                  b: Optional[jax.Array] = None) -> jax.Array:
     """Depthwise causal convolution over time: ``y_t = b + sum_i w[i] *
-    x_{t-(k-1)+i}`` with ``x`` [B, T, C], ``w`` [k, C]; float32 sums."""
+    x_{t-(k-1)+i}`` with ``x`` [B, T, C], ``w`` [k, C]; float32 sums. ``b``
+    [C] where the convolution has a bias (Mamba-2's; the short-convolution
+    mixer of :mod:`.shortconv` has none)."""
     k, t = w.shape[0], x.shape[1]
     padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0))).astype(jnp.float32)
     w = w.astype(jnp.float32)
     y = sum(w[i] * padded[:, i:i + t] for i in range(k))
-    return y + b.astype(jnp.float32)
+    return y if b is None else y + b.astype(jnp.float32)
 
 
 @jax.named_scope("model/ssm_scan")

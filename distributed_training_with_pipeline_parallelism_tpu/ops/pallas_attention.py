@@ -581,11 +581,16 @@ def _bwd_vmem(s_pad: int, dh: int, dv: int, itemsize: int) -> dict:
     (dh * (itemsize + 4) + dv * itemsize)`` bytes, 16 MiB at s = 8192,
     dh = dv = 128 in bf16 (22 MiB at 192 and 128) — with the blocks and
     the row statistics, over the default scoped limit, and the compiler
-    refuses (seen compiling the nemotron_h step for a described v5e). Where
+    refuses (seen compiling the nemotron_h step for a described v5e). A
+    width under 128 counts as 128: VMEM tiles the minor dimension to 128
+    lanes, so heads of 64 at s = 8192 take the 16 MiB heads of 128 take
+    (refused at 16.50 MiB of 16 when counted as 64; compiled for a described
+    v5e, PR 36). Where
     the residency passes three quarters of the default the limit is asked
     for explicitly, half as much again (a v5e core has 128 MiB); everywhere
     else — every shape that compiled before — nothing is passed and the
     program is the one it was."""
+    dh, dv = max(dh, 128), max(dv, 128)
     resident = 2 * s_pad * (dh * (itemsize + 4) + dv * itemsize)
     if resident <= 0.75 * _DEFAULT_SCOPED_VMEM_BYTES:
         return {}

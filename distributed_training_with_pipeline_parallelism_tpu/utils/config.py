@@ -30,9 +30,11 @@ class ModelConfig:
       - "llama": pre-RMSNorm, causal self-attn with RoPE, SwiGLU MLP, no biases,
         tied-free output head.
       - "nemotron_h": pre-RMSNorm residual layers of ONE mixer each, chosen
-        per layer by ``hybrid_override_pattern`` (``M`` Mamba-2, ``*`` causal
-        GQA attention without positions, ``L`` latent attention with RoPE,
-        ``-`` a dense MLP, ``E`` routed + shared experts), as
+        per layer by ``hybrid_override_pattern`` (``M`` Mamba-2, ``C`` a
+        gated short convolution, ``*`` causal GQA attention — without
+        positions, or with RoPE and per-head q/k norms —, ``L`` latent
+        attention with RoPE, ``-`` a dense MLP, ``E`` routed experts, with a
+        shared expert or without), as
         one expert-parallel rank holds it (``experts_held``). One pipeline
         stage without tensor/sequence/fsdp axes; training and eval only
         (``models/nemotron_h.py``).
@@ -142,7 +144,7 @@ class ModelConfig:
     n_routed_experts: int = 128  # the router's width, whatever is held here
     num_experts_per_tok: int = 6
     moe_intermediate_size: int = 1856
-    moe_shared_expert_intermediate_size: int = 3712
+    moe_shared_expert_intermediate_size: int = 3712  # 0: no shared expert
     routed_scaling_factor: float = 2.5
     # The ids (of ``n_routed_experts``) whose weights this rank holds; None =
     # all. A token's weights are normalised over ALL its chosen experts and
@@ -160,6 +162,21 @@ class ModelConfig:
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
+    # The ``C`` layers (LFM2's gated short convolution), under the source's
+    # names: taps a channel, and whether the convolution and both
+    # projections carry a bias.
+    conv_L_cache: int = 3
+    conv_bias: bool = False
+    # The ``*`` layers as LFM2 has them: an RMSNorm (``rms_eps``) over the
+    # columns of every query head and every key head (the source's modules
+    # ``q_layernorm`` / ``k_layernorm``), then RoPE in split halves at
+    # ``rope_theta``, no scaling. Both off: Nemotron-H's attention, which
+    # has neither.
+    qk_layernorm: bool = False
+    attn_rope: bool = False
+    # What guards the sum a token's routing weights are normalised by
+    # (DeepSeek-V3-style sources 1e-20, LFM2's code 1e-6).
+    router_norm_eps: float = 1e-20
 
     def __post_init__(self):
         if self.dim % self.n_heads != 0:
@@ -222,13 +239,23 @@ class ModelConfig:
         pattern = self.hybrid_override_pattern
         if not pattern:
             raise ValueError("arch='nemotron_h' needs hybrid_override_pattern "
-                             "(one of 'M', '*', 'L', '-', 'E' a layer)")
-        unknown = sorted(set(pattern) - set("M*L-E"))
+                             "(one of 'M', 'C', '*', 'L', '-', 'E' a layer)")
+        unknown = sorted(set(pattern) - set("MC*L-E"))
         if unknown:
             raise ValueError(
                 f"hybrid_override_pattern {pattern!r}: unknown layer kind(s) "
-                f"{unknown}; 'M' is Mamba-2, '*' attention, 'L' latent "
-                "attention, '-' a dense MLP, 'E' experts")
+                f"{unknown}; 'M' is Mamba-2, 'C' a gated short convolution, "
+                "'*' attention, 'L' latent attention, '-' a dense MLP, 'E' "
+                "experts")
+        if self.conv_L_cache < 1:
+            raise ValueError(f"conv_L_cache={self.conv_L_cache} must be >= 1")
+        if self.attn_rope and self.head_dim % 2:
+            raise ValueError(f"attn_rope: head_dim={self.head_dim} must be "
+                             "even: RoPE turns pairs")
+        if self.moe_shared_expert_intermediate_size < 0:
+            raise ValueError("moe_shared_expert_intermediate_size="
+                             f"{self.moe_shared_expert_intermediate_size} "
+                             "must be >= 0 (0: no shared expert)")
         if self.mlp_hidden_act not in ("relu2", "silu"):
             raise ValueError(f"mlp_hidden_act={self.mlp_hidden_act!r} must "
                              "be 'relu2' or 'silu' (gated)")

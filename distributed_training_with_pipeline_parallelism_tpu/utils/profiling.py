@@ -81,8 +81,9 @@ def annotated_steps(steps: Iterable[int],
 #: regions too, wherever no name of this list is further in.
 REGIONS = ("model/embed", "model/layers", "model/attn", "model/mlp",
            "model/head_loss", "train/optimizer")
-#: The patterned stack's own five, beside ``model/attn`` for its attention
-#: layers and ``model/mlp`` for its dense MLP, disjoint by the
+#: The patterned stack's own six, beside ``model/attn`` for its attention
+#: layers (an LFM2-style layer's q/k norms and rotation inside it) and
+#: ``model/mlp`` for its dense MLP, disjoint by the
 #: innermost-wins rule: ``model/ssm``
 #: (``models/nemotron_h.py``: a Mamba-2 mixer but its scan — norm, both
 #: projections, convolution, gate, group norm), ``model/ssm_scan``
@@ -92,10 +93,12 @@ REGIONS = ("model/embed", "model/layers", "model/attn", "model/mlp",
 #: ``model/mla_latent`` (``ops/attention.py:mla_project``, inside a latent-
 #: attention layer's ``model/attn``: both down-projections, the latent
 #: norms, both up-projections, RoPE, building ``k`` — everything between the
-#: normed input and the attention core's operands). Kept apart from :data:`REGIONS`, which every dense step names
+#: normed input and the attention core's operands) and ``model/shortconv``
+#: (a gated short-convolution layer whole: norm, both projections, both
+#: gates, the convolution). Kept apart from :data:`REGIONS`, which every dense step names
 #: whole (``benchmark/tests/test_bench_scopes.py`` holds it to that).
 HYBRID_REGIONS = ("model/ssm", "model/ssm_scan", "model/moe",
-                  "model/moe_experts", "model/mla_latent")
+                  "model/moe_experts", "model/mla_latent", "model/shortconv")
 UNSCOPED = "unscoped"
 
 #: In this order, the first mark an op_name holds gives its phase. JAX writes

@@ -29,10 +29,11 @@ def main(argv=None):
     ap.add_argument("--model", default="gpt2-small",
                     help="gpt2-{small,medium,large,xl}, llama2-7b, llama3-8b, "
                          "llama-debug, nemotron-h-{stage,debug,joyai-stage,"
-                         "joyai-debug} (a patterned "
-                         "stack of Mamba-2 / attention / expert layers, or of "
-                         "latent attention / MLP / experts: --pipe 1, no "
-                         "--tp/--sp/--ep), or ref (the reference parity "
+                         "joyai-debug,lfm2-stage,lfm2-debug} (a patterned "
+                         "stack of Mamba-2 / attention / expert layers, of "
+                         "latent attention / MLP / experts, or of short "
+                         "convolutions / attention / MLP / experts: --pipe 1, "
+                         "no --tp/--sp/--ep), or ref (the reference parity "
                          "model)")
     ap.add_argument("--schedule", default="1F1B", choices=list(SCHEDULE_NAMES))
     ap.add_argument("--pipe", type=int, default=2)

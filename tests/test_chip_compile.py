@@ -94,8 +94,15 @@ def _bytes(compiled):
     # seq 256 (PR 33), strips of 128 / of 256 forward and 128 backward
     ((32, 256, 16, 64), None),
     ((16, 512, 16, 64), None),
+    # lfm2's attention layer: heads of 64 at 8192 rows run the classic
+    # kernels (the packed slab would be 32 MiB), and a 64-wide row takes 128
+    # lanes in VMEM: the backward's residency is that of heads of 128, and
+    # is asked for ('Scoped allocation with size 16.50M and limit 16.00M'
+    # while ``_bwd_vmem`` counted 64; PR 36)
+    ((2, 8192, 32, 64), None),
 ], ids=["medium", "xl-25-heads", "small", "ragged-1000", "window-1024",
-        "nemotron-8192x128", "medium-b32s256", "medium-b16s512"])
+        "nemotron-8192x128", "medium-b32s256", "medium-b16s512",
+        "lfm2-8192x64"])
 def test_flash_fwd_bwd_compiles(one_chip, mosaic, shape, window):
     x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
 
@@ -211,6 +218,35 @@ def test_joyai_llm_flash_layers_compile_at_published_widths(one_chip, mosaic,
 
     text = jax.jit(jax.grad(loss)).lower(params, h).compile().as_text()
     assert (text.count("tpu_custom_call") == 2) == (kind == "mla")
+
+
+def test_lfm2_shortconv_compiles_at_published_widths(one_chip):
+    """The new mixer of the benchmark's lfm2-8b-a1b configuration, the gated
+    short convolution (three taps over 2048 channels), forward and backward
+    at batch 2 x seq 8192, the cell's shapes. Its attention is
+    ``test_flash_fwd_bwd_compiles[lfm2-8192x64]``'s kernels behind norms and a
+    rotation, its gated experts and dense MLP the joyai configuration's forms
+    at other widths; the whole 12-sublayer step was compiled by hand with
+    :func:`lower_train_step` (12.503 GB with ``remat_layers``; the plain
+    program is refused at 17.42 GB; PR 36)."""
+    from distributed_training_with_pipeline_parallelism_tpu.models import (
+        nemotron_h)
+    cfg = nemotron_h.nemotron_h_config(
+        "lfm2-stage", dtype="bfloat16", param_dtype="float32")
+    h = jax.ShapeDtypeStruct((2, 8192, cfg.dim), jnp.bfloat16,
+                             sharding=one_chip)
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, jnp.bfloat16,
+                                       sharding=one_chip),
+        jax.eval_shape(lambda: nemotron_h.mixer_init(jax.random.key(0), cfg,
+                                                     "shortconv")))
+
+    def loss(p, x):
+        return nemotron_h.mixer_apply(cfg, "shortconv", p, x)[0].astype(
+            jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss)).lower(params, h).compile().as_text()
+    assert "tpu_custom_call" not in text and "model/shortconv" in text
 
 
 @pytest.mark.parametrize("wrap,calls", [("remat_layer", 2), ("bare", 3)])

@@ -63,15 +63,18 @@ def compiled(ref):
     """What several tests run on the float32 debug model, each jitted ONCE
     for the module (a function made inside a test compiles again in every
     case): the reference's loss with its gradients, its loss alone, its
-    routing counts, and the program's loss."""
+    routing counts, and the program's loss, alone and with its gradients."""
     cfg = ref.model_config(SIZES, {})
     return types.SimpleNamespace(
-        cfg=cfg, params=tfm.transformer_init(jax.random.key(0), cfg),
+        cfg=cfg, params=jax.jit(lambda k: tfm.transformer_init(k, cfg))(
+            jax.random.key(0)),  # one program, not one an op
         ref_grads=jax.jit(jax.value_and_grad(
             lambda p, x, y: ref.loss(p, x, y, SIZES))),
         ref_loss=jax.jit(lambda p, x, y: ref.loss(p, x, y, SIZES)),
         ref_counts=jax.jit(lambda p, x: ref.routing_counts(p, x, SIZES)),
-        loss=jax.jit(lambda p, x, y: tfm.transformer_loss(cfg, p, x, y)))
+        loss=jax.jit(lambda p, x, y: tfm.transformer_loss(cfg, p, x, y)),
+        grads=jax.jit(jax.value_and_grad(
+            lambda p, x, y: tfm.transformer_loss(cfg, p, x, y))))
 
 
 # bf16 over fp32 masters at this size (48 tokens, so little averages out):
@@ -87,8 +90,9 @@ def test_program_equals_reference_loss_and_every_gradient(
     cfg = ref.model_config(SIZES, numerics)
     params = compiled.params  # fp32 masters under either numerics
     x, y = batch(seq)
-    got, g_got = jax.jit(jax.value_and_grad(
-        lambda p: tfm.transformer_loss(cfg, p, x, y)))(params)
+    program = compiled.grads if not numerics else jax.jit(jax.value_and_grad(
+        lambda p, x, y: tfm.transformer_loss(cfg, p, x, y)))
+    got, g_got = program(params, x, y)
     want, g_want = compiled.ref_grads(params, x, y)
     assert abs(float(got) - float(want)) / float(want) < loss_tol
     apart = jax.tree.map(
@@ -135,19 +139,23 @@ def test_the_shares_add_up_to_the_uncut_layer(ref):
     it with every expert held."""
     whole = dict(SIZES, experts_held=list(range(16)))
     cfg = ref.model_config(whole, {})
-    p = nemotron_h.mixer_init(jax.random.key(2), cfg, "moe")
+    p = jax.jit(lambda k: nemotron_h.mixer_init(k, cfg, "moe"))(
+        jax.random.key(2))
     x = jax.random.normal(jax.random.key(3), (40, cfg.dim))
+    # one compiled program a side: the held ids are data (an array)
+    apply = jax.jit(experts.experts_apply, static_argnums=(3, 4))
     with jax.default_matmul_precision("highest"):
-        want = ref._experts(p, x, whole)
-        shared = ref._relu2(x @ p["shared"]["up"]["w"]) @ p["shared"]["down"]["w"]
+        want, shared = jax.jit(lambda p, x: (
+            ref._experts(p, x, whole),
+            ref._relu2(x @ p["shared"]["up"]["w"]) @ p["shared"]["down"]["w"])
+        )(p, x)
         total, seen = shared, 0
         for rank in range(4):
-            held = tuple(range(4 * rank, 4 * rank + 4))
             mine = dict(p, experts=jax.tree.map(lambda w: w[4 * rank:4 * rank + 4],
                                                 p["experts"]))
-            out, counts = experts.experts_apply(
-                mine, x, held, cfg.num_experts_per_tok,
-                cfg.routed_scaling_factor)
+            out, counts = apply(
+                mine, x, jnp.arange(4 * rank, 4 * rank + 4),
+                cfg.num_experts_per_tok, cfg.routed_scaling_factor)
             seen += int(counts.sum())
             total = total + (out - shared)
     assert seen == 40 * cfg.num_experts_per_tok  # every assignment, once
@@ -203,11 +211,12 @@ def base(**over):
 @pytest.mark.parametrize("over,error,match", [
     (dict(hybrid_override_pattern="MEMXE"), ValueError, r"unknown layer kind"),
     (dict(hybrid_override_pattern="MEM"), ValueError, "n_layers"),
+    (dict(conv_L_cache=0), ValueError, "conv_L_cache"),
     (dict(experts_held=(0, 16)), ValueError, "experts_held"),
     (dict(tie_embeddings=True), NotImplementedError, "tie_embeddings"),
     (dict(arch="gpt2", hybrid_override_pattern="MEM*E"), ValueError,
      "requires arch"),
-], ids=["letter", "length", "held", "tied", "other-arch"])
+], ids=["letter", "length", "taps", "held", "tied", "other-arch"])
 def test_configuration_errors_are_named(over, error, match):
     with pytest.raises(error, match=match):
         dtpp.ModelConfig(**base(**over))
@@ -267,9 +276,9 @@ def trained(ref):
     return cfg, before, after, float(loss), step, (x, y)
 
 
-def test_train_step_on_the_normal_path(trained, ref):
+def test_train_step_on_the_normal_path(trained, compiled):
     cfg, before, after, loss, _, (x, y) = trained
-    want = float(ref.loss(before, x, y, SIZES))
+    want = float(compiled.ref_loss(before, x, y))
     assert abs(loss - want) / want < 2e-3
     moved = jax.tree.map(lambda a, b: float(jnp.abs(a - b).max()),
                          before, after)
@@ -309,16 +318,13 @@ def test_fit_and_eval_on_the_normal_path():
     assert all(line.startswith("E0: max/mean") for _, line in said)
 
 
-def test_remat_layers_changes_no_number(ref):
+def test_remat_layers_changes_no_number(compiled):
     x, y = batch(24)
-    plain = ref.model_config(SIZES, {})
-    params = tfm.transformer_init(jax.random.key(0), plain)
-
-    def grads(cfg):
-        return jax.jit(jax.grad(
-            lambda p: tfm.transformer_loss(cfg, p, x, y)))(params)
-
-    a, b = grads(plain), grads(dataclasses.replace(plain, remat_layers=True))
+    params = compiled.params
+    remat = dataclasses.replace(compiled.cfg, remat_layers=True)
+    _, a = compiled.grads(params, x, y)
+    b = jax.jit(jax.grad(
+        lambda p: tfm.transformer_loss(remat, p, x, y)))(params)
     for u, v in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
         np.testing.assert_allclose(u, v, rtol=1e-5, atol=1e-7)
 
@@ -326,9 +332,10 @@ def test_remat_layers_changes_no_number(ref):
 def test_compiled_step_names_the_four_regions(trained):
     names = re.findall(r'op_name="([^"]*)"', trained[4].as_text())
     read = {classify(n) for n in names}
-    # the latent-attention region is tests/test_latent_attention.py's
+    # the latent-attention region is tests/test_latent_attention.py's, the
+    # short convolution's tests/test_lfm2_moe.py's
     for region in HYBRID_REGIONS + ("model/attn", "model/head_loss"):
-        if region != "model/mla_latent":
+        if region not in ("model/mla_latent", "model/shortconv"):
             assert any(r == region for _, r in read), region
     assert ("backward", "model/ssm_scan") in read
     assert ("recompute", "model/moe") in read  # remat_layers: a second run
@@ -343,6 +350,9 @@ def test_compiled_step_names_the_four_regions(trained):
      "model/moe_experts/dot_general", ("backward", "model/moe_experts")),
     ("jit(train_step)/jvp(model/layers)/checkpoint/model/moe/sort",
      ("forward", "model/moe")),
+    ("jit(train_step)/transpose(jvp(model/layers))/checkpoint/"
+     "rematted_computation/model/shortconv/mul", ("recompute",
+                                                  "model/shortconv")),
 ])
 def test_classify_reads_the_hybrid_regions(op_name, expected):
     assert classify(op_name) == expected
