@@ -48,7 +48,7 @@ BATCH, SEQ = 8, 1024  # every training phase: 8 sequences of 1024 tokens
 # then gpt2-medium's 8192 tokens as short rows, where 'auto' takes the
 # kernels since PR 33 (two strips of 128 at 256; two of 256 forward and four
 # of 128 backward at 512); last, latent attention's two widths (a fifth
-# number: the values' head width; PR 34) in blocks of 256 and in one block
+# number: the values' head width; PR 34) in blocks of 512 and in one block
 FLASH_SHAPES = ((BATCH, SEQ, 16, 64), (2, SEQ, 25, 64), (2, 1000, 12, 64),
                 (32, 256, 16, 64), (16, 512, 16, 64),
                 (1, 2048, 8, 192, 128), (2, SEQ, 8, 192, 128))

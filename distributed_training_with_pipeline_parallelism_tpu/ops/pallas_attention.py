@@ -411,6 +411,62 @@ def _pad_to_blocks(s: int, block_q: int, block_k: int) -> int:
     return -(-s // blk) * blk
 
 
+# The compiler gives a kernel 16 MiB of VMEM unless told otherwise.
+_DEFAULT_SCOPED_VMEM_BYTES = 16 * 1024 * 1024
+
+
+def _lanes(width: int) -> int:
+    """What a row ``width`` wide takes in VMEM, whose minor dimension is
+    tiled to 128 lanes: heads of 64 take what heads of 128 take, heads of
+    192 take 256 (the compiler's own count for a described v5e: 8.29 MiB of
+    forward operands at 8192 x 64 as at 8192 x 128, 12.41 at 192 | 128)."""
+    return -(-width // 128) * 128
+
+
+def _vmem(operands, body_bytes: int) -> dict:
+    """``pallas_call`` keywords for the scoped VMEM of a classic kernel,
+    forward or backward. ``operands``: ``(rows, width, itemsize)`` of every
+    block the pipeline keeps there, double-buffered; the whole-row ones (k
+    and v forward; q, do and the float32 dq accumulator backward) are what
+    grows with the sequence: 16 MiB backward at 8192 rows of 128 in bf16 and
+    28 at 192 | 128, 8 and 12 forward. ``body_bytes``: what the loop's body
+    holds beside them, its [block_q, block_k] float32 tiles (forward:
+    scores, probabilities in float32 and cast, the mask tile; backward: dp
+    and ds too) and its float32 carries.
+
+    Where the operands alone pass three quarters of the default limit the
+    kernel asks for operands plus body and a quarter more (a v5e core has
+    128 MiB). Everywhere else NOTHING is passed and the program is the one
+    it was: every row of one block (at most 1024 rows: 2 MiB of operands,
+    and a body without a loop, which streams its one tile: 4.2 and 5.8 MiB
+    wanted in all at 1000 rows), the forward at 8192 x 64 and 8192 x 128
+    (8.5 MiB), the windowed 4096 x 128 call. The quarter left under the
+    default is what the bodies take at blocks of 512 (3.1-3.9 MiB). What the
+    described v5e's compiler wanted in all, found by raising a too-small
+    limit until it compiled, and what this asks, MiB (PR 37):
+
+    =================  =============  =============  =============
+    8192 rows          64             128            192 | 128
+    =================  =============  =============  =============
+    forward, 256       9.36, -        9.36, -        13.55, 16.9
+    forward, 512       11.68, -       11.70, -       16.20, 21.2
+    backward, 256      17.73, 22.5    17.73, 22.5    30.35, 38.0
+    backward, 512      20.83, 28.1    20.65, 28.1    33.39, 44.1
+    =================  =============  =============  =============
+
+    Until PR 37 the forward asked for nothing (refused at 192 | 128 in
+    blocks of 512: 16.20 of 16) and the backward for 1.5 x its whole rows
+    with 192 counted as 192 lanes: 33.00 MiB, 0.39 short at 512 — a factor
+    that fitted blocks of 256 and did not follow them."""
+    held = 2 * sum(rows * _lanes(width) * size
+                   for rows, width, size in operands)
+    if held <= 0.75 * _DEFAULT_SCOPED_VMEM_BYTES:
+        return {}
+    from jax.experimental.pallas import tpu as pltpu
+    return {"compiler_params": pltpu.CompilerParams(
+        vmem_limit_bytes=int(1.25 * (held + body_bytes)))}
+
+
 def _flash_fwd(q: jax.Array, k: jax.Array, v: jax.Array, causal: bool,
                block_q: int, block_k: int,
                window: Optional[int] = None):
@@ -421,7 +477,7 @@ def _flash_fwd(q: jax.Array, k: jax.Array, v: jax.Array, causal: bool,
     (the lse stays padded — it only feeds the backward kernels, which slice
     consistently)."""
     bh, s, dh = q.shape
-    dv = v.shape[-1]
+    dv, size = v.shape[-1], q.dtype.itemsize
     scale = 1.0 / (dh ** 0.5)
     block_q = min(block_q, s)
     block_k = min(block_k, s)
@@ -447,6 +503,11 @@ def _flash_fwd(q: jax.Array, k: jax.Array, v: jax.Array, causal: bool,
         out_specs=(pl.BlockSpec((1, block_q, dv), lambda i, j: (i, j, 0)),
                    pl.BlockSpec((1, 1, block_q), lambda i, j: (i, 0, j))),
         interpret=_use_interpret(),
+        # q and o blocks, a whole row's k and v; the body's scores,
+        # probabilities (float32 and cast) and mask tile, and its accumulator
+        **_vmem([(block_q, dh, size), (s_pad, dh, size), (s_pad, dv, size),
+                 (block_q, dv, size)],
+                4 * (4 * block_q * block_k + block_q * _lanes(dv))),
     )(q, k, v)
     return out[:, :s, :], lse
 
@@ -570,42 +631,13 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
 
-# The compiler gives a kernel 16 MiB of VMEM unless told otherwise.
-_DEFAULT_SCOPED_VMEM_BYTES = 16 * 1024 * 1024
-
-
-def _bwd_vmem(s_pad: int, dh: int, dv: int, itemsize: int) -> dict:
-    """``pallas_call`` keywords for the classic backward's VMEM. The kernel
-    keeps a whole row's q (``dh`` wide) and do (``dv`` wide) and the float32
-    dq accumulator resident, each double-buffered by the pipeline: ``2 * s *
-    (dh * (itemsize + 4) + dv * itemsize)`` bytes, 16 MiB at s = 8192,
-    dh = dv = 128 in bf16 (22 MiB at 192 and 128) — with the blocks and
-    the row statistics, over the default scoped limit, and the compiler
-    refuses (seen compiling the nemotron_h step for a described v5e). A
-    width under 128 counts as 128: VMEM tiles the minor dimension to 128
-    lanes, so heads of 64 at s = 8192 take the 16 MiB heads of 128 take
-    (refused at 16.50 MiB of 16 when counted as 64; compiled for a described
-    v5e, PR 36). Where
-    the residency passes three quarters of the default the limit is asked
-    for explicitly, half as much again (a v5e core has 128 MiB); everywhere
-    else — every shape that compiled before — nothing is passed and the
-    program is the one it was."""
-    dh, dv = max(dh, 128), max(dv, 128)
-    resident = 2 * s_pad * (dh * (itemsize + 4) + dv * itemsize)
-    if resident <= 0.75 * _DEFAULT_SCOPED_VMEM_BYTES:
-        return {}
-    from jax.experimental.pallas import tpu as pltpu
-    return {"compiler_params": pltpu.CompilerParams(
-        vmem_limit_bytes=int(1.5 * resident))}
-
-
 def _flash_bwd(q, k, v, o, lse, g, causal, block_q, block_k, window):
     """Blockwise dq/dk/dv from saved (o, lse): the [s, s] matrix never
     materializes. Inputs unpadded, q and k [bh, s, dh], v, o and g [bh, s,
     dv]; lse [bh, 1, s_pad] (padded, log2-domain, from the forward). One
     fused kernel produces all three grads (see _flash_bwd_kernel)."""
     bh, s, dh = q.shape
-    dv = v.shape[-1]
+    dv, size = v.shape[-1], q.dtype.itemsize
     scale = 1.0 / (dh ** 0.5)
     block_q = min(block_q, s)
     block_k = min(block_k, s)
@@ -641,7 +673,13 @@ def _flash_bwd(q, k, v, o, lse, g, causal, block_q, block_k, window):
             pl.BlockSpec((1, block_k, dv), lambda i, j: (i, j, 0)),
         ),
         interpret=_use_interpret(),
-        **_bwd_vmem(s_pad, dh, dv, q.dtype.itemsize),
+        # a whole row's q, do and float32 dq, the k, v, dk and dv blocks;
+        # the body's five tiles (scores, probabilities, dp, ds, the mask)
+        # and its dk and dv accumulators
+        **_vmem([(s_pad, dh, size), (s_pad, dv, size), (s_pad, dh, 4)]
+                + 2 * [(block_k, dh, size), (block_k, dv, size)],
+                4 * (5 * block_q * block_k
+                     + block_k * (_lanes(dh) + _lanes(dv)))),
     )(q, k, v, g, lse, delta)
     # the deferred `scale` fold (see kernel docstring); XLA fuses it into
     # the cast + transpose that follow
@@ -935,7 +973,8 @@ _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
 def _auto_block(s: int) -> int:
-    """Default kernel block (v5e measurements, docs/performance.md).
+    """Default kernel block, from the row length alone (v5e measurements,
+    docs/performance.md).
 
     s <= 1024: ONE block covers the whole row — one grid step a row, no
     interior k loop with a `program_id` trip count (round 4 measured that
@@ -947,25 +986,43 @@ def _auto_block(s: int) -> int:
     958 us a call; chip, PR 31); where the row is a whole number of
     strips the kernels therefore cut the triangle STATICALLY inside the
     one block (:func:`_strip_rows`: forward 368 us, backward 605 us),
-    and a ragged row keeps the square. Beyond 1024 the
-    [bq, bk] f32 tiles exceed VMEM at block 1024 (the backward fails to
-    compile) and 512 measured up to ~20% (fwd) / ~34% (grad) faster per
-    row than 256; estimated time ~ padded_length / per-row-speed, so 256
+    and a ragged row keeps the square.
+
+    Beyond 1024 rows: 512, at every length. Blocks of 1024 x 1024 want more
+    VMEM than the kernels ask for (20.5 MiB forward at 8192 x 64) and 512
+    measured up to ~20% (fwd) / ~34% (grad) faster per row than 256 at
+    2048-4096 rows; estimated time ~ padded_length / per-row-speed, so 256
     wins only where its padding saving exceeds 512's ~1.2x per-row
-    advantage (s=1280: 1280 vs 1536/1.2 -> 256; s=2600: -> 512).
-    Where the PADDED block-512 row length reaches 8192, 256 is forced:
-    COMPOSED train-step programs (flash backward custom-calls next to
-    the weight-grad dots) crash the v5e compiler at block 512 with
-    8192-long rows — the isolated kernel compiles at any block, the
-    failure needs the surrounding fusion, and block 256 compiles
-    (round-5 bisection; s=7168 with 512 is fine). The check uses the
-    padded length because the kernels pad ragged rows up to a block
-    multiple, so s=7700 would compile the same crash-prone 8192-row
-    block-512 shape. Per-row speed is secondary to compiling at all."""
+    advantage (s=1280: 1280 vs 1536/1.2 -> 256; s=2600: -> 512). At 8192
+    rows the advantage is 2x (chip, PR 37: kernels alone, 2 x 8192 x 32
+    heads, bf16, device us a call forward / backward; the relative error of
+    out, dq, dk, dv against float32 attention is the same to three digits
+    in every column):
+
+    ===========  ===============  ===============  ===============
+    heads        256              512              1024 x 512
+    ===========  ===============  ===============  ===============
+    64           18 574 / 37 939  9 862 / 19 763   11 649 / 18 153
+    128          18 532 / 38 187  9 822 / 19 765   11 758 / 18 079
+    192 | 128    21 450 / 38 644  12 611 / 29 358  14 269 / 28 368
+    ===========  ===============  ===============  ===============
+
+    The kernels are bound by per-block and per-score vector work (heads of
+    64 take what heads of 128 take) and a row in blocks of 512 has a quarter
+    as many block pairs. Every width gains, so the rule does not read the
+    width; 1024 x 512 (unequal blocks: the masked body, no static diagonal)
+    wins 3-9% backward and loses 13-20% forward, 0.6-1.6% slower in sum.
+
+    Until PR 37 256 was FORCED where the padded row reached 8192, for two
+    walls: composed train steps (the backward call beside the weight-grad
+    dots) had crashed the v5e compiler at 512 x 8192 in round 5, and PR 34
+    saw 512 refused there at 192 | 128 for VMEM. Neither stands: the
+    second was two missing requests (:func:`_vmem`), and with them the
+    compiler takes the three composed 8k steps of the benchmark at 512
+    (12.503, 14.654 and 13.121 GB counted, as at 256;
+    ``tests/test_chip_compile.py -m slow``) and the chip runs them."""
     if s <= 1024:
         return 1024
-    if -(-s // 512) * 512 >= 8192:
-        return 256
     if -(-s // 256) * 256 * 1.2 <= -(-s // 512) * 512:
         return 256
     return 512
@@ -991,10 +1048,12 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     (Mosaic constraint; :func:`_auto_block`'s 256/512/1024 are always
     safe, and CPU interpret mode takes any block, which is what the
     small-block unit tests use). ``block_q``/``block_k`` default to :func:`_auto_block`
-    (the whole row up to 1024; beyond it 512, or 256 where it avoids a dead
-    padding block); both kernels keep one [block_q, block_k] f32 tile plus
-    the full per-(batch, head) K/V in VMEM, so block size trades tile-reuse
-    against grid parallelism, not memory. Where one block spans a plain
+    (the whole row up to 1024; beyond it 512, at 8192 rows too since PR 37,
+    or 256 where it avoids a dead padding block); both kernels keep their
+    [block_q, block_k] f32 tiles plus the full per-(batch, head) K/V (the
+    backward: q, do and a float32 dq) in VMEM and ask for what that takes
+    where it passes the compiler's default (:func:`_vmem`), so block size
+    trades per-block overhead against grid parallelism. Where one block spans a plain
     causal row of a whole number of strips (every ``s`` <= 1024 that is a
     multiple of 128, from 256), both kernels leave the dead half of the
     square unformed (:func:`_strip_rows`, :func:`causal_strips`): nothing

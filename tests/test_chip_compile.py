@@ -81,50 +81,62 @@ def _bytes(compiled):
             "alias": ma.alias_size_in_bytes}
 
 
-@pytest.mark.parametrize("shape,window", [
-    ((8, 1024, 16, 64), None),    # gpt2-medium, the smoke's batch
-    ((2, 1024, 25, 64), None),    # gpt2-xl: odd head count, unpacked path
-    ((4, 1024, 12, 64), None),    # gpt2-small
-    ((2, 1000, 12, 64), None),    # ragged: one block spans the row
-    ((2, 4096, 32, 128), 1024),   # windowed, head_dim 128
+@pytest.mark.parametrize("shape,window,blocks", [
+    ((8, 1024, 16, 64), None, 1024),    # gpt2-medium, the smoke's batch
+    ((2, 1024, 25, 64), None, 1024),    # gpt2-xl: odd head count, unpacked path
+    ((4, 1024, 12, 64), None, 1024),    # gpt2-small
+    ((2, 1000, 12, 64), None, 1000),    # ragged: one block spans the row
+    ((2, 4096, 32, 128), 1024, 512),    # windowed, head_dim 128
     # nemotron_h's attention layer: a whole row's q, do and f32 dq pass the
-    # default 16 MiB of VMEM in the backward, which asks for more (PR 30)
-    ((2, 8192, 32, 128), None),
+    # default 16 MiB of VMEM in the backward, which asks for more (PR 30).
+    # In blocks of 512 since PR 37 (256 was forced from 8192 rows on): the
+    # compiler wants 11.70 MiB forward, under its default, and 20.65 backward
+    ((2, 8192, 32, 128), None, 512),
     # gpt2-medium's 8192 tokens as short rows: 'auto' takes the kernels from
     # seq 256 (PR 33), strips of 128 / of 256 forward and 128 backward
-    ((32, 256, 16, 64), None),
-    ((16, 512, 16, 64), None),
+    ((32, 256, 16, 64), None, 256),
+    ((16, 512, 16, 64), None, 512),
     # lfm2's attention layer: heads of 64 at 8192 rows run the classic
     # kernels (the packed slab would be 32 MiB), and a 64-wide row takes 128
     # lanes in VMEM: the backward's residency is that of heads of 128, and
     # is asked for ('Scoped allocation with size 16.50M and limit 16.00M'
-    # while ``_bwd_vmem`` counted 64; PR 36)
-    ((2, 8192, 32, 64), None),
+    # while the request counted 64; PR 36)
+    ((2, 8192, 32, 64), None, 512),
+    # a window at 8192 rows takes the same rule and the same requests (the
+    # masked body, no static diagonal: 12.36 MiB forward, 20.84 backward)
+    ((2, 8192, 32, 128), 1024, 512),
 ], ids=["medium", "xl-25-heads", "small", "ragged-1000", "window-1024",
         "nemotron-8192x128", "medium-b32s256", "medium-b16s512",
-        "lfm2-8192x64"])
-def test_flash_fwd_bwd_compiles(one_chip, mosaic, shape, window):
+        "lfm2-8192x64", "window-1024-8192x128"])
+def test_flash_fwd_bwd_compiles(one_chip, mosaic, caplog, shape, window,
+                                blocks):
     x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
 
     def loss(q, k, v):
         return pallas_attention.flash_attention(
             q, k, v, causal=True, window=window).astype(jnp.float32).sum()
 
-    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
-        x, x, x).compile().as_text()
+    with caplog.at_level("INFO"):
+        text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+            x, x, x).compile().as_text()
     assert text.count("tpu_custom_call") >= 2  # forward and backward kernels
+    assert f"blocks {blocks} x {blocks}," in caplog.text  # what the rule picks
 
 
-@pytest.mark.parametrize("seq", [8192, 1024],
-                         ids=["blocks-of-256", "one-block-strips"])
-def test_flash_two_widths_compile(one_chip, mosaic, seq):
+@pytest.mark.parametrize("seq,blocks", [(8192, 512), (1024, 1024)],
+                         ids=["blocks-of-512", "one-block-strips"])
+def test_flash_two_widths_compile(one_chip, mosaic, caplog, seq, blocks):
     """Latent attention's geometry, 32 heads of 192 for q and k and of 128
-    for v, at the benchmark cell's 8192 rows (the backward asks for 33 MiB
-    of VMEM there) and in one block: Mosaic takes the two widths as they
-    are. O and dV come out 128 wide and dQ, dK 192: nothing is padded to a
-    common width in HBM. (v padded to 192 through the equal-width kernels is
-    REFUSED at 8192 rows: the forward's resident k and v rows pass the
-    default 16 MiB; PR 34.)"""
+    for v, at the benchmark cell's 8192 rows and in one block: Mosaic takes
+    the two widths as they are. O and dV come out 128 wide and dQ, dK 192:
+    nothing is padded to a common width in HBM. At 8192 rows the blocks are
+    512 since PR 37, and BOTH kernels ask for VMEM: 192 takes 256 lanes
+    there, so the forward's k and v rows are 12 MiB and it wants 16.20 of
+    the default 16 ('Scoped allocation with size 16.20M and limit 16.00M'
+    while it asked for nothing), the backward 33.39 (33.00 was asked while
+    the request was a factor on the rows and did not count the tiles). (v
+    padded to 192 through the equal-width kernels is REFUSED at 8192 rows in
+    any block; PR 34.)"""
     q = jax.ShapeDtypeStruct((2, seq, 32, 192), jnp.bfloat16, sharding=one_chip)
     v = jax.ShapeDtypeStruct((2, seq, 32, 128), jnp.bfloat16, sharding=one_chip)
 
@@ -132,14 +144,17 @@ def test_flash_two_widths_compile(one_chip, mosaic, seq):
         return pallas_attention.flash_attention(
             q, k, v, causal=True).astype(jnp.float32).sum()
 
-    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
-        q, q, v).compile().as_text()
+    with caplog.at_level("INFO"):
+        text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+            q, q, v).compile().as_text()
+    assert (f"classic kernels, 2 x {seq} x 32 x 192 (values 128), "
+            f"blocks {blocks} x {blocks},") in caplog.text
     calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
     assert len(calls) == 2, calls
     fwd, bwd = sorted(calls, key=lambda line: "f32[64,%d,192]" % seq in line)
     assert f"(bf16[64,{seq},128]" in fwd            # O
-    assert (f"(f32[64,{seq},192]" in bwd and f"bf16[64,{seq},192]" in bwd
-            and f"bf16[64,{seq},128]" in bwd)       # dQ, dK, dV
+    assert (f"(f32[64,{seq},192]" in bwd and f"bf16[64,{seq},128]" in bwd
+            and f"bf16[64,{seq},192]" in bwd)       # dQ, dK, dV
 
 
 def test_fused_xent_compiles(one_chip, mosaic):
@@ -332,8 +347,37 @@ def test_gpt2_medium_train_step_fits_one_chip(topo, mosaic, batch, seq):
     assert [n for n in names if ".remat" in n] == []
 
 
+def _compile_8k_step(topo, preset):
+    """One of the benchmark's 8k cells as it runs — ``nemotron_h_config(preset)``
+    at published widths, bf16 over fp32 masters, AdamW, the flash kernels,
+    the fused cross-entropy, ``remat_layers``, batch 2 x seq 8192 in two
+    microbatches — compiled for the described chip: (parameter count, bytes
+    the compiler counts, calls of the forward and of the backward kernel,
+    the program's text)."""
+    from distributed_training_with_pipeline_parallelism_tpu.models.nemotron_h import (
+        nemotron_h_config)
+    cfg = nemotron_h_config(
+        preset, dtype="bfloat16", param_dtype="float32",
+        use_flash_attention=True, use_fused_xent=True, remat_layers=True)
+    mesh = make_mesh(n_pipe=1, devices=topo.devices[:1])
+    lowered, params = lower_train_step(
+        cfg, mesh, ScheduleConfig(name="1F1B", n_microbatches=2), 2, 8192)
+    compiled = lowered.compile()
+    b = _bytes(compiled)
+    assert b["alias"] > 0.9 * b["argument"], b  # params + moments in place
+    text = compiled.as_text()
+    kernels = []
+    for body in re.findall(r'"body":"([A-Za-z0-9+/=]+)"', text):
+        kernels += re.findall(rb"_flash_(?:fwd|bwd)_kernel",
+                              base64.b64decode(body))
+    return (sum(x.size for x in jax.tree.leaves(params)),
+            b["argument"] + b["output"] + b["temp"] - b["alias"],
+            (kernels.count(b"_flash_fwd_kernel"),
+             kernels.count(b"_flash_bwd_kernel")), text)
+
+
 @pytest.mark.slow
-def test_joyai_llm_flash_train_step_fits_one_chip(topo, mosaic):
+def test_joyai_llm_flash_train_step_fits_one_chip(topo, mosaic, caplog):
     """The benchmark cell ``joyai-llm-flash.train-b2s8192`` as it runs: the
     first eight layers at published widths (latent attention, a dense gated
     MLP, seven layers of gated experts; 622.0 M parameters), bf16 over fp32
@@ -345,36 +389,44 @@ def test_joyai_llm_flash_train_step_fits_one_chip(topo, mosaic):
     ``remat_layer`` keeps (PR 35). So each of the eight attention sublayers
     runs the forward kernel once and the backward once: 16 calls of the two
     kernels at the two widths, 24 while the forward ran again in every
-    backward. ``slow``: it takes the chip's compiler a minute on every core
-    the suite shares (and half of that is spent whatever the depth); tier 1
-    compiles each kind of sublayer at these shapes
+    backward; in blocks of 512 since PR 37 (the count does not move: the
+    blocks live in VMEM). ``slow``: it takes the chip's compiler a minute on
+    every core the suite shares (and half of that is spent whatever the
+    depth); tier 1 compiles each kind of sublayer at these shapes
     (``test_joyai_llm_flash_layers_compile_at_published_widths``) and the
     rematerialised attention sublayer
-    (``test_joyai_llm_flash_remat_layer_runs_the_forward_kernel_once``).
-    The ``nemotron`` step, compiled the same way by hand (PR 35): 14.654 GB
-    (14.517 before), one forward and one backward call."""
-    from distributed_training_with_pipeline_parallelism_tpu.models.nemotron_h import (
-        nemotron_h_config)
-    cfg = nemotron_h_config(
-        "joyai-stage", dtype="bfloat16", param_dtype="float32",
-        use_flash_attention=True, use_fused_xent=True, remat_layers=True)
-    mesh = make_mesh(n_pipe=1, devices=topo.devices[:1])
-    lowered, params = lower_train_step(
-        cfg, mesh, ScheduleConfig(name="1F1B", n_microbatches=2), 2, 8192)
-    assert sum(x.size for x in jax.tree.leaves(params)) == 621_989_632
-    compiled = lowered.compile()
-    b = _bytes(compiled)
-    assert b["alias"] > 0.9 * b["argument"], b  # params + moments in place
-    total = b["argument"] + b["output"] + b["temp"] - b["alias"]
-    assert 12.9e9 < total < 13.4e9 < HBM_BYTES, b  # 13.121 (PR 35)
-    text = compiled.as_text()
-    kernels = []
-    for body in re.findall(r'"body":"([A-Za-z0-9+/=]+)"', text):
-        kernels += re.findall(rb"_flash_(?:fwd|bwd)_kernel",
-                              base64.b64decode(body))
-    assert (kernels.count(b"_flash_fwd_kernel"),
-            kernels.count(b"_flash_bwd_kernel")) == (8, 8), kernels
+    (``test_joyai_llm_flash_remat_layer_runs_the_forward_kernel_once``)."""
+    with caplog.at_level("INFO"):
+        n_params, total, calls, text = _compile_8k_step(topo, "joyai-stage")
+    assert n_params == 621_989_632
+    assert 12.9e9 < total < 13.4e9 < HBM_BYTES, total  # 13.121 (PR 35, PR 37)
+    assert calls == (8, 8)
     assert "bf16[64,8192,192]" in text and "bf16[64,8192,128]" in text
+    assert "x 192 (values 128), blocks 512 x 512, no strips" in caplog.text
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("preset,gb,calls,shape", [
+    ("lfm2-stage", 12.503, (2, 2), "2 x 8192 x 32 x 64"),
+    ("stage", 14.654, (1, 1), "2 x 8192 x 32 x 128"),
+], ids=["lfm2", "nemotron"])
+def test_8k_train_steps_fit_one_chip_in_blocks_of_512(topo, mosaic, caplog,
+                                                      preset, gb, calls,
+                                                      shape):
+    """The cells ``lfm2-8b-a1b.train-b2s8192`` and
+    ``nemotron-twotower-30b-a3b.train-b2s8192`` as they run, COMPOSED: the
+    flash kernels in blocks of 512 at 8192 rows beside the weight-gradient
+    products. ``_auto_block`` forced 256 there until PR 37, because such a
+    composed step had crashed the v5e compiler at 512 in round 5 (an
+    earlier kernel, an earlier step); the compiler takes both now, and counts
+    what it counted at 256 to every digit. ``slow`` for the reason
+    ``test_joyai_llm_flash_train_step_fits_one_chip`` is: 35 and 40 s of
+    every core."""
+    with caplog.at_level("INFO"):
+        _, total, got, _ = _compile_8k_step(topo, preset)
+    assert abs(total / 1e9 - gb) < 0.05 and total < HBM_BYTES, total
+    assert got == calls
+    assert f"classic kernels, {shape}, blocks 512 x 512, no strips" in caplog.text
 
 
 def test_gpt2_xl_width_pipe4_rests_sharded(topo, mosaic):

@@ -333,19 +333,106 @@ def test_flash_causal_strips_count_and_log(caplog):
             "bwd strips of 128 rows, 36 of 64 tiles") in caplog.text
 
 
-def _kernel_calls(jaxpr, out=None):
-    """Kernel function name -> how many ``pallas_call``s of it a jaxpr holds,
-    at any depth (remat, custom_vjp, scan and jit bodies)."""
-    out = {} if out is None else out
+@pytest.mark.parametrize("s,want", [
+    (13, 1024), (256, 1024), (1000, 1024), (1024, 1024),  # one block a row
+    (1025, 256), (1280, 256),       # 256 saves a padding block of 512
+    (1281, 512), (2048, 512), (2600, 512), (7168, 512),
+    (7700, 512), (8192, 512),       # 256 was forced here until PR 37
+    (16384, 512),
+])
+def test_flash_auto_block_table(s, want):
+    """The block a call resolves to from its row length alone: the whole row
+    up to 1024, then 512 unless 256 pads the row by a sixth less. Rows whose
+    padded length reaches 8192 take the 512 every shorter row takes (chip,
+    PR 37: half the time of 256 a call, the same errors)."""
+    assert _auto_block(s) == want
+
+
+def _vmem_limits(shape, dv=None, window=None, **blocks):
+    """MiB of scoped VMEM each kernel of a causal ``flash_attention`` call
+    and its gradient asks for (None: the compiler's default), by kernel."""
+    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    v = jax.ShapeDtypeStruct(shape[:3] + (dv or shape[3],), jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda q, k, v: flash_attention(
+            q, k, v, causal=True, window=window, **blocks).astype(
+                jnp.float32).sum(), argnums=(0, 1, 2)))(q, q, v).jaxpr
+    out = {}
+    for name, params in _pallas_calls(jaxpr):
+        mosaic = (params["compiler_params"] or {}).get("mosaic_tpu")
+        limit = mosaic and mosaic.vmem_limit_bytes
+        out[name.removeprefix("_flash_").replace("_kernel", "")] = (
+            limit and round(limit / 2 ** 20, 1))
+    return out
+
+
+@pytest.mark.parametrize("shape,dv,window,block,want", [
+    # test_chip_compile.py::test_flash_fwd_bwd_compiles' shapes: what
+    # compiled with nothing asked before PR 37 is still asked nothing
+    ((8, 1024, 16, 64), None, None, None,
+     {"fwd_packed": None, "bwd_packed": None}),
+    ((2, 1024, 25, 64), None, None, None, {"fwd": None, "bwd": None}),
+    ((4, 1024, 12, 64), None, None, None,
+     {"fwd_packed": None, "bwd_packed": None}),
+    ((2, 1000, 12, 64), None, None, None,
+     {"fwd_packed": None, "bwd_packed": None}),
+    ((2, 1000, 25, 64), None, None, None, {"fwd": None, "bwd": None}),
+    ((2, 4096, 32, 128), None, 1024, None, {"fwd": None, "bwd": None}),
+    ((32, 256, 16, 64), None, None, None,
+     {"fwd_packed": None, "bwd_packed": None}),
+    ((16, 512, 16, 64), None, None, None,
+     {"fwd_packed": None, "bwd_packed": None}),
+    # 8192 rows in blocks of 512: the forward's k and v rows are 8 MiB of
+    # its 16, the backward's q, do and dq rows 16: it asked before (24 MiB)
+    ((2, 8192, 32, 128), None, None, None, {"fwd": None, "bwd": 28.1}),
+    ((2, 8192, 32, 64), None, None, None, {"fwd": None, "bwd": 28.1}),
+    ((2, 8192, 32, 128), None, 1024, None, {"fwd": None, "bwd": 28.1}),
+    # latent attention: 192 takes 256 lanes, the forward's rows are 12 MiB
+    # and it asks too; the backward's 28 MiB of rows and five tiles of 1 MiB.
+    # The request follows the blocks: the described v5e's compiler wants
+    # 30.35 MiB backward in blocks of 256 and 33.39 in blocks of 512, and a
+    # factor on the rows alone (33.00 until PR 37) fitted the first only
+    ((2, 8192, 32, 192), 128, None, None, {"fwd": 21.2, "bwd": 44.1}),
+    ((2, 8192, 32, 192), 128, None, 256, {"fwd": 16.9, "bwd": 38.0}),
+    ((2, 1024, 32, 192), 128, None, None, {"fwd": None, "bwd": None}),
+])
+def test_flash_vmem_requests(shape, dv, window, block, want):
+    """Both classic kernels ask for the scoped VMEM their operands and
+    their bodies' tiles need, where the operands pass three quarters of the
+    compiler's default, and for nothing anywhere else
+    (:func:`ops.pallas_attention._vmem`; the compiler's own needs are in its
+    docstring and held by ``test_chip_compile.py``)."""
+    assert _vmem_limits(shape, dv, window, block_q=block,
+                        block_k=block) == want
+
+
+def test_flash_blocks_of_512_match_dense():
+    """A multi-block row in the blocks the rule picks beyond 1280 rows (and,
+    since PR 37, at 8192): four blocks of 512 a row, the diagonal one peeled
+    statically, forward and all three gradients against dense attention."""
+    assert _auto_block(2048) == 512
+    _assert_matches_dense(*_qkvg(37, (1, 2048, 1, 16)))
+
+
+def _pallas_calls(jaxpr):
+    """Every ``pallas_call`` equation of a jaxpr, at any depth (remat,
+    custom_vjp, scan and jit bodies), as (kernel function name, params)."""
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "pallas_call":
-            name = eqn.params["jaxpr"].debug_info.func_name
-            out[name] = out.get(name, 0) + 1
+            yield eqn.params["jaxpr"].debug_info.func_name, eqn.params
         for value in eqn.params.values():
             for sub in value if isinstance(value, (list, tuple)) else [value]:
                 sub = getattr(sub, "jaxpr", sub)
                 if hasattr(sub, "eqns"):
-                    _kernel_calls(sub, out)
+                    yield from _pallas_calls(sub)
+
+
+def _kernel_calls(jaxpr):
+    """Kernel function name -> how many ``pallas_call``s of it a jaxpr
+    holds."""
+    out = {}
+    for name, _ in _pallas_calls(jaxpr):
+        out[name] = out.get(name, 0) + 1
     return out
 
 
