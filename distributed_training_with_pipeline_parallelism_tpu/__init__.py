@@ -9,6 +9,10 @@ schedules, expressed as single-program SPMD over a device mesh with
 Import alias convention: ``import distributed_training_with_pipeline_parallelism_tpu as dtpp``.
 """
 
+# First, before anything else of the package: the module stamps the clock at
+# its own top (before its ``import jax``), and ``import_done`` at the bottom of
+# this file closes the ``setup/import`` span (docs/observability.md §2).
+from .utils.profiling import import_done as _import_done
 from .utils.config import (MeshConfig, ModelConfig, RunConfig, ScheduleConfig,
                            virtual_stages_for)
 
@@ -84,3 +88,5 @@ __all__ = [
 ]
 
 __version__ = "0.2.0"
+
+_import_done()

@@ -620,7 +620,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     calibration = args.calibration or args.all
     if not (tables or lint or jaxpr or search or memory or overlap
             or calibration):
-        tables = lint = True  # cheap default: no jax import needed
+        tables = lint = True  # cheap default: no backend needed
 
     report = run_checks(tables=tables, lint=lint, jaxpr=jaxpr,
                         search=search, search_out=args.search_out,

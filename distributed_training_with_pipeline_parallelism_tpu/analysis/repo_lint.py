@@ -15,9 +15,13 @@ Each rule encodes a contract documented elsewhere in the repo
 ``init-lazy-exports``
     Package ``__init__.py`` files must not eagerly import submodules:
     re-exports go through the ``_LAZY`` + ``__getattr__`` pattern of the
-    top-level ``__init__`` so ``import dtpp`` stays cheap. The only
-    allowlisted eager import is ``utils.config`` (pure-python dataclasses
-    the one-import surface needs at definition time).
+    top-level ``__init__`` so ``import dtpp`` pulls no subsystem. The
+    allowlisted eager imports are ``utils.config`` (pure-python dataclasses
+    the one-import surface needs at definition time) and
+    ``utils.profiling`` (the host recorder: it stamps the clock at its own
+    top, before its ``import jax``, so the ``setup/import`` span covers the
+    package's import whole — the package's ``__init__`` reads no clock
+    itself, see ``raw-step-timing``).
 
 ``jit-named-scope``
     No bare ``jax.jit`` in ``parallel/`` modules without a
@@ -66,7 +70,9 @@ Each rule encodes a contract documented elsewhere in the repo
     ``time.monotonic()``) outside the sanctioned timing surfaces:
     ``utils/telemetry.py`` (run-report timers + event log),
     ``utils/metrics.py`` (the timed benchmark loop),
-    ``utils/profiling.py``, ``utils/train.py`` (log-window wall clock),
+    ``utils/profiling.py`` (the host-span recorder: ``annotate`` and the
+    package's ``setup/import`` stamp), ``utils/train.py`` (log-window wall
+    clock),
     ``utils/resilience.py`` (checkpoint stamps), ``serving/engine.py``
     (serving wall clock), and ``analysis/calibration.py`` (the probe
     harness). Anywhere else, a raw clock read is an ad-hoc step timing
@@ -86,7 +92,7 @@ import os
 from typing import Dict, List, Optional, Set, Tuple
 
 # __init__.py relative imports that may stay eager (see rule docstring).
-LAZY_IMPORT_ALLOWLIST = frozenset({"utils.config"})
+LAZY_IMPORT_ALLOWLIST = frozenset({"utils.config", "utils.profiling"})
 
 # Calls banned inside tick/scan bodies: (dotted-name, message).
 _BANNED_DOTTED = {

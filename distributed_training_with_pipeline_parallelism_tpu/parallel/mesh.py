@@ -17,6 +17,8 @@ import jax
 import numpy as np
 from jax.sharding import Mesh
 
+from ..utils.profiling import annotate, backend_is_up
+
 DATA_AXIS = "data"
 PIPE_AXIS = "pipe"
 MODEL_AXIS = "model"  # tensor_parallel.TP_AXIS aliases this
@@ -49,26 +51,31 @@ def make_mesh(n_pipe: int, n_data: int = 1, n_model: int = 1, n_seq: int = 1,
     sequence parallelism inside stages), and/or an 'expert' axis (MoE
     expert parallelism inside stages) when those sizes exceed 1. Extra
     axes are innermost — the highest-traffic collectives ride the shortest
-    ICI hops."""
-    devices = list(devices if devices is not None else jax.devices())
-    sizes = [("n_data", DATA_AXIS, n_data), ("n_pipe", PIPE_AXIS, n_pipe)]
-    if n_model > 1:
-        sizes.append(("n_model", MODEL_AXIS, n_model))
-    if n_seq > 1:
-        sizes.append(("n_seq", SEQ_AXIS, n_seq))
-    if n_expert > 1:
-        sizes.append(("n_expert", EXPERT_AXIS, n_expert))
-    need = int(np.prod([n for _, _, n in sizes]))
-    if len(devices) < need:
-        detail = ", ".join(f"{name[2:]}={n}" for name, _, n in sizes)
-        raise ValueError(
-            f"need {need} devices for mesh ({detail}), have {len(devices)}; "
-            f"for CPU simulation set "
-            f"XLA_FLAGS=--xla_force_host_platform_device_count=N before "
-            f"importing jax (the JAX analog of the reference's "
-            f"gloo-on-localhost trick)")
-    grid = np.asarray(devices[:need]).reshape([n for _, _, n in sizes])
-    return Mesh(grid, tuple(axis for _, axis, _ in sizes))
+    ICI hops.
+
+    Kept as the host span ``setup/mesh``. Without ``devices`` this is often
+    a process's first call that needs them, so the span then holds the
+    backend's initialisation: its notes say ``backend_was_up=False``."""
+    with annotate("setup/mesh", backend_was_up=backend_is_up()):
+        devices = list(devices if devices is not None else jax.devices())
+        sizes = [("n_data", DATA_AXIS, n_data), ("n_pipe", PIPE_AXIS, n_pipe)]
+        if n_model > 1:
+            sizes.append(("n_model", MODEL_AXIS, n_model))
+        if n_seq > 1:
+            sizes.append(("n_seq", SEQ_AXIS, n_seq))
+        if n_expert > 1:
+            sizes.append(("n_expert", EXPERT_AXIS, n_expert))
+        need = int(np.prod([n for _, _, n in sizes]))
+        if len(devices) < need:
+            detail = ", ".join(f"{name[2:]}={n}" for name, _, n in sizes)
+            raise ValueError(
+                f"need {need} devices for mesh ({detail}), have "
+                f"{len(devices)}; for CPU simulation set "
+                f"XLA_FLAGS=--xla_force_host_platform_device_count=N before "
+                f"importing jax (the JAX analog of the reference's "
+                f"gloo-on-localhost trick)")
+        grid = np.asarray(devices[:need]).reshape([n for _, _, n in sizes])
+        return Mesh(grid, tuple(axis for _, axis, _ in sizes))
 
 
 def init_multihost(coordinator_address: Optional[str] = None,

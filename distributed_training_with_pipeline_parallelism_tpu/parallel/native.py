@@ -19,6 +19,8 @@ from typing import Optional
 
 import numpy as np
 
+from ..utils.profiling import annotate
+
 _CSRC = os.path.join(os.path.dirname(__file__), "..", "..", "csrc")
 
 
@@ -47,8 +49,9 @@ class NativeLib:
             if self._lib is not None or self._failed:
                 return self._lib
             try:
-                subprocess.run(["make", "-C", os.path.abspath(_CSRC)],
-                               check=True, capture_output=True)
+                with annotate("setup/native_build"):
+                    subprocess.run(["make", "-C", os.path.abspath(_CSRC)],
+                                   check=True, capture_output=True)
                 lib = ctypes.CDLL(self._so)
                 self._configure(lib)
                 self._lib = lib
@@ -78,6 +81,7 @@ def native_available() -> bool:
     return _load() is not None
 
 
+@annotate("setup/schedule")
 def compile_schedule_native(name: str, n_devices: int, n_virtual: int,
                             n_microbatches: int):
     """Native twin of ``schedules.compile_schedule`` (without the Action tick

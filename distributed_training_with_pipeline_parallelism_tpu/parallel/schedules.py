@@ -40,6 +40,8 @@ from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
+from ..utils.profiling import annotate
+
 F = "F"
 B = "B"  # full backward — or input-grad (dgrad) only under a split schedule
 W = "W"  # weight-grad (wgrad) — split schedules (ZB-H1) only
@@ -726,9 +728,12 @@ def _allocate_slots(events: List[Tuple[int, int, object]]) -> Tuple[Dict[object,
     return assign, n_slots
 
 
+@annotate("setup/schedule")
 def compile_schedule(name: str, n_devices: int, n_virtual: int,
                      n_microbatches: int) -> CompiledSchedule:
     """Generate, validate, and lower a schedule to executor tick tables.
+    Kept as the host span ``setup/schedule`` (order generation, table,
+    self-check), as its native twin is.
 
     The lowering is the SPMD analog of upstream's comm insertion
     (``_add_send_recv`` / ``_prepare_schedule_with_comms``,

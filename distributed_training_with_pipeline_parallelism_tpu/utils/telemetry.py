@@ -440,6 +440,7 @@ class RunReport:
         self.memory: Optional[Dict[str, Any]] = None
         self.dynamics: Optional[Dict[str, Any]] = None
         self.calibration: Optional[Dict[str, Any]] = None
+        self.setup: Optional[Dict[str, Any]] = None
         self.out_dir = out_dir
         self._events_fh = None
         # the event stream is written from the training loop AND from
@@ -552,6 +553,15 @@ class RunReport:
         planner search will consume."""
         self.calibration = dict(section)
 
+    def attach_setup(self, section: Dict[str, Any]) -> None:
+        """Embed where start-up went
+        (:func:`utils.profiling.setup_section`: the ``setup/*`` host spans,
+        the step program's trace / lowering / backend seconds, every other
+        program's count and seconds with the dearest few by name, the
+        recorder's own cost) as the manifest's ``setup`` block, beside the
+        ``compile_s`` timer, which brackets the first step whole."""
+        self.setup = dict(section)
+
     # -- output ---------------------------------------------------------
 
     def manifest(self) -> Dict[str, Any]:
@@ -583,6 +593,8 @@ class RunReport:
             out["dynamics"] = _jsonable(self.dynamics)
         if self.calibration is not None:
             out["calibration"] = _jsonable(self.calibration)
+        if self.setup is not None:
+            out["setup"] = _jsonable(self.setup)
         return out
 
     def write(self, path: Optional[str] = None) -> Dict[str, Any]:
@@ -943,3 +955,66 @@ def validate_report(manifest: Dict[str, Any]) -> None:
         lp = cal.get("ledger_path")
         if lp is not None and not isinstance(lp, str):
             fail("calibration.ledger_path must be a string or null")
+    setup = manifest.get("setup")
+    if setup is not None:
+        def seconds_or_null(x):
+            return x is None or (isinstance(x, (int, float))
+                                 and not isinstance(x, bool) and x >= 0)
+
+        if not isinstance(setup, dict):
+            fail("setup must be a dict")
+        spans = setup.get("spans")
+        if not isinstance(spans, dict):
+            fail("setup.spans must be a dict")
+        for name, row in spans.items():
+            if not name.startswith("setup/"):
+                fail(f"setup.spans key {name!r} must start with 'setup/'")
+            if not isinstance(row, dict) or not isinstance(
+                    row.get("count"), int) or row["count"] < 1:
+                fail(f"setup.spans[{name!r}] needs an int count >= 1")
+            for key in ("seconds", "longest_s"):
+                if row.get(key) is None or not seconds_or_null(row[key]):
+                    fail(f"setup.spans[{name!r}].{key} must be a number "
+                         ">= 0")
+            if row["longest_s"] > row["seconds"] + 1e-9:
+                fail(f"setup.spans[{name!r}]: longest_s above seconds")
+            inside = row.get("inside")
+            if not isinstance(inside, dict) or not all(
+                    isinstance(k, str) and seconds_or_null(v)
+                    and v is not None for k, v in inside.items()):
+                fail(f"setup.spans[{name!r}].inside must be a dict of "
+                     "seconds by where the span ran")
+        step = setup.get("step_program", "missing")
+        if step is not None:
+            if not isinstance(step, dict) or not isinstance(
+                    step.get("name"), str):
+                fail("setup.step_program must be null or a dict with a "
+                     "str 'name'")
+            for key in ("trace_s", "lower_s", "backend_s"):
+                if key not in step or not seconds_or_null(step[key]):
+                    fail(f"setup.step_program.{key} must be a number >= 0 "
+                         "or null")
+            if step.get("cache") not in (None, "hit", "miss", "uncached"):
+                fail("setup.step_program.cache must be hit, miss, "
+                     "uncached or null")
+        other = setup.get("other_programs")
+        if not isinstance(other, dict) or not isinstance(
+                other.get("count"), int):
+            fail("setup.other_programs needs an int 'count'")
+        if other.get("seconds") is None or not seconds_or_null(
+                other["seconds"]):
+            fail("setup.other_programs.seconds must be a number >= 0")
+        dearest = other.get("dearest")
+        if not isinstance(other.get("traced_only"), int):
+            fail("setup.other_programs needs an int 'traced_only'")
+        if not isinstance(dearest, list) or len(dearest) > (
+                other["count"] + other["traced_only"]):
+            fail("setup.other_programs.dearest must be a list no longer "
+                 "than count + traced_only")
+        for row in dearest:
+            if not isinstance(row, dict) or not isinstance(
+                    row.get("name"), str) or row.get(
+                    "seconds") is None or not seconds_or_null(
+                    row["seconds"]):
+                fail("each setup.other_programs.dearest row needs a str "
+                     "'name' and numeric 'seconds'")

@@ -30,7 +30,8 @@ from ..parallel.pipeline import (make_pipeline_grad_fn, model_init,
 from .checkpoint import restore_checkpoint, save_checkpoint
 from .config import ModelConfig, ScheduleConfig
 from .dynamics import as_dynamics_config, nonfinite_per_stage, stage_stats
-from .profiling import annotate, annotated_steps
+from .profiling import (annotate, annotated_steps, format_setup,
+                        setup_section, trace)
 
 Pytree = Any
 
@@ -43,6 +44,7 @@ Pytree = Any
 _jit_step = functools.partial(jax.jit, donate_argnums=(0, 1))
 
 
+@annotate("setup/build_step")
 def make_train_step(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig,
                     optimizer: optax.GradientTransformation, moe=None,
                     sp_attn_impl: str = "ring",
@@ -102,7 +104,12 @@ def make_train_step(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig,
     grad norms feeding the gradient-noise-scale estimator. Like the
     guard counters the dict is read only at the caller's log syncs;
     with ``dynamics`` falsy the traced program is byte-identical to a
-    build without the argument."""
+    build without the argument.
+
+    Kept as the host span ``setup/build_step``: the Python that BUILDS the
+    function (the executor's schedule table among it, ``setup/schedule``),
+    not its tracing — that happens at the first call or ``lower()`` and is
+    filed by ``utils.profiling.programs`` under the function's name."""
     dcfg = as_dynamics_config(dynamics)
     want_gns = dcfg is not None and dcfg.gns
     grad_fn = make_pipeline_grad_fn(cfg, mesh, sched, moe=moe,
@@ -278,6 +285,7 @@ def make_train_step(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig,
     return guarded_step
 
 
+@annotate("setup/init_params")
 def init_params(cfg: ModelConfig, mesh: Mesh, key: jax.Array, moe=None,
                 fsdp: bool = False,
                 tp_vocab_parallel: bool = False) -> Pytree:
@@ -286,7 +294,9 @@ def init_params(cfg: ModelConfig, mesh: Mesh, key: jax.Array, moe=None,
     is jitted with those ``out_shardings``, so each device only ever
     materializes its own stages' weights — the whole model never sits on
     the first device, which for gpt2-xl (6.2 GB fp32) plus its Adam
-    moments would not fit a 16 GB chip."""
+    moments would not fit a 16 GB chip. Kept as the host span
+    ``setup/init_params``: getting the init program (traced, lowered,
+    compiled or read back) and enqueueing it, not the device's work."""
     return jax.jit(model_init(cfg, moe), out_shardings=param_shardings(
         cfg, mesh, moe=moe, fsdp=fsdp,
         tp_vocab_parallel=tp_vocab_parallel))(key)
@@ -328,11 +338,12 @@ def opt_state_shardings(optimizer: optax.GradientTransformation,
     return jax.tree_util.tree_map_with_path(rest, shapes)
 
 
+@annotate("setup/init_opt_state")
 def init_opt_state(optimizer: optax.GradientTransformation, params: Pytree,
                    mesh: Mesh, zero1: bool = False) -> Pytree:
     """``optimizer.init`` jitted INTO :func:`opt_state_shardings`: the
     state is born where it rests, so no replicated (or first-device) peak
-    ever materializes."""
+    ever materializes. Kept as the host span ``setup/init_opt_state``."""
     return jax.jit(optimizer.init, out_shardings=opt_state_shardings(
         optimizer, params, mesh, zero1=zero1))(params)
 
@@ -618,9 +629,10 @@ def fit(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig, params: Pytree,
 
     start_step = 0
     if resume and mgr is not None:
-        restored = mgr.restore_latest({
-            "params": params, "opt_state": opt_state,
-            "step": jnp.asarray(0)})
+        with annotate("setup/restore"):
+            restored = mgr.restore_latest({
+                "params": params, "opt_state": opt_state,
+                "step": jnp.asarray(0)})
         if restored is not None:
             n, path, state = restored
             # the restore template carries the live shardings (see
@@ -704,8 +716,14 @@ def fit(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig, params: Pytree,
         watchdog = StepWatchdog(stall_timeout_s, _on_stall)
 
     history = []
+    startup_shown = False
+    # the name JAX's compile events carry for the step (train_step,
+    # guarded_step, ...): the recorder's first request under it is the
+    # program this run waits for
+    step_name = getattr(step_fn, "__name__", "train_step")
     window_start = time.perf_counter()
     window_tokens = 0
+    profile = contextlib.ExitStack()  # the open profiler session, if any
     profiling = False
     preempted = False
     last_done = start_step - 1  # newest step whose outputs params hold
@@ -754,6 +772,7 @@ def fit(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig, params: Pytree,
             res["stalls"] = watchdog.stalls
         if res:
             report.attach_resilience(res)
+        report.attach_setup(setup_section(step_name))
         report.write()
 
     # profile_steps counts from the first step THIS run executes, so a
@@ -773,10 +792,10 @@ def fit(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig, params: Pytree,
                     preempt.trigger()  # deterministic stand-in for SIGTERM
                 if profile_dir is not None:
                     if i == prof_start and not profiling:
-                        jax.profiler.start_trace(profile_dir)
+                        profile.enter_context(trace(profile_dir))
                         profiling = True
                     elif i == prof_stop and profiling:
-                        jax.profiler.stop_trace()
+                        profile.close()
                         profiling = False
                         if verbose:
                             print(f"profile trace written to {profile_dir}",
@@ -790,9 +809,13 @@ def fit(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig, params: Pytree,
                     recorder.note_batch(i, batch_digest(tokens, targets))
                 # first executed step = trace + compile + run; the report's
                 # compile_s timer brackets it (forced, so the timer is honest)
+                # and so does the setup/first_step span, which forces nothing:
+                # without a report it holds getting the program, not its run
                 first = report is not None and i == start_step
                 with (report.timer("compile_s") if first
-                      else contextlib.nullcontext()), annotate("dispatch"):
+                      else contextlib.nullcontext()), \
+                        (annotate("setup/first_step") if i == start_step
+                         else contextlib.nullcontext()), annotate("dispatch"):
                     args = (params, opt_state, tokens, targets)
                     if drop_key is not None:
                         args += (jax.random.fold_in(drop_key, i),)
@@ -820,6 +843,10 @@ def fit(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig, params: Pytree,
                     history.append((i, loss_f))
                     if verbose:
                         print(f"step {i}: loss {loss_f:.4f}", flush=True)
+                        if not startup_shown:  # once, at the first log point
+                            print(format_setup(setup_section(step_name)),
+                                  flush=True)
+                            startup_shown = True
                     if on_log is not None:
                         on_log(i, params, tokens)
                     if metrics_path:
@@ -1002,9 +1029,7 @@ def fit(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig, params: Pytree,
     finally:
         if watchdog is not None:
             watchdog.stop()
-        if profiling:  # the profile window ran past the last executed step
-            jax.profiler.stop_trace()
-            profiling = False
+        profile.close()  # the profile window ran past the last executed step
     if eval_fn is not None and num_steps > start_step and not preempted:
         _eval(num_steps - 1)
     if (mgr is not None and checkpoint_every and num_steps > start_step

@@ -126,19 +126,23 @@ def run_backfill(args) -> int:
 def run_grid(args) -> int:
     import time
 
+    # JAX_PLATFORMS is read when jax is imported, and importing the package
+    # imports jax (its host recorder, utils/profiling.py): default it first.
+    # It is only defaulted, so a TPU probe run just sets JAX_PLATFORMS=tpu
+    # in the environment.
+    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+
     from distributed_training_with_pipeline_parallelism_tpu.analysis import (
         calibration as cal)
 
     specs = cal.probe_grid(args.grid, seed=args.seed)
     need = max(s.n_devices for s in specs)
-    # must precede the first jax import: the simulated mesh needs `need`
-    # host devices. Forcing the *host* platform count is harmless on a
-    # real accelerator; JAX_PLATFORMS is only defaulted, so a TPU probe
-    # run just sets JAX_PLATFORMS=tpu in the environment.
+    # must precede the first backend initialisation: the simulated mesh
+    # needs `need` host devices. Forcing the *host* platform count is
+    # harmless on a real accelerator.
     os.environ["XLA_FLAGS"] = (
         f"--xla_force_host_platform_device_count={need} "
         + os.environ.get("XLA_FLAGS", ""))
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
     import jax
 
