@@ -35,8 +35,9 @@ Parameters: ``layers`` is ``{"mamba": .., "shortconv": .., "attn": ..,
 layers of one kind stacked on axis 0 in pattern order; the stack is walked in
 pattern order as straight-line code (layers of different kinds share no
 scan), each layer under ``jax.checkpoint`` where ``cfg.remat_layers``
-(:func:`..ops.layers.remat_layer`: all but the flash kernels' output and
-log-sum-exp is recomputed in the backward).
+(:func:`..ops.layers.remat_layer`: all is recomputed in the backward but
+the flash kernels' output and log-sum-exp, and the named product outputs
+that :func:`kept_names` grants each layer from the room the chip has).
 ``transformer_init`` / ``body_apply`` of :mod:`.transformer` dispatch here,
 so ``transformer_loss``, ``train.init_params`` and ``train.make_train_step``
 run this family on the normal path.
@@ -49,7 +50,7 @@ from __future__ import annotations
 
 import functools
 import logging
-from typing import Dict, List, Tuple
+from typing import Callable, Dict, List, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -57,10 +58,12 @@ import jax.numpy as jnp
 from ..ops.attention import (mha_apply, mha_init, mla_apply, mla_init,
                              rope_frequencies)
 from ..ops.experts import experts_apply, experts_init, mlp_apply, mlp_init
-from ..ops.layers import remat_layer, rms_norm_apply, rms_norm_init
+from ..ops.layers import (Offer, choose_kept, current_room, offers_of,
+                          remat_layer, rms_norm_apply, rms_norm_init)
 from ..ops.mamba2 import mamba2_apply, mamba2_init
 from ..ops.shortconv import shortconv_apply, shortconv_init
 from ..utils.config import ModelConfig
+from ..utils.profiling import annotate
 
 #: pattern letter -> the key of its stack under ``params["layers"]``
 KINDS = {"M": "mamba", "C": "shortconv", "*": "attn", "L": "mla",
@@ -69,6 +72,11 @@ KINDS = {"M": "mamba", "C": "shortconv", "*": "attn", "L": "mla",
 #: recurrence's decay parameters and the whole router are float32 whatever
 #: ``cfg.dtype`` is
 FLOAT32_LEAVES = frozenset(("A_log", "dt_bias", "D", "router"))
+
+#: what a layer's backward holds at once, in multiples of its named
+#: products' bytes, where it is not the 0.75 of a layer of plain products
+#: (the Mamba-2 scan's float32 decay tiles: 1.9 GB at the benchmark's shapes)
+WORKING_SET = {"mamba": 5.7}
 
 _log = logging.getLogger(__name__)
 
@@ -251,18 +259,94 @@ def mixer_apply(cfg: ModelConfig, kind: str, params: Dict, h: jax.Array):
         return h + out, counts
 
 
+def instants(cfg: ModelConfig, layers: Dict, h: jax.Array,
+             offers: List[List[Offer]], room: float) -> List[float]:
+    """What the layers BEFORE layer ``L`` may keep together while ``L``'s
+    backward runs, for ``L`` = 0 .. the number of layers (the last: after
+    the forward, when everything kept is held) — an estimate of what the
+    compiler will count, from shapes, calibrated against its count of the
+    three 8k steps (``tests/test_chip_compile.py``).
+
+    ``room`` is what the tracing step declared (:func:`..ops.layers.
+    remat_room`): the chip less parameters, optimizer state, compute copies,
+    ALL gradients and the margin. At instant ``L`` there is that, plus the
+    gradients not made yet — the stacks whose last layer comes before ``L``:
+    a stack's gradient is one buffer, there from the first of its layers the
+    backward reaches — less what the rematerialised stack holds then
+    whatever is granted: the inputs of the layers up to ``L``, the flash
+    pair of the attention layers before it (output [b, s, heads x d_v] and a
+    float32 log-sum-exp a head and row), and layer ``L``'s own working set,
+    :data:`WORKING_SET` times its named products."""
+    plan = layer_plan(cfg)
+    b, s, _ = h.shape
+    h_bytes = h.size * h.dtype.itemsize
+    width = jnp.dtype(cfg.storage_dtype).itemsize
+    grads = {kind: sum(x.size * width for x in jax.tree.leaves(stack))
+             for kind, stack in layers.items()}
+    last = {kind: l for l, (kind, _) in enumerate(plan)}
+    v_dim = ({"attn": cfg.head_dim, "mla": cfg.v_head_dim}
+             if cfg.flash_for(True, s) else {})
+    out, flash = [], 0
+    for l, (kind, _) in enumerate(plan):
+        working = WORKING_SET.get(kind, 0.75) * sum(
+            o.nbytes for o in offers[l])
+        out.append(room + sum(g for k, g in grads.items() if last[k] < l)
+                   - (l + 1) * h_bytes - flash - working)
+        if kind in v_dim:
+            flash += b * s * cfg.n_heads * (v_dim[kind] * h.dtype.itemsize + 4)
+    return out + [room + sum(grads.values()) - len(plan) * h_bytes - flash]
+
+
+def kept_names(cfg: ModelConfig, layers: Dict, h: jax.Array,
+               ) -> List[Tuple[str, ...]]:
+    """The names every layer of the pattern is granted beside the flash
+    pair, chosen where the step is traced: ONE walk over shapes (each
+    kind's mixer under ``jax.eval_shape``: no FLOP runs) lists the named
+    product outputs, and :func:`..ops.layers.choose_kept` grants them,
+    dearest to recompute per byte first, within what :func:`instants` finds
+    room for — nothing on a device whose memory the table does not know, so
+    nothing on the CPU. Kept as the host span ``setup/remat_keep``, whose
+    notes are the record of the choice."""
+    plan = layer_plan(cfg)
+    with annotate("setup/remat_keep") as span:
+        by_kind = {kind: offers_of(
+            functools.partial(mixer_apply, cfg, kind),
+            jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape[1:], x.dtype),
+                         layers[kind]),
+            jax.ShapeDtypeStruct(h.shape, h.dtype))
+            for kind in dict.fromkeys(k for k, _ in plan)}
+        offers = [by_kind[kind] for kind, _ in plan]
+        room = current_room()
+        rooms = (instants(cfg, layers, h, offers, room) if room > 0
+                 else [0.0] * (len(plan) + 1))
+        keep, record = choose_kept(offers, rooms)
+        span.note(room_bytes=int(room),
+                  instant_bytes=[int(r) for r in rooms], **record)
+    return keep
+
+
 def stack_apply(cfg: ModelConfig, layers: Dict, h: jax.Array,
                 ) -> Tuple[jax.Array, List[jax.Array]]:
     """Walk the pattern -> (h, the expert layers' counts in order). Under
     ``cfg.remat_layers`` every layer is recomputed in the backward from its
     input, but for what :func:`..ops.layers.remat_layer` keeps: the flash
-    kernels' output and log-sum-exp of an attention layer."""
+    kernels' output and log-sum-exp of an attention layer, and the named
+    product outputs :func:`kept_names` granted that layer from the chip's
+    room (``x W1`` and ``x W3`` of an expert layer, an MLP's ``up`` and
+    ``gate``, the in-projections and the attention projections: each whole
+    or not at all)."""
     plan = layer_plan(cfg)
-    one = mixer_apply
-    if cfg.remat_layers:
-        one = remat_layer(one, len(plan), static_argnums=(0, 1))
+    keep = (kept_names(cfg, layers, h) if cfg.remat_layers
+            else [()] * len(plan))
+    wrapped: Dict[Tuple[str, ...], Callable] = {}
     counts = []
-    for kind, i in plan:
+    for (kind, i), names in zip(plan, keep):
+        one = mixer_apply
+        if cfg.remat_layers:
+            if names not in wrapped:
+                wrapped[names] = remat_layer(mixer_apply, len(plan), names,
+                                             static_argnums=(0, 1))
+            one = wrapped[names]
         h, c = one(cfg, kind, jax.tree.map(lambda x: x[i], layers[kind]), h)
         if c is not None:
             counts.append(c)

@@ -22,7 +22,7 @@ from typing import Dict, Optional
 import jax
 import jax.numpy as jnp
 
-from .layers import (dropout_apply, linear_init, linear_apply,
+from .layers import (dropout_apply, linear_init, linear_apply, named_product,
                      rms_norm_apply, rms_norm_init, sharded_dropout_apply)
 
 
@@ -163,9 +163,13 @@ def qkv_project(params: Dict, q_in: jax.Array, kv_in: jax.Array, n_heads: int,
     conventions cannot drift between them."""
     head_dim = params["q"]["w"].shape[1] // n_heads
     n_kv = params["k"]["w"].shape[1] // head_dim
-    q = _split_heads(linear_apply(params["q"], q_in), n_heads)
-    k = _split_heads(linear_apply(params["k"], kv_in), n_kv)
-    v = _split_heads(linear_apply(params["v"], kv_in), n_kv)
+    def product(m, x, heads):
+        return _split_heads(named_product(
+            linear_apply(params[m], x), "attn_" + m, x.shape[-1]), heads)
+
+    q = product("q", q_in, n_heads)
+    k = product("k", kv_in, n_kv)
+    v = product("v", kv_in, n_kv)
     if "q_layernorm" in params:
         q = rms_norm_apply(params["q_layernorm"], q, norm_eps)
         k = rms_norm_apply(params["k_layernorm"], k, norm_eps)
@@ -275,12 +279,16 @@ def mla_project(params: Dict, x: jax.Array, n_heads: int,
     scaling), ``k_pe`` one head for all."""
     b, s, _ = x.shape
     rope = qk_rope_head_dim
-    c_q = rms_norm_apply(params["q_norm"], linear_apply(params["q_a"], x), eps)
-    q = linear_apply(params["q_b"], c_q).reshape(b, s, n_heads, -1)
+    def product(m, x):
+        return named_product(linear_apply(params[m], x), "mla_" + m,
+                             x.shape[-1])
+
+    c_q = rms_norm_apply(params["q_norm"], product("q_a", x), eps)
+    q = product("q_b", c_q).reshape(b, s, n_heads, -1)
     nope = q.shape[-1] - rope
-    c_kv = linear_apply(params["kv_a"], x)
+    c_kv = product("kv_a", x)
     kv_rank = c_kv.shape[-1] - rope
-    kv = linear_apply(params["kv_b"], rms_norm_apply(
+    kv = product("kv_b", rms_norm_apply(
         params["kv_norm"], c_kv[..., :kv_rank], eps)).reshape(b, s, n_heads, -1)
     angles = rope_frequencies(rope, s, rope_theta)
     q_pe = apply_rope_interleaved(q[..., nope:], angles)

@@ -69,7 +69,7 @@ from typing import Dict, Sequence
 import jax
 import jax.numpy as jnp
 
-from .layers import linear_apply, linear_init
+from .layers import linear_apply, linear_init, named_product
 
 
 def mlp_init(keys, dim: int, width: int, gated: bool) -> Dict:
@@ -122,9 +122,11 @@ def mlp_apply(params: Dict, x: jax.Array) -> jax.Array:
     """``down(relu2(up x))``, or ``down(silu(gate x) * up x)`` where the
     parameters hold a gate. The activation is checkpointed: the backward
     keeps the pre-activations and recomputes the pointwise chain."""
-    up = linear_apply(params["up"], x)
+    d = x.shape[-1]
+    up = named_product(linear_apply(params["up"], x), "mlp_up", d)
     if "gate" in params:
-        h = jax.checkpoint(silu_gated)(linear_apply(params["gate"], x), up)
+        h = jax.checkpoint(silu_gated)(named_product(
+            linear_apply(params["gate"], x), "mlp_gate", d), up)
     else:
         h = jax.checkpoint(relu2)(up)
     return linear_apply(params["down"], h)
@@ -151,10 +153,12 @@ def held_experts(experts: Dict, x: jax.Array, gate: jax.Array) -> jax.Array:
     [T, held] (float32): every held expert on every token, the gate between
     the hidden activation (``relu(x W1)^2``, or ``silu(x W1) * (x W3)`` where
     the stacks hold a ``w3``) and the product with ``W2``."""
-    h = jnp.einsum("td,edf->tef", x, experts["w1"])
+    d = x.shape[-1]
+    h = named_product(jnp.einsum("td,edf->tef", x, experts["w1"]),
+                      "experts_w1", d)
     if "w3" in experts:
-        h = jax.checkpoint(silu_gated)(
-            h, jnp.einsum("td,edf->tef", x, experts["w3"]))
+        h = jax.checkpoint(silu_gated)(h, named_product(
+            jnp.einsum("td,edf->tef", x, experts["w3"]), "experts_w3", d))
     else:
         h = jax.checkpoint(relu2)(h)
     h = (h.astype(jnp.float32) * gate[..., None]).astype(x.dtype)

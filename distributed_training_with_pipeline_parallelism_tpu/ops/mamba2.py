@@ -32,7 +32,8 @@ from typing import Dict, Optional
 import jax
 import jax.numpy as jnp
 
-from .layers import linear_apply, linear_init, rms_norm_init
+from .layers import (linear_apply, linear_init, named_product,
+                     rms_norm_init)
 
 
 def mamba2_init(key: jax.Array, dim: int, n_heads: int, head_dim: int,
@@ -157,7 +158,8 @@ def mamba2_apply(params: Dict, u: jax.Array, n_heads: int, head_dim: int,
     pad = -t % chunk
     if pad:
         u = jnp.pad(u, ((0, 0), (0, pad), (0, 0)))
-    zxbcdt = linear_apply(params["in_proj"], u)
+    zxbcdt = named_product(linear_apply(params["in_proj"], u), "mamba_in",
+                           u.shape[-1])
     z, xBC, dt = jnp.split(zxbcdt, [d_inner, 2 * d_inner + 2 * gn], axis=-1)
     xBC = jax.nn.silu(causal_conv1d(
         xBC, params["conv"]["w"], params["conv"]["b"])).astype(u.dtype)

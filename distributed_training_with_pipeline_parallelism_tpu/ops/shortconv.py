@@ -26,7 +26,7 @@ from typing import Dict
 import jax
 import jax.numpy as jnp
 
-from .layers import linear_apply, linear_init
+from .layers import linear_apply, linear_init, named_product
 from .mamba2 import causal_conv1d
 
 
@@ -47,7 +47,9 @@ def shortconv_init(key: jax.Array, dim: int, taps: int, bias: bool) -> Dict:
 
 def shortconv_apply(params: Dict, u: jax.Array) -> jax.Array:
     """The mixer on ``u`` [B, T, d] (already normed) -> [B, T, d]."""
-    B, C, x = jnp.split(linear_apply(params["in_proj"], u), 3, axis=-1)
+    B, C, x = jnp.split(named_product(
+        linear_apply(params["in_proj"], u), "shortconv_in", u.shape[-1]),
+        3, axis=-1)
     f32 = jnp.float32
     y = C.astype(f32) * causal_conv1d(
         B.astype(f32) * x.astype(f32), params["conv"]["w"],

@@ -82,7 +82,10 @@ class ModelConfig:
     use_fused_xent: bool = False  # route the loss through the Pallas fused-CE kernel
     # jax.checkpoint each layer, trading FLOPs for HBM: the backward recomputes
     # a layer from its input, all but the flash kernels' output and
-    # log-sum-exp, which are kept (ops/layers.py:remat_layer)
+    # log-sum-exp, which are kept (ops/layers.py:remat_layer), and in the
+    # patterned stack the named product outputs the chip has room for,
+    # chosen where the step is traced (models/nemotron_h.py:kept_names; no
+    # field sets the budget: it is the chip's memory less what the step holds)
     remat_layers: bool = False
     # Unroll the per-layer scan into straight-line code: XLA fuses across
     # layers and backward residuals avoid the scan-boundary HBM round-trip

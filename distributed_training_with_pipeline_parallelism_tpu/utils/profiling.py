@@ -87,6 +87,8 @@ SETUP_SPANS = (
     ("setup/init_params", "utils/train.py:init_params"),
     ("setup/init_opt_state", "utils/train.py:init_opt_state"),
     ("setup/build_step", "utils/train.py:make_train_step"),
+    ("setup/remat_keep", "models/nemotron_h.py:kept_names, while the step "
+                         "is traced: what remat_layers keeps, in its notes"),
     ("setup/restore", "utils/train.py:fit, CheckpointManager.restore_latest"),
     ("setup/first_step", "utils/train.py:fit, the first step's call"),
 )
@@ -140,7 +142,8 @@ class annotate(jax.profiler.TraceAnnotation):
     program: ``{"setup/build_step": 0.8}``, empty for a span that always
     ran on its own), and the last :data:`RING` ``(start, seconds)`` pairs.
     Keyword ``notes`` are kept with the name
-    (``annotate("setup/mesh", backend_was_up=False)``).
+    (``annotate("setup/mesh", backend_was_up=False)``; :meth:`note` adds
+    what is learned inside the span).
     ``@annotate("setup/build_step")`` wraps a function in a fresh span per
     call.
 
@@ -174,6 +177,10 @@ class annotate(jax.profiler.TraceAnnotation):
         stack.append(self._name)
         self._start = time.perf_counter()
         return self
+
+    def note(self, **notes: Any) -> None:
+        """More notes, learned while the span is open; kept at its end."""
+        self._notes = {**self._notes, **notes}
 
     def __exit__(self, *exc) -> Optional[bool]:
         end = time.perf_counter()

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import Mesh
 
+from ..ops.layers import chip_room, remat_room
 from ..parallel.mesh import PIPE_AXIS
 from ..parallel.pipeline import (make_pipeline_grad_fn, model_init,
                                  param_shardings)
@@ -141,13 +142,31 @@ def make_train_step(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig,
         raise ValueError(f"fault_plan.nan_grad_stage={nan_stage} out of "
                          f"range for {n_stages} stages")
 
-    def run_grads(params, tokens, targets, rng):
+    def step_room(params, opt_state) -> float:
+        """What a chip of the mesh has left for the activations of the step
+        being traced (:func:`..ops.layers.chip_room`), beside what it holds
+        whatever a layer keeps: the donated parameters and optimizer state,
+        the gradients (the parameters' bytes again) and, under mixed
+        precision, ``compute_cast``'s copies. Whole trees: an upper bound
+        where they rest sharded."""
+        def nbytes(tree):
+            return sum(x.size * x.dtype.itemsize
+                       for x in jax.tree.leaves(tree))
+        copies = (sum(x.size for x in jax.tree.leaves(params))
+                  * jnp.dtype(cfg.dtype).itemsize if cfg.mixed_precision
+                  else 0)
+        return chip_room(mesh, 2 * nbytes(params) + nbytes(opt_state)
+                         + copies)
+
+    def run_grads(params, opt_state, tokens, targets, rng):
         """(loss, grads, sq_mb|None) — arity bridge over the dynamics
-        pipeline variant."""
+        pipeline variant, traced inside the room ``remat_layers`` may keep
+        named products in."""
         args = (params, tokens, targets) + (() if rng is None else (rng,))
-        if want_gns:
-            return grad_fn(*args)
-        loss, grads = grad_fn(*args)
+        with remat_room(step_room(params, opt_state)):
+            if want_gns:
+                return grad_fn(*args)
+            loss, grads = grad_fn(*args)
         return loss, grads, None
 
     def dyn_stats(grads, params, updates, sq_mb):
@@ -164,8 +183,8 @@ def make_train_step(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig,
                 @_jit_step
                 def train_step_dropout_dyn(params, opt_state, tokens,
                                            targets, rng):
-                    loss, grads, sq_mb = run_grads(params, tokens, targets,
-                                                   rng)
+                    loss, grads, sq_mb = run_grads(params, opt_state, tokens,
+                                                   targets, rng)
                     new_params, opt_state, updates = update(
                         grads, opt_state, params)
                     dyn = dyn_stats(grads, params, updates, sq_mb)
@@ -175,7 +194,8 @@ def make_train_step(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig,
 
             @_jit_step
             def train_step_dropout(params, opt_state, tokens, targets, rng):
-                loss, grads = grad_fn(params, tokens, targets, rng)
+                loss, grads, _ = run_grads(params, opt_state, tokens,
+                                           targets, rng)
                 params, opt_state, _ = update(grads, opt_state, params)
                 return params, opt_state, loss
 
@@ -184,7 +204,8 @@ def make_train_step(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig,
         if dcfg is not None:
             @_jit_step
             def train_step_dyn(params, opt_state, tokens, targets):
-                loss, grads, sq_mb = run_grads(params, tokens, targets, None)
+                loss, grads, sq_mb = run_grads(params, opt_state, tokens,
+                                               targets, None)
                 new_params, opt_state, updates = update(grads, opt_state,
                                                         params)
                 dyn = dyn_stats(grads, params, updates, sq_mb)
@@ -194,14 +215,16 @@ def make_train_step(cfg: ModelConfig, mesh: Mesh, sched: ScheduleConfig,
 
         @_jit_step
         def train_step(params, opt_state, tokens, targets):
-            loss, grads = grad_fn(params, tokens, targets)
+            loss, grads, _ = run_grads(params, opt_state, tokens, targets,
+                                       None)
             params, opt_state, _ = update(grads, opt_state, params)
             return params, opt_state, loss
 
         return train_step
 
     def guarded(params, opt_state, tokens, targets, guard_state, rng=None):
-        loss, grads, sq_mb = run_grads(params, tokens, targets, rng)
+        loss, grads, sq_mb = run_grads(params, opt_state, tokens, targets,
+                                       rng)
         step = guard_state["step"]
         if nan_steps:
             bad = functools.reduce(
