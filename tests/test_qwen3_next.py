@@ -119,26 +119,42 @@ def test_the_tree_is_the_sources(compiled):
     assert moe["experts"]["w1"].shape == (2, 4, 64, 32)
 
 
-def test_chunked_rule_is_the_token_recurrence(ref, seq=128, heads_at_once=2):
-    """Outputs and the gradients of every input: the chunked form across a
-    chunk boundary and in groups of heads, each checkpointed, against the
-    reference's position-by-position recurrence (lengths that are no
-    multiple of the chunk: ``test_each_mixer_alone_equals_the_reference``,
-    whose mixer pads 80 positions to 128)."""
+# (positions, log-decay g from a standard normal n, key heads under the
+# four value heads, beta's logit shift)
+RULE_CASES = {
+    "two-chunks": (128, lambda n: -0.2 * jax.nn.softplus(n), 4, 0.0),
+    "three-chunks": (192, lambda n: -0.2 * jax.nn.softplus(n), 4, 0.0),
+    "weak-decay": (128, lambda n: -1e-3 * jax.nn.softplus(n), 4, 0.0),
+    "strong-decay": (128, lambda n: -5.0 + 0.5 * jnp.tanh(n), 4, 0.0),
+    "repeated-keys": (128, lambda n: -0.2 * jax.nn.softplus(n), 2, 0.0),
+    "beta-near-1": (128, lambda n: -0.2 * jax.nn.softplus(n), 4, 6.0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(RULE_CASES))
+def test_chunked_rule_is_the_token_recurrence(ref, case):
+    """Outputs and the gradients of every input: the kernel pair across
+    chunk boundaries (interpreted here), its forward and its hand-written
+    backward, against the reference's position-by-position recurrence under
+    autodiff; in ``repeated-keys`` each key head serves two value heads, as
+    in the mixer (lengths that are no multiple of the chunk:
+    ``test_each_mixer_alone_equals_the_reference``, whose mixer pads 80
+    positions to 128)."""
+    seq, decay, key_heads, shift = RULE_CASES[case]
     ks = jax.random.split(jax.random.key(seq), 6)
-    q = gdn.l2norm(jax.random.normal(ks[0], (2, seq, 4, 16))) / 4.0
-    k = gdn.l2norm(jax.random.normal(ks[1], (2, seq, 4, 16)))
+    q = gdn.l2norm(jax.random.normal(ks[0], (2, seq, key_heads, 16))) / 4.0
+    k = gdn.l2norm(jax.random.normal(ks[1], (2, seq, key_heads, 16)))
     v = jax.random.normal(ks[2], (2, seq, 4, 8))
-    g = -0.2 * jax.nn.softplus(jax.random.normal(ks[3], (2, seq, 4)))
-    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (2, seq, 4)))
+    g = decay(jax.random.normal(ks[3], (2, seq, 4)))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (2, seq, 4)) + shift)
     cot = jax.random.normal(ks[5], (2, seq, 4, 8))
 
     def chunked(*x):
-        return jnp.vdot(gdn.chunked_delta_rule(
-            *x, heads_at_once=heads_at_once), cot)
+        return jnp.vdot(gdn.chunked_delta_rule(*x), cot)
 
-    def token(*x):
-        return jnp.vdot(ref.recurrence(*x), cot)
+    def token(q, k, *x):  # the reference reads keys repeated to the values
+        q, k = (jnp.repeat(y, 4 // key_heads, axis=2) for y in (q, k))
+        return jnp.vdot(ref.recurrence(q, k, *x), cot)
 
     with jax.default_matmul_precision("highest"):
         got, g_got = jax.jit(jax.value_and_grad(
@@ -268,15 +284,16 @@ def trained(ref):
     before = jax.tree.map(jnp.copy, params)
     opt_state = train.init_opt_state(opt, params, mesh)
     x, y = batch()
-    step = train.make_train_step(cfg, mesh, sched, opt).lower(
-        params, opt_state, x, y).compile()
+    fn = train.make_train_step(cfg, mesh, sched, opt)
+    jaxpr = jax.make_jaxpr(fn)(params, opt_state, x, y)
+    step = fn.lower(params, opt_state, x, y).compile()
     params, opt_state, loss = step(params, opt_state, x, y)
     after, _, _ = step(params, opt_state, x, y)  # the first has lr 0
-    return cfg, before, after, float(loss), step, (x, y)
+    return cfg, before, after, float(loss), step, (x, y), jaxpr
 
 
 def test_train_step_on_the_normal_path(trained, compiled):
-    cfg, before, after, loss, _, (x, y) = trained
+    cfg, before, after, loss, _, (x, y), _ = trained
     want, _ = compiled.ref_grads(jax.device_get(before), x, y)
     assert abs(loss - float(want)) / float(want) < 2e-3
     moved = jax.jit(lambda a, b: jax.tree.map(
@@ -308,7 +325,53 @@ def test_compiled_step_names_the_regions(trained):
                    for _, r in read)
 
 
-def test_new_knobs_are_checked_and_named():
+def _kernels(jaxpr, outer=""):
+    """``(kernel function, phase, region)`` of every ``pallas_call`` in
+    ``jaxpr`` and the jaxprs inside it, the region and phase read by
+    ``classify`` from the name stacks that lead to it (what the compiled
+    instruction's ``op_name`` is made of)."""
+    found = []
+    for eqn in jaxpr.eqns:
+        where = "/".join(p for p in (outer, str(eqn.source_info.name_stack))
+                         if p)
+        if eqn.primitive.name == "pallas_call":
+            kernel = eqn.params["jaxpr"].debug_info.func_src_info.split()[0]
+            found.append((kernel, *classify(where + "/pallas_call")))
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (list, tuple)) else [param]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    found += _kernels(sub, where)
+    return found
+
+
+def test_both_kernels_run_inside_the_scan_region_in_every_phase(trained):
+    """The step runs the forward kernel in the forward and in
+    ``remat_layers``' recompute and the backward kernel in the backward,
+    every call inside ``model/gdn_scan`` (the region the benchmark's
+    ``model.gdn_scan_share_pct`` and ``kernels.gdn_scan_roofline_pct``
+    read); in the compiled step the interpreted kernels' grid loops, the
+    region's only loops, fall in the same three phases."""
+    assert sorted(set(_kernels(trained[6].jaxpr))) == [
+        ("_gdn_bwd_kernel", "backward", "model/gdn_scan"),
+        ("_gdn_fwd_kernel", "forward", "model/gdn_scan"),
+        ("_gdn_fwd_kernel", "recompute", "model/gdn_scan")]
+    loops = {classify(name) for name in re.findall(
+        r'^\s*(?:ROOT )?%?while[\w.\-]* = [^\n]*op_name="([^"]*)"',
+        trained[4].as_text(), re.M)}
+    assert {(p, r) for p, r in loops if r == "model/gdn_scan"} == {
+        ("forward", "model/gdn_scan"), ("recompute", "model/gdn_scan"),
+        ("backward", "model/gdn_scan")}
+
+
+def test_new_knobs_are_checked_and_named(monkeypatch):
+    x = jnp.zeros((1, 64, 2, 16))
+    with pytest.raises(ValueError, match="a multiple of 64"):
+        gdn.chunked_delta_rule(x[:, :48], x[:, :48], x[:, :48],
+                               x[:, :48, :, 0], x[:, :48, :, 0])
+    monkeypatch.setattr(gdn, "_use_interpret", lambda: False)  # as on a TPU
+    with pytest.raises(NotImplementedError, match="multiples of 128"):
+        gdn.chunked_delta_rule(x, x, x, x[..., 0], x[..., 0])
     base = dict(arch="nemotron_h", dim=64, n_layers=2, n_heads=4,
                 vocab_size=128, hybrid_override_pattern="GE",
                 n_routed_experts=8, num_experts_per_tok=2)
