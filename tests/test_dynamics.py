@@ -141,8 +141,8 @@ def test_as_dynamics_config_coercion():
 
 @pytest.mark.parametrize("name,M", [
     ("GPipe", 4),
-    ("1F1B", 8),
-    ("ZBH1", 8),     # split backward (B/W units)
+    ("1F1B", 4),
+    ("ZBH1", 4),     # split backward (B/W units)
 ])
 def test_per_stage_norms_match_oracle(problem, name, M):
     params, tokens, targets, ref_loss, ref_grads = problem

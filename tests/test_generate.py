@@ -6,6 +6,8 @@ cache must produce exactly the tokens obtained by re-running
 position — for both supported block families (gpt2, llama+GQA).
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -24,8 +26,9 @@ LLAMA = dtpp.ModelConfig(dim=32, n_layers=2, n_heads=4, n_kv_heads=2,
 
 def _greedy_no_cache(cfg, params, prompt, n_new):
     toks = prompt
+    apply = jax.jit(functools.partial(tfm.transformer_apply, cfg))
     for _ in range(n_new):
-        logits = tfm.transformer_apply(cfg, params, toks)
+        logits = apply(params, toks)
         nxt = jnp.argmax(logits[:, -1], axis=-1)
         toks = jnp.concatenate([toks, nxt[:, None].astype(toks.dtype)], axis=1)
     return toks

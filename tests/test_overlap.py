@@ -219,8 +219,8 @@ def test_collective_matmul_matches_unfused(arch):
     vals, grads = {}, {}
     for mode in ("none", "ring"):
         mcfg = dataclasses.replace(cfg, tp_overlap=mode)
-        vals[mode], grads[mode] = jax.value_and_grad(
-            _tp_loss_fn(mcfg, full, mesh))(params, h)
+        vals[mode], grads[mode] = jax.jit(jax.value_and_grad(
+            _tp_loss_fn(mcfg, full, mesh)))(params, h)
     np.testing.assert_allclose(vals["none"], vals["ring"],
                                rtol=2e-5, atol=2e-5)
     for (kp, a), (_, b) in zip(

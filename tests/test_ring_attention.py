@@ -62,8 +62,8 @@ def test_ring_attention_grads_match():
         lambda q, k, v: ring_attention(q, k, v, SEQ_AXIS, causal=True),
         mesh,
         in_specs=(P(None, SEQ_AXIS),) * 3, out_specs=P(None, SEQ_AXIS))
-    g_ring = jax.grad(lambda q, k, v: jnp.sum(jnp.sin(ring(q, k, v))),
-                      argnums=(0, 1, 2))(q, k, v)
+    g_ring = jax.jit(jax.grad(lambda q, k, v: jnp.sum(jnp.sin(ring(q, k, v))),
+                              argnums=(0, 1, 2)))(q, k, v)
     g_ref = jax.grad(
         lambda q, k, v: jnp.sum(jnp.sin(_full_attention(q, k, v, True))),
         argnums=(0, 1, 2))(q, k, v)
@@ -207,15 +207,15 @@ def test_ring_dropout_differs_from_eval_and_is_differentiable():
             mesh,
             in_specs=(P(None, SEQ_AXIS),) * 3,
             out_specs=P(None, SEQ_AXIS))
-        return f(q, q, q)
+        return jax.jit(f)(q, q, q)
 
     train, evl = run(rng), run(None)
     assert float(jnp.max(jnp.abs(train - evl))) > 1e-3
-    g = jax.grad(lambda x: jnp.sum(_shard_map(
+    g = jax.jit(jax.grad(lambda x: jnp.sum(_shard_map(
         lambda q, k, v: ring_attention(q, k, v, SEQ_AXIS, causal=True,
                                        dropout_rate=rate, dropout_rng=rng),
         mesh, in_specs=(P(None, SEQ_AXIS),) * 3,
-        out_specs=P(None, SEQ_AXIS))(x, x, x) ** 2))(q)
+        out_specs=P(None, SEQ_AXIS))(x, x, x) ** 2)))(q)
     assert bool(jnp.all(jnp.isfinite(g)))
 
 

@@ -21,8 +21,8 @@ def _problem(cfg, seed=0, batch=4, seq=16):
     params = tfm.transformer_init(jax.random.key(seed), cfg)
     tokens = jax.random.randint(jax.random.key(1), (batch, seq), 0, cfg.vocab_size)
     targets = jax.random.randint(jax.random.key(2), (batch, seq), 0, cfg.vocab_size)
-    ref_loss, ref_grads = jax.value_and_grad(
-        lambda p: tfm.transformer_loss(cfg, p, tokens, targets))(params)
+    ref_loss, ref_grads = jax.jit(jax.value_and_grad(
+        lambda p: tfm.transformer_loss(cfg, p, tokens, targets)))(params)
     return params, tokens, targets, ref_loss, ref_grads
 
 
@@ -118,20 +118,37 @@ def test_4d_dp_pp_tp_sp():
     _check(step, *prob)
 
 
-def test_tp_sp_ulysses_composes():
-    """Round-5 guard closure: Megatron TP nests with Ulysses — each model
-    column all-to-alls its own head shard over 'seq' (4 heads / T=2 / D=2
-    -> 1 head per device post-scatter), the o projection completes
-    row-parallel. Loss/grads equal single-device autodiff."""
+@pytest.fixture(scope="module")
+def tp_sp_ulysses():
     cfg = dtpp.ModelConfig(dim=32, n_layers=4, n_heads=4, vocab_size=64,
                            ffn_dim=64, max_seq_len=32, arch="gpt2")
-    prob = _problem(cfg)
     mesh = make_mesh(n_pipe=2, n_model=2, n_seq=2)
     step = make_pipeline_step(cfg, mesh,
                               dtpp.ScheduleConfig(name="GPipe",
                                                   n_microbatches=2),
                               sp_attn_impl="ulysses")
+    return step, _problem(cfg)
+
+
+def test_tp_sp_ulysses_composes(tp_sp_ulysses):
+    """Round-5 guard closure: Megatron TP nests with Ulysses — each model
+    column all-to-alls its own head shard over 'seq' (4 heads / T=2 / D=2
+    -> 1 head per device post-scatter), the o projection completes
+    row-parallel. Loss/grads equal single-device autodiff."""
+    step, prob = tp_sp_ulysses
     _check(step, *prob)
+
+
+def test_tp_sp_ulysses_runs_ten_steps_in_one_process(tp_sp_ulysses):
+    """The tick's units hold collectives over 'model' and 'seq' beside the
+    pipe ring's permute. Under XLA:CPU's default schedule ten calls of this
+    step hung in most runs; tests/conftest.py says why and what cures it."""
+    step, (params, tokens, targets, _, _) = tp_sp_ulysses
+    first = step(params, tokens, targets)
+    for _ in range(9):
+        again = step(params, tokens, targets)
+    assert jax.tree.all(jax.tree.map(
+        lambda a, b: bool(jnp.array_equal(a, b)), first, again))
 
 
 def test_4d_dp_free_pp_tp_sp_ulysses_llama():

@@ -1,13 +1,15 @@
 """Stored-activation pipeline backward (remat_backward) correctness.
 
-The tick executor's default backward banks the stage body's vjp residuals
-per slot and replays them (no forward recompute) — matching the reference's
+``remat_backward=False`` banks the stage body's vjp residuals per slot and
+replays them (no forward recompute) — matching the reference's
 torch-autograd semantics (its backward stashes saved tensors, never
 recomputes: ``LLMsDistributedTrainingHelper.py:98-143`` via upstream
-``stage.py:857/937``). These tests pin:
+``stage.py:857/937``). Past one device the default is the rematerialising
+backward, which tests/test_pipeline.py's schedule grid holds to the oracle.
+These tests pin:
 
-- oracle equality of BOTH policies (stored and remat) against single-device
-  autodiff across schedules and depths,
+- oracle equality of the stored policy against single-device autodiff
+  across schedules and depths, and that the default IS the remat program,
 - the residual taint classification (weights are never slot-stored),
 - the compiled-FLOP ordering (remat pays the recompute, stored does not),
 - the unsupported-configuration errors (split-backward schedules, fsdp).
@@ -51,15 +53,10 @@ def assert_matches(loss, grads, ref_loss, ref_grads, tol=1e-5):
 
 
 @pytest.mark.parametrize("name,D,V,M,remat", [
-    # explicit stored (the default resolves to this for non-split, non-fsdp)
     ("GPipe", 2, 1, 4, False),
     ("1F1B", 4, 1, 8, False),
     ("Interleaved1F1B", 2, 2, 4, False),
     ("BFS", 4, 2, 4, False),
-    # explicit remat: the flipped default must not lose the remat path
-    ("GPipe", 2, 1, 4, True),
-    ("1F1B", 4, 1, 8, True),
-    ("Interleaved1F1B", 2, 2, 4, True),
 ])
 def test_policy_matches_single_device(problem, name, D, V, M, remat):
     params, tokens, targets, ref_loss, ref_grads = problem
@@ -70,6 +67,27 @@ def test_policy_matches_single_device(problem, name, D, V, M, remat):
         remat_backward=remat)
     loss, grads = step(params, tokens, targets)
     assert_matches(loss, grads, ref_loss, ref_grads)
+
+
+def _jaxpr_text(name, V, **kw):
+    """The tiny two-stage grad program's jaxpr, as text."""
+    from test_pipeline import _FORM_CFG
+    from distributed_training_with_pipeline_parallelism_tpu.parallel.pipeline import (
+        make_pipeline_grad_fn)
+    fn = make_pipeline_grad_fn(
+        _FORM_CFG, make_mesh(n_pipe=2),
+        dtpp.ScheduleConfig(name=name, n_microbatches=4, n_virtual=V), **kw)
+    params = tfm.transformer_init(jax.random.key(0), _FORM_CFG)
+    tokens = jnp.zeros((4, 8), jnp.int32)
+    return str(jax.make_jaxpr(fn)(params, tokens, tokens))
+
+
+@pytest.mark.parametrize("name,V", [
+    ("GPipe", 1), ("1F1B", 1), ("Interleaved1F1B", 2)])
+def test_default_policy_is_remat_past_one_device(name, V):
+    """So the schedule grid of tests/test_pipeline.py, which runs the
+    default, is the remat policy's oracle grid."""
+    assert _jaxpr_text(name, V) == _jaxpr_text(name, V, remat_backward=True)
 
 
 def test_stored_rejects_split_backward():
@@ -88,14 +106,11 @@ def test_stored_rejects_fsdp():
             fsdp=True, remat_backward=False)
 
 
-def test_split_backward_auto_falls_back(problem):
-    # auto policy on a ZB schedule silently keeps remat — and stays correct
-    params, tokens, targets, ref_loss, ref_grads = problem
-    mesh = make_mesh(n_pipe=2)
-    step = make_pipeline_step(
-        CFG, mesh, dtpp.ScheduleConfig(name="ZBH1", n_microbatches=4))
-    loss, grads = step(params, tokens, targets)
-    assert_matches(loss, grads, ref_loss, ref_grads)
+def test_split_backward_auto_falls_back():
+    # auto policy on a ZB schedule silently keeps remat (the program
+    # tests/test_zero_bubble.py holds to the oracle)
+    assert _jaxpr_text("ZBH1", 1) == _jaxpr_text("ZBH1", 1,
+                                                 remat_backward=True)
 
 
 def test_taint_mask_excludes_weights():

@@ -23,8 +23,8 @@ def _problem(cfg, seed=0, batch=8, seq=6):
     params = tfm.transformer_init(jax.random.key(seed), cfg)
     tokens = jax.random.randint(jax.random.key(1), (batch, seq), 0, cfg.vocab_size)
     targets = jax.random.randint(jax.random.key(2), (batch, seq), 0, cfg.vocab_size)
-    ref_loss, ref_grads = jax.value_and_grad(
-        lambda p: tfm.transformer_loss(cfg, p, tokens, targets))(params)
+    ref_loss, ref_grads = jax.jit(jax.value_and_grad(
+        lambda p: tfm.transformer_loss(cfg, p, tokens, targets)))(params)
     return params, tokens, targets, ref_loss, ref_grads
 
 

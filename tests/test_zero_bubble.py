@@ -3,12 +3,10 @@ executor gradient parity with single-device autodiff, and the bubble win
 over 1F1B under the split-cost model.
 """
 
-import jax
 import numpy as np
 import pytest
 
 import distributed_training_with_pipeline_parallelism_tpu as dtpp
-from distributed_training_with_pipeline_parallelism_tpu.models import transformer as tfm
 from distributed_training_with_pipeline_parallelism_tpu.parallel import native
 from distributed_training_with_pipeline_parallelism_tpu.parallel.mesh import make_mesh
 from distributed_training_with_pipeline_parallelism_tpu.parallel.pipeline import (
@@ -17,7 +15,7 @@ from distributed_training_with_pipeline_parallelism_tpu.parallel.schedules impor
     Action, B, F, W, ScheduleError, compile_schedule, simulated_bubble,
     zb_h1_order)
 
-from test_pipeline import CFG, assert_matches_reference
+from test_pipeline import CFG, assert_matches_reference, problem  # noqa: F401
 
 
 def test_order_structure():
@@ -42,13 +40,15 @@ def test_invalid_configs_raise():
         compile_schedule("ZBH1", 2, 2, 4)  # virtual stages unsupported
 
 
-@pytest.mark.parametrize("D,M", [(2, 2), (2, 4), (4, 4), (4, 8), (8, 8)])
-def test_compile_and_verify(D, M):
+@pytest.mark.parametrize("name,V,D,M", [
+    ("ZBH1", 1, 2, 2), ("ZBH1", 1, 2, 4), ("ZBH1", 1, 4, 4), ("ZBH1", 1, 4, 8),
+    ("ZBH1", 1, 8, 8), ("ZBV", 2, 2, 4), ("ZBV", 2, 4, 8)])
+def test_compile_and_verify(name, V, D, M):
     # compile_schedule runs the symbolic table interpreter internally
-    cs = compile_schedule("ZBH1", D, 1, M)
+    cs = compile_schedule(name, D, V, M)
     assert cs.split_backward
-    # every W is scheduled at or after its B (s > 0)
-    for s in range(1, D):
+    # every W is scheduled after its B (stage 0 has no B)
+    for s in range(1, D * V):
         for m in range(M):
             assert cs.ticks[Action(s, W, m)] > cs.ticks[Action(s, B, m)]
 
@@ -77,49 +77,22 @@ def test_native_engine_matches_python():
 
 
 @pytest.mark.parametrize("D,M", [(2, 4), (4, 8)])
-def test_executor_matches_single_device(D, M):
-    params = tfm.transformer_init(jax.random.key(0), CFG)
-    tokens = jax.random.randint(jax.random.key(1), (16, 6), 0, CFG.vocab_size)
-    targets = jax.random.randint(jax.random.key(2), (16, 6), 0, CFG.vocab_size)
-    ref_loss, ref_grads = jax.value_and_grad(
-        lambda p: tfm.transformer_loss(CFG, p, tokens, targets))(params)
-    mesh = make_mesh(n_pipe=D)
+def test_executor_matches_single_device(problem, D, M):
+    params, tokens, targets, ref_loss, ref_grads = problem
     step = make_pipeline_step(
-        CFG, mesh, dtpp.ScheduleConfig(name="ZBH1", n_microbatches=M))
-    loss, grads = step(params, tokens, targets)
-    assert_matches_reference(loss, grads, ref_loss, ref_grads)
+        CFG, make_mesh(n_pipe=D),
+        dtpp.ScheduleConfig(name="ZBH1", n_microbatches=M))
+    assert_matches_reference(*step(params, tokens, targets), ref_loss,
+                             ref_grads)
 
 
-@pytest.mark.parametrize("D,M", [(2, 4), (4, 8)])
-def test_zbv_executor_matches_single_device(D, M):
-    # ZB-V parity mirror of the ZB-H1 test above: the vshape executor
-    # (2 chunks per device, split backward, bidirectional routing) must
-    # reproduce single-device autodiff exactly. M >= 2D per the ZBV
-    # contract; CFG's 8 layers split evenly over 2D chunks.
-    params = tfm.transformer_init(jax.random.key(0), CFG)
-    tokens = jax.random.randint(jax.random.key(1), (16, 6), 0, CFG.vocab_size)
-    targets = jax.random.randint(jax.random.key(2), (16, 6), 0, CFG.vocab_size)
-    ref_loss, ref_grads = jax.value_and_grad(
-        lambda p: tfm.transformer_loss(CFG, p, tokens, targets))(params)
-    mesh = make_mesh(n_pipe=D)
+def test_zbh1_with_data_parallel(problem):
+    params, tokens, targets, ref_loss, ref_grads = problem
     step = make_pipeline_step(
-        CFG, mesh,
-        dtpp.ScheduleConfig(name="ZBV", n_microbatches=M, n_virtual=2))
-    loss, grads = step(params, tokens, targets)
-    assert_matches_reference(loss, grads, ref_loss, ref_grads)
-
-
-def test_zbh1_with_data_parallel():
-    params = tfm.transformer_init(jax.random.key(0), CFG)
-    tokens = jax.random.randint(jax.random.key(1), (16, 6), 0, CFG.vocab_size)
-    targets = jax.random.randint(jax.random.key(2), (16, 6), 0, CFG.vocab_size)
-    ref_loss, ref_grads = jax.value_and_grad(
-        lambda p: tfm.transformer_loss(CFG, p, tokens, targets))(params)
-    mesh = make_mesh(n_pipe=2, n_data=2)
-    step = make_pipeline_step(
-        CFG, mesh, dtpp.ScheduleConfig(name="ZBH1", n_microbatches=2))
-    loss, grads = step(params, tokens, targets)
-    assert_matches_reference(loss, grads, ref_loss, ref_grads)
+        CFG, make_mesh(n_pipe=2, n_data=2),
+        dtpp.ScheduleConfig(name="ZBH1", n_microbatches=2))
+    assert_matches_reference(*step(params, tokens, targets), ref_loss,
+                             ref_grads)
 
 
 @pytest.mark.parametrize("name,V,cases", [
