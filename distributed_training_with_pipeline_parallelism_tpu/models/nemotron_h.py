@@ -85,10 +85,10 @@ FLOAT32_LEAVES = frozenset(("A_log", "dt_bias", "D", "router"))
 
 #: what a layer's backward holds at once, in multiples of its named
 #: products' bytes, where it is not the 0.75 of a layer of plain products
-#: (the Mamba-2 scan's float32 decay tiles: 1.9 GB at the benchmark's shapes;
-#: a Gated DeltaNet layer's float32 q, k, v, chunk tiles and states: 2.82 GB
-#: for 0.202 GB of products, one row of 8192, by the described v5e's
-#: compiler, PR 40)
+#: (Mamba-2: 1.9 GB, calibrated on the jax.numpy scan's float32 decay tiles
+#: and kept for the kernel pair, whose layer peaks 0.47 GB lower; a Gated
+#: DeltaNet layer's float32 q, k, v, chunk tiles and states: 2.82 GB for
+#: 0.202 GB of products, one row of 8192; both by the described v5e's compiler)
 WORKING_SET = {"mamba": 5.7, "gdn": 14.0}
 
 _log = logging.getLogger(__name__)

@@ -12,16 +12,15 @@ One mixer, as the ``nemotron_h`` family's ``config.json`` declares it
     y_t = S_t C_t + D x_t
     out = RMSNorm_groups(y * silu(z)) W_out      groups of HP / G channels
 
-:func:`ssd_chunked` computes the recurrence in the chunked matrix form: inside
-a chunk a masked ``C B^T`` product weighted by the decay between the two
-positions, between chunks a ``lax.scan`` over the chunks' states. ``dt``,
-``A``, the cumulative log-decay and the carried state are float32 whatever
-the activations' dtype; the matrix products take the activations' dtype and
-accumulate in float32. The backward pass is autodiff's.
+:func:`ssd_chunked` computes the recurrence in the chunked matrix form, a
+pair of Pallas TPU kernels with a hand-written backward (:mod:`.pallas_ssd`):
+inside a chunk a masked ``C B^T`` product weighted by the decay between the
+two positions, between chunks the float32 state carried in VMEM. The matrix
+products take the activations' dtype and accumulate in float32.
 
 Two profiler regions (``utils/profiling.py:HYBRID_REGIONS``): ``model/ssm_scan``
-is :func:`ssd_chunked`, what a kernel would one day replace; the rest of the
-mixer is the caller's ``model/ssm``.
+is :func:`ssd_chunked`, both kernels in every phase; the rest of the mixer is
+the caller's ``model/ssm``.
 """
 
 from __future__ import annotations
@@ -34,6 +33,7 @@ import jax.numpy as jnp
 
 from .layers import (linear_apply, linear_init, named_product,
                      rms_norm_init)
+from .pallas_ssd import ssd_scan
 
 
 def mamba2_init(key: jax.Array, dim: int, n_heads: int, head_dim: int,
@@ -83,53 +83,14 @@ def causal_conv1d(x: jax.Array, w: jax.Array,
 @jax.named_scope("model/ssm_scan")
 def ssd_chunked(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
                 C: jax.Array, chunk: int) -> jax.Array:
-    """The state-space recurrence in chunked matrix form.
+    """The state-space recurrence in chunked matrix form, ``S_0 = 0``, as the
+    kernel pair (:func:`.pallas_ssd.ssd_scan`).
 
     ``x`` [b, T, H, P], ``dt`` [b, T, H] (float32, after softplus), ``A`` [H]
     (float32, negative), ``B``/``C`` [b, T, G, N]; ``T`` a multiple of
     ``chunk``. Returns ``y`` [b, T, H, P] in ``x``'s dtype, without the
     ``D x`` skip."""
-    b, T, H, P = x.shape
-    G, N = B.shape[2:]
-    J, nc, f32 = H // G, T // chunk, jnp.float32
-    xd = (x.astype(f32) * dt[..., None]).astype(x.dtype)      # dt_t x_t
-    xd = xd.reshape(b, nc, chunk, G, J, P)
-    Bc, Cc = (m.reshape(b, nc, chunk, G, N) for m in (B, C))
-    # log-decay a[t] = dt_t A, summed inside the chunk: cum[t] = sum_{s<=t} a[s]
-    cum = jnp.cumsum((dt * A).reshape(b, nc, chunk, G, J), axis=2)
-    cum_h = cum.transpose(0, 1, 3, 4, 2)                       # [b,nc,G,J,l]
-    total = cum_h[..., -1]                                     # [b,nc,G,J]
-
-    # inside a chunk: y_t += sum_{s <= t} (C_t . B_s) exp(cum_t - cum_s) xd_s
-    scores = jnp.einsum("bclgn,bcsgn->bcgls", Cc, Bc,
-                        preferred_element_type=f32)
-    lag = cum_h[..., :, None] - cum_h[..., None, :]            # [b,nc,G,J,l,s]
-    decay = jnp.exp(jnp.where(jnp.tril(jnp.ones((chunk, chunk), bool)),
-                              lag, -jnp.inf))
-    y = jnp.einsum("bcgjls,bcsgjp->bclgjp",
-                   (scores[:, :, :, None] * decay).astype(x.dtype), xd,
-                   preferred_element_type=f32)
-
-    # each chunk's own state at its end: sum_s exp(total - cum_s) xd_s (x) B_s
-    to_end = jnp.exp(total[:, :, None] - cum)                  # [b,nc,s,G,J]
-    own = jnp.einsum("bcsgn,bcsgjp->bcgjpn", Bc,
-                     (xd.astype(f32) * to_end[..., None]).astype(x.dtype),
-                     preferred_element_type=f32)
-
-    # between chunks: S_c = exp(total_c) S_{c-1} + own_c, carried in float32
-    def carry(state, chunk_in):
-        own_c, total_c = chunk_in
-        return jnp.exp(total_c)[..., None, None] * state + own_c, state
-
-    _, before = jax.lax.scan(
-        carry, jnp.zeros((b, G, J, P, N), f32),
-        (jnp.moveaxis(own, 1, 0), jnp.moveaxis(total, 1, 0)))
-    before = jnp.moveaxis(before, 0, 1)        # the state entering each chunk
-    # what the entering state adds: exp(cum_t) C_t . S
-    y = y + jnp.exp(cum)[..., None] * jnp.einsum(
-        "bclgn,bcgjpn->bclgjp", Cc, before.astype(x.dtype),
-        preferred_element_type=f32)
-    return y.reshape(b, T, H, P).astype(x.dtype)
+    return ssd_scan(x, dt, A, B, C, chunk)
 
 
 def gated_group_rms_norm(scale: jax.Array, y: jax.Array, z: jax.Array,

@@ -142,3 +142,31 @@ def pytest_runtest_protocol(item, nextitem):
         yield
     finally:
         faulthandler.cancel_dump_traceback_later()
+
+
+def _pallas_kernels(jaxpr, outer=""):
+    """``(kernel function, phase, region)`` of every ``pallas_call`` in
+    ``jaxpr`` and the jaxprs inside it, the region and phase read by
+    ``utils/profiling.py:classify`` from the name stacks that lead to it
+    (what the compiled instruction's ``op_name`` is made of)."""
+    from distributed_training_with_pipeline_parallelism_tpu.utils.profiling import (
+        classify)
+    found = []
+    for eqn in jaxpr.eqns:
+        where = "/".join(p for p in (outer, str(eqn.source_info.name_stack))
+                         if p)
+        if eqn.primitive.name == "pallas_call":
+            kernel = eqn.params["jaxpr"].debug_info.func_src_info.split()[0]
+            found.append((kernel, *classify(where + "/pallas_call")))
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (list, tuple)) else [param]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    found += _pallas_kernels(sub, where)
+    return found
+
+
+@pytest.fixture
+def pallas_kernels():
+    """:func:`_pallas_kernels`: where a step runs each Pallas kernel."""
+    return _pallas_kernels

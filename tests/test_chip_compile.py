@@ -24,7 +24,8 @@ from distributed_training_with_pipeline_parallelism_tpu.models.gpt2 import (
 from distributed_training_with_pipeline_parallelism_tpu.models.transformer import (
     transformer_init)
 from distributed_training_with_pipeline_parallelism_tpu.ops import (
-    gated_deltanet, layers, pallas_attention, pallas_xent)
+    gated_deltanet, layers, mamba2, pallas_attention, pallas_ssd,
+    pallas_xent)
 from distributed_training_with_pipeline_parallelism_tpu.parallel.mesh import (
     make_mesh)
 from distributed_training_with_pipeline_parallelism_tpu.parallel.pipeline import (
@@ -72,9 +73,10 @@ def one_chip(topo):
 @pytest.fixture
 def mosaic(monkeypatch):
     """The kernels ask ``jax.devices()`` whether to interpret, and here that
-    is the CPU: steer them to the Mosaic lowering (``pallas_xent`` and
-    ``gated_deltanet`` import the predicate by name, so every module)."""
-    for mod in (pallas_attention, pallas_xent, gated_deltanet):
+    is the CPU: steer them to the Mosaic lowering (``pallas_xent``,
+    ``gated_deltanet`` and ``pallas_ssd`` import the predicate by name, so
+    every module)."""
+    for mod in (pallas_attention, pallas_xent, gated_deltanet, pallas_ssd):
         monkeypatch.setattr(mod, "_use_interpret", lambda: False)
 
 
@@ -193,6 +195,36 @@ def test_delta_rule_kernels_compile(one_chip, mosaic, caplog, key_heads):
             f"head(s) read") in caplog.text
 
 
+def test_ssd_scan_kernels_compile(one_chip, mosaic, caplog):
+    """The Mamba-2 kernel pair at the ``nemotron`` cell's shapes, 2 x 8192
+    x 64 heads of 64 in 8 groups of 128, chunk 128, bf16: the forward and
+    the hand-written backward (a group's eight heads a grid step, their 512
+    columns in four windows of 128 lanes) compile for Mosaic, and the call
+    says what runs."""
+    x = jax.ShapeDtypeStruct((2, 8192, 64, 64), jnp.bfloat16,
+                             sharding=one_chip)
+    dt = jax.ShapeDtypeStruct((2, 8192, 64), jnp.float32, sharding=one_chip)
+    a = jax.ShapeDtypeStruct((64,), jnp.float32, sharding=one_chip)
+    bc = jax.ShapeDtypeStruct((2, 8192, 8, 128), jnp.bfloat16,
+                              sharding=one_chip)
+
+    def loss(*args):
+        return mamba2.ssd_chunked(*args, 128).astype(jnp.float32).sum()
+
+    with caplog.at_level("INFO"):
+        text = jax.jit(jax.value_and_grad(loss, argnums=range(5))).lower(
+            x, dt, a, bc, bc).compile().as_text()
+    kernels = []
+    for body in re.findall(r'"body":"([A-Za-z0-9+/=]+)"', text):
+        kernels += re.findall(rb"_ssd_(?:fwd|bwd)_kernel",
+                              base64.b64decode(body))
+    assert sorted(set(kernels)) == [b"_ssd_bwd_kernel", b"_ssd_fwd_kernel"]
+    assert text.count("tpu_custom_call") == 2
+    assert ("2 x 8192 x 64 heads x 64, 8 groups x 128, chunk 128, grid 2 x "
+            "8 x 64: 8 heads a step (512 columns, 4 windows of 128 lanes)"
+            ) in caplog.text
+
+
 def test_fused_xent_compiles(one_chip, mosaic):
     logits = jax.ShapeDtypeStruct((8192, 50257), jnp.bfloat16,
                                   sharding=one_chip)
@@ -203,12 +235,13 @@ def test_fused_xent_compiles(one_chip, mosaic):
     assert "tpu_custom_call" in text
 
 
-def test_nemotron_h_layers_compile_at_published_widths(one_chip):
+def test_nemotron_h_layers_compile_at_published_widths(one_chip, mosaic):
     """One Mamba-2 layer and one expert layer of the benchmark's
     nemotron-twotower-30b-a3b configuration, forward and backward at batch 1
-    x seq 8192: the chunked scan's einsums and the held experts' gated
-    products are accepted by the chip's compiler. The whole 9-layer
-    step at batch 2 is rehearsed by hand with :func:`lower_train_step`."""
+    x seq 8192: the Mamba-2 mixer around the scan's kernel pair and the held
+    experts' gated products are accepted by the chip's compiler. The whole
+    9-layer step at batch 2 is rehearsed by hand with
+    :func:`lower_train_step`."""
     from distributed_training_with_pipeline_parallelism_tpu.models import (
         nemotron_h)
     from distributed_training_with_pipeline_parallelism_tpu.utils.config import (
@@ -467,7 +500,9 @@ def test_joyai_llm_flash_train_step_fits_one_chip(topo, mosaic, caplog):
      (13, 22, 3.406, "11:experts_w1 11:experts_w3 10:attn_q 10:attn_k "
       "10:attn_v 9:experts_w1 9:experts_w3 8:shortconv_in 7:experts_w1 "
       "7:experts_w3 6:shortconv_in 2:attn_q 2:attn_k")),
-    ("stage", 15.066, (1, 1), "2 x 8192 x 32 x 128",
+    # the Mamba-2 scan as a kernel pair: the same grants, 1.33 GB less
+    # counted (15.066 with the jax.numpy scan's decay tiles)
+    ("stage", 13.738, (1, 1), "2 x 8192 x 32 x 128",
      (8, 15, 1.340, "8:experts_w1 8:mlp_up 7:mamba_in 6:mlp_up 5:attn_q "
       "5:attn_k 5:attn_v 3:mlp_up")),
     # PR 40: 15.59 GB with nothing granted, 15.93 had the Gated DeltaNet

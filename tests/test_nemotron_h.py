@@ -119,18 +119,80 @@ def literal_recurrence(x, dt, A, B, C):
     return y
 
 
-@pytest.mark.parametrize("chunk", [4, 16], ids=["chunk4", "one-chunk"])
-def test_chunked_scan_equals_literal_recurrence(chunk):
-    rng = np.random.default_rng(0)
-    b, T, H, P, G, N = 2, 16, 4, 3, 2, 5
-    x = rng.normal(size=(b, T, H, P)).astype(np.float32)
-    dt = rng.uniform(0.01, 0.5, (b, T, H)).astype(np.float32)
+def scanned_recurrence(x, dt, A, B, C):
+    """The same recurrence as a ``lax.scan`` over time steps, float32 at
+    HIGHEST: what ``jax.grad`` differentiates for the kernels' backward."""
+    H, G = x.shape[2], B.shape[2]
+    B, C = (jnp.repeat(m, H // G, axis=2) for m in (B, C))
+
+    def step(S, t):
+        x_t, dt_t, B_t, C_t = t
+        S = (jnp.exp(dt_t * A)[..., None, None] * S
+             + (dt_t[..., None] * x_t)[..., None] * B_t[:, :, None, :])
+        return S, jnp.einsum("bhpn,bhn->bhp", S, C_t, precision="highest")
+
+    S0 = jnp.zeros((x.shape[0], H, x.shape[3], B.shape[3]))
+    _, y = jax.lax.scan(step, S0, tuple(jnp.moveaxis(m, 1, 0)
+                                        for m in (x, dt, B, C)))
+    return jnp.moveaxis(y, 0, 1)
+
+
+# (positions, heads, head width, groups, state, chunk): chunks after chunks,
+# one chunk, many chunks, one head a group, every head in one group
+SCAN_CASES = {
+    "chunk4": (16, 4, 3, 2, 5, 4), "one-chunk": (16, 4, 3, 2, 5, 16),
+    "many-chunks": (64, 4, 3, 2, 5, 4), "a-head-a-group": (16, 4, 3, 4, 5, 4),
+    "one-group": (24, 6, 4, 1, 8, 8)}
+
+
+def scan_inputs(case, seed=0):
+    T, H, P, G, N, _ = SCAN_CASES[case]
+    rng = np.random.default_rng(seed)
+    x = rng.normal(size=(2, T, H, P)).astype(np.float32)
+    dt = rng.uniform(0.01, 0.5, (2, T, H)).astype(np.float32)
     A = -rng.uniform(0.5, 4.0, (H,)).astype(np.float32)
-    B, C = (rng.normal(size=(b, T, G, N)).astype(np.float32) for _ in "BC")
+    B, C = (rng.normal(size=(2, T, G, N)).astype(np.float32) for _ in "BC")
+    return x, dt, A, B, C
+
+
+@pytest.mark.parametrize("chunk", sorted(SCAN_CASES))
+def test_chunked_scan_equals_literal_recurrence(chunk):
+    """The kernel pair's forward (interpreted here) in float32 against the
+    recurrence one step at a time: a state rounded to bf16 between chunks
+    reads some 1e-3 off."""
+    x, dt, A, B, C = scan_inputs(chunk)
     got = mamba2.ssd_chunked(*(jnp.asarray(a) for a in (x, dt, A, B, C)),
-                             chunk)
+                             SCAN_CASES[chunk][-1])
     np.testing.assert_allclose(got, literal_recurrence(x, dt, A, B, C),
                                rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("case", ["chunk4", "one-chunk", "many-chunks",
+                                  "one-group"])
+def test_scan_gradients_equal_the_recurrences(case):
+    """The hand-written backward: the gradients of ``x``, ``dt``, ``A``,
+    ``B`` and ``C`` against ``jax.grad`` of the recurrence as a plain
+    ``lax.scan`` (``dt`` reaches ``y`` through ``x dt`` and through the
+    log-decay ``dt A``; ``A`` through the decay alone, summed over rows and
+    positions)."""
+    args = tuple(jnp.asarray(a) for a in scan_inputs(case, seed=1))
+    cot = jax.random.normal(jax.random.key(2), args[0].shape)
+
+    def chunked(*a):
+        return jnp.vdot(mamba2.ssd_chunked(*a, SCAN_CASES[case][-1]), cot)
+
+    def scanned(*a):
+        return jnp.vdot(scanned_recurrence(*a), cot)
+
+    with jax.default_matmul_precision("highest"):
+        got, g_got = jax.jit(jax.value_and_grad(
+            chunked, argnums=range(5)))(*args)
+        want, g_want = jax.jit(jax.value_and_grad(
+            scanned, argnums=range(5)))(*args)
+    assert abs(float(got - want)) < 1e-5 * abs(float(want))
+    for name, a, b in zip("x dt A B C".split(), g_got, g_want):
+        rel = float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
+        assert rel < 1e-5, (name, rel)
 
 
 def test_the_shares_add_up_to_the_uncut_layer(ref):
@@ -270,14 +332,15 @@ def trained(ref):
     opt_state = train.init_opt_state(opt, params, mesh)
     step = train.make_train_step(cfg, mesh, sched, opt)
     x, y = batch(24)
+    jaxpr = jax.make_jaxpr(step)(params, opt_state, x, y)
     step = step.lower(params, opt_state, x, y).compile()  # once, and kept
     params, opt_state, loss = step(params, opt_state, x, y)
     after, _, _ = step(params, opt_state, x, y)  # the first has lr 0
-    return cfg, before, after, float(loss), step, (x, y)
+    return cfg, before, after, float(loss), step, (x, y), jaxpr
 
 
 def test_train_step_on_the_normal_path(trained, compiled):
-    cfg, before, after, loss, _, (x, y) = trained
+    cfg, before, after, loss, _, (x, y), _ = trained
     want = float(compiled.ref_loss(before, x, y))
     assert abs(loss - want) / want < 2e-3
     moved = jax.tree.map(lambda a, b: float(jnp.abs(a - b).max()),
@@ -329,7 +392,13 @@ def test_remat_layers_changes_no_number(compiled):
         np.testing.assert_allclose(u, v, rtol=1e-5, atol=1e-7)
 
 
-def test_compiled_step_names_the_four_regions(trained):
+def test_compiled_step_names_the_four_regions(trained, pallas_kernels):
+    """Every region of the stack is named in the compiled step, and the
+    scan's kernel pair runs inside ``model/ssm_scan`` (the region the
+    benchmark's ``model.ssm_scan_share_pct`` reads): the forward kernel in
+    the forward and in ``remat_layers``' recompute, the backward kernel in
+    the backward; in the compiled step the interpreted kernels' grid loops,
+    the region's only loops, fall in the same three phases."""
     names = re.findall(r'op_name="([^"]*)"', trained[4].as_text())
     read = {classify(n) for n in names}
     # the latent-attention region is tests/test_latent_attention.py's, the
@@ -341,6 +410,16 @@ def test_compiled_step_names_the_four_regions(trained):
             assert any(r == region for _, r in read), region
     assert ("backward", "model/ssm_scan") in read
     assert ("recompute", "model/moe") in read  # remat_layers: a second run
+    assert sorted(set(pallas_kernels(trained[6].jaxpr))) == [
+        ("_ssd_bwd_kernel", "backward", "model/ssm_scan"),
+        ("_ssd_fwd_kernel", "forward", "model/ssm_scan"),
+        ("_ssd_fwd_kernel", "recompute", "model/ssm_scan")]
+    loops = {classify(name) for name in re.findall(
+        r'^\s*(?:ROOT )?%?while[\w.\-]* = [^\n]*op_name="([^"]*)"',
+        trained[4].as_text(), re.M)}
+    assert {(p, r) for p, r in loops if r == "model/ssm_scan"} == {
+        ("forward", "model/ssm_scan"), ("recompute", "model/ssm_scan"),
+        ("backward", "model/ssm_scan")}
 
 
 @pytest.mark.parametrize("op_name,expected", [

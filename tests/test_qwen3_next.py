@@ -325,34 +325,15 @@ def test_compiled_step_names_the_regions(trained):
                    for _, r in read)
 
 
-def _kernels(jaxpr, outer=""):
-    """``(kernel function, phase, region)`` of every ``pallas_call`` in
-    ``jaxpr`` and the jaxprs inside it, the region and phase read by
-    ``classify`` from the name stacks that lead to it (what the compiled
-    instruction's ``op_name`` is made of)."""
-    found = []
-    for eqn in jaxpr.eqns:
-        where = "/".join(p for p in (outer, str(eqn.source_info.name_stack))
-                         if p)
-        if eqn.primitive.name == "pallas_call":
-            kernel = eqn.params["jaxpr"].debug_info.func_src_info.split()[0]
-            found.append((kernel, *classify(where + "/pallas_call")))
-        for param in eqn.params.values():
-            for sub in param if isinstance(param, (list, tuple)) else [param]:
-                sub = getattr(sub, "jaxpr", sub)
-                if hasattr(sub, "eqns"):
-                    found += _kernels(sub, where)
-    return found
-
-
-def test_both_kernels_run_inside_the_scan_region_in_every_phase(trained):
+def test_both_kernels_run_inside_the_scan_region_in_every_phase(
+        trained, pallas_kernels):
     """The step runs the forward kernel in the forward and in
     ``remat_layers``' recompute and the backward kernel in the backward,
     every call inside ``model/gdn_scan`` (the region the benchmark's
     ``model.gdn_scan_share_pct`` and ``kernels.gdn_scan_roofline_pct``
     read); in the compiled step the interpreted kernels' grid loops, the
     region's only loops, fall in the same three phases."""
-    assert sorted(set(_kernels(trained[6].jaxpr))) == [
+    assert sorted(set(pallas_kernels(trained[6].jaxpr))) == [
         ("_gdn_bwd_kernel", "backward", "model/gdn_scan"),
         ("_gdn_fwd_kernel", "forward", "model/gdn_scan"),
         ("_gdn_fwd_kernel", "recompute", "model/gdn_scan")]
