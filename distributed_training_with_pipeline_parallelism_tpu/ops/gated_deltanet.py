@@ -28,21 +28,26 @@ so every [n, n] product serves both (zero between heads), and keeps each
 head's [dk, dv] float32 state in VMEM scratch across the chunks:
 
 - forward (``_gdn_fwd_kernel``): per chunk ``gamma = cumsum(g)``, the
-  masked ``A``, its ``(I + A)^{-1}`` by forward substitution by halves, the
-  updates, ``o`` and the next state; the call the backward needs also
-  writes every chunk's entering state;
+  masked ``A``, its ``T = (I + A)^{-1}`` by forward substitution by halves,
+  the updates, ``o`` and the next state; the call the backward needs also
+  writes every chunk's entering state and its ``T``;
 - backward (``_gdn_bwd_kernel``): the chunks in reverse, each chunk's
-  forward again from its saved entering state, then ``dq, dk, dv, dg,
-  dbeta`` (``dg`` takes each exponent's gradient by the ``g`` it sums, not
-  as a difference of cumulative sums) and the entering state's gradient,
-  carried in VMEM scratch.
+  forward again from its saved entering state and ``T`` up to the updates
+  (no inverse, no ``o``, no leaving state: only what the gradients read),
+  then ``dq, dk, dv, dg, dbeta`` (``dg`` takes each exponent's gradient by
+  the ``g`` it sums, not as a difference of cumulative sums) and the
+  entering state's gradient, carried in VMEM scratch.
 
-The backward saves the inputs and the entering states: b x H x T / 64 x dk x
-dv float32, 537 MB a layer at 2 x 8192 x 32 heads x 128 x 128, alive only
-inside that layer's backward (``remat_layers`` recomputes the layer). A key
-head is read once for the value heads of a step it serves. Everything is
-float32 with its products at ``Precision.HIGHEST`` (a TPU otherwise rounds
-float32 operands to bf16): the state is carried whole and read whole.
+The backward saves the inputs, the entering states (b x H x T / 64 x dk x
+dv float32, 537 MB a layer at 2 x 8192 x 32 heads x 128 x 128) and every
+grid step's ``T`` (b x H / 2 x T / 64 x 128 x 128 float32, 268 MB there),
+alive only inside that layer's backward (``remat_layers`` recomputes the
+layer, so the recompute builds the inverses the backward reads). ``T``
+depends on a chunk's ``k``, ``g`` and ``beta`` alone, not on the state
+carried between chunks. A key head is read once for the value heads of a
+step it serves. Everything is float32 with its products at
+``Precision.HIGHEST`` (a TPU otherwise rounds float32 operands to bf16): the
+state is carried whole and read whole.
 
 Two profiler regions (``utils/profiling.py:HYBRID_REGIONS``):
 ``model/gdn_scan`` is :func:`chunked_delta_rule`, both kernels in every
@@ -171,16 +176,18 @@ def _stacked(xs) -> jax.Array:
     return jnp.concatenate(xs, axis=0)
 
 
-def _chunk(q, k, v, g, beta, states) -> types.SimpleNamespace:
+def _chunk(q, k, v, g, beta, states, t=None) -> types.SimpleNamespace:
     """One chunk of ``len(states)`` value heads at once, each head's
     :data:`CHUNK` rows after the previous head's (two heads fill the MXU's
     128 rows: one product where each head would take one of half the
     depth): ``q``, ``k`` [n, dk], ``v`` [n, dv] float32 tiles, ``g``,
-    ``beta`` [1, n] rows and the heads' entering states [dk, dv]. Every
-    [n, n] quantity is zero between heads (``same``), so the products over
-    the stack are each head's own. Returns every quantity the backward reads
-    again. Exponents are masked BEFORE ``exp`` (``gamma_i - gamma_j`` for
-    ``i < j`` is positive and overflows)."""
+    ``beta`` [1, n] rows, the heads' entering states [dk, dv] and, where the
+    caller has it, ``t`` = ``(I + A)^{-1}`` [n, n] (built here otherwise).
+    Every [n, n] quantity is zero between heads (``same``), so the products
+    over the stack are each head's own. Returns every quantity the backward
+    reads again, up to the updates ``upd``; :func:`_leaving` makes ``o`` and
+    the leaving states from them. Exponents are masked BEFORE ``exp``
+    (``gamma_i - gamma_j`` for ``i < j`` is positive and overflows)."""
     n = k.shape[0]
     i = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
     j = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
@@ -192,7 +199,9 @@ def _chunk(q, k, v, g, beta, states) -> types.SimpleNamespace:
     decay = jnp.where(below, jnp.exp(jnp.where(below, gamma - grow, 0.0)),
                       0.0)
     kk = _abt(k, k)
-    t = _unit_lower_inverse(jnp.where(strict, beta * decay * kk, 0.0), i, j)
+    if t is None:
+        t = _unit_lower_inverse(jnp.where(strict, beta * decay * kk, 0.0), i,
+                                j)
     eg = jnp.exp(gamma)
     vb, kb = beta * v, beta * eg * k
     w, u = _ab(t, kb), _ab(t, vb)
@@ -203,25 +212,30 @@ def _chunk(q, k, v, g, beta, states) -> types.SimpleNamespace:
     ke = tail * k
     qk = _abt(q, k)
     p, qg = decay * qk, eg * q
-    heads = range(len(states))
     upd = _stacked([_rows(u, h) - _ab(_rows(w, h), states[h])
-                    for h in heads])                      # U = u - w S0
+                    for h in range(len(states))])         # U = u - w S0
     return types.SimpleNamespace(
         same=same, eye=eye, below=below, strict=strict, upper=i < j,
         beta=beta, decay=decay, kk=kk, t=t, eg=eg, vb=vb, kb=kb, w=w,
-        upd=upd, qk=qk, p=p, qg=qg, total=total, tail=tail, ke=ke,
-        o=_stacked([_ab(_rows(qg, h), states[h]) for h in heads])
-        + _ab(p, upd),
-        states=[jnp.exp(_rows(total, h)[:1]) * states[h]
-                + _atb(_rows(ke, h), _rows(upd, h)) for h in heads])
+        upd=upd, qk=qk, p=p, qg=qg, total=total, tail=tail, ke=ke)
 
 
-def _chunk_grads(q, k, v, g, beta, states, do, dsn):
-    """:func:`_chunk`'s gradients: ``dq``, ``dk``, ``dv`` tiles, ``dg``,
-    ``dbeta`` rows and each head's entering state's ``ds``, from the
-    chunk's inputs, the entering states, ``do`` and the leaving states'
-    ``dsn``."""
-    x = _chunk(q, k, v, g, beta, states)
+def _leaving(x: types.SimpleNamespace, states):
+    """``o`` = ``qg S0 + p U`` and each head's leaving state ``e^{total} S0
+    + ke^T U`` from :func:`_chunk`'s ``x`` and the entering ``states``."""
+    heads = range(len(states))
+    o = _stacked([_ab(_rows(x.qg, h), states[h]) for h in heads]) + _ab(
+        x.p, x.upd)
+    return o, [jnp.exp(_rows(x.total, h)[:1]) * states[h]
+               + _atb(_rows(x.ke, h), _rows(x.upd, h)) for h in heads]
+
+
+def _chunk_grads(q, k, v, g, beta, states, do, dsn, t):
+    """:func:`_leaving`'s gradients through :func:`_chunk`: ``dq``, ``dk``,
+    ``dv`` tiles, ``dg``, ``dbeta`` rows and each head's entering state's
+    ``ds``, from the chunk's inputs, the entering states, ``do``, the
+    leaving states' ``dsn`` and the chunk's ``T`` the forward built."""
+    x = _chunk(q, k, v, g, beta, states, t)
     heads = range(len(states))
     # S' = e^{total} S + ke^T U,  o = qg S + p U (each head's S)
     et = [jnp.exp(_rows(x.total, h)[:1]) for h in heads]
@@ -294,9 +308,9 @@ def _tiles(c, heads, dk, q_ref, k_ref, v_ref, g_ref, beta_ref):
 
 def _gdn_fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, *rest):
     """One chunk of the step's value heads in one row, chunks in order:
-    ``o`` and, where asked, the entering states; the states stay in the
-    float32 scratch."""
-    *entering_ref, state_ref = rest
+    ``o`` and, where asked, the entering states and the chunk's ``T``; the
+    states stay in the float32 scratch."""
+    *kept_refs, state_ref = rest
     c, heads = pl.program_id(2), state_ref.shape[0]
 
     @pl.when(c == 0)
@@ -306,19 +320,24 @@ def _gdn_fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, *rest):
     states = [state_ref[h] for h in range(heads)]
     x = _chunk(*_tiles(c, heads, state_ref.shape[1], q_ref, k_ref, v_ref,
                        g_ref, beta_ref), states)
-    _unstack(o_ref, x.o, heads)
+    o, leaving = _leaving(x, states)
+    _unstack(o_ref, o, heads)
+    if kept_refs:
+        entering_ref, t_ref = kept_refs
+        t_ref[0, 0, 0] = x.t
+        for h in range(heads):
+            entering_ref[0, h, 0] = states[h]
     for h in range(heads):
-        if entering_ref:
-            entering_ref[0][0, h, 0] = states[h]
-        state_ref[h] = x.states[h]
+        state_ref[h] = leaving[h]
 
 
 def _gdn_bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, s_ref, do_ref,
-                    dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, ds_ref):
+                    t_ref, dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, ds_ref):
     """One chunk of the step's value heads in one row, chunks in reverse:
-    the chunk's forward again from the saved entering states, then its
-    gradients; the gradients of the states leaving the chunk stay in the
-    float32 scratch and become the entering states'."""
+    the chunk's forward again from the saved entering states and ``T`` up
+    to the updates, then its gradients; the gradients of the states leaving
+    the chunk stay in the float32 scratch and become the entering
+    states'."""
     step, heads = pl.program_id(2), ds_ref.shape[0]
     c = pl.num_programs(2) - 1 - step
 
@@ -330,7 +349,7 @@ def _gdn_bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, s_ref, do_ref,
     dq, dk, dv, dg, dbeta, ds = _chunk_grads(
         *_tiles(c, heads, dk_width, q_ref, k_ref, v_ref, g_ref, beta_ref),
         [s_ref[0, h, 0] for h in range(heads)], _stack(do_ref, heads),
-        [ds_ref[h] for h in range(heads)])
+        [ds_ref[h] for h in range(heads)], t_ref[0, 0, 0])
     keys = dq_ref.shape[-1] // dk_width
     for ref, x in ((dq_ref, dq), (dk_ref, dk)):
         if keys < heads:   # one key head: the sum over the heads it serves
@@ -351,8 +370,9 @@ def _specs(b: int, T: int, heads: int, keys: int, dk: int, dv: int,
     ``[b, T, Hk * dk]`` (``keys``: the step's key heads, one per value head
     or one for them all; the heads' columns are their block: no relayout),
     ``g``/``beta`` rows of ``[b, H / heads, nc, heads * CHUNK]`` (the step's
-    whole sequence resident: one row a chunk), and the entering states
-    ``[b, H, nc, dk, dv]``."""
+    whole sequence resident: one row a chunk), the entering states
+    ``[b, H, nc, dk, dv]`` and each step's ``T`` tile of ``[b, H / heads,
+    nc, heads * CHUNK, heads * CHUNK]``."""
     nc = T // CHUNK
 
     def chunk(c):
@@ -367,7 +387,9 @@ def _specs(b: int, T: int, heads: int, keys: int, dk: int, dv: int,
         row=pl.BlockSpec((1, 1, nc, heads * CHUNK),
                          lambda r, h, c: (r, h, 0, 0)),
         state=pl.BlockSpec((1, heads, 1, dk, dv),
-                           lambda r, h, c: (r, h, chunk(c), 0, 0)))
+                           lambda r, h, c: (r, h, chunk(c), 0, 0)),
+        t=pl.BlockSpec((1, 1, 1, heads * CHUNK, heads * CHUNK),
+                       lambda r, h, c: (r, h, chunk(c), 0, 0)))
 
 
 def _heads(H: int) -> int:
@@ -412,28 +434,40 @@ def _operands(q, k, v, g, beta):
               for x in (g, beta)))
 
 
+def _t_shape(b: int, T: int, H: int) -> jax.ShapeDtypeStruct:
+    """Every grid step's ``T``: ``[b, H / heads, nc, heads * CHUNK, heads *
+    CHUNK]`` float32, block diagonal (each head's inverse on the
+    diagonal)."""
+    heads = _heads(H)
+    return jax.ShapeDtypeStruct(
+        (b, H // heads, T // CHUNK, heads * CHUNK, heads * CHUNK),
+        jnp.float32)
+
+
 def _forward(q, k, v, g, beta, keep_states: bool):
     """``o`` [b, T, H, dv] float32 and, where ``keep_states``, the state
-    entering every chunk, [b, H, nc, dk, dv] float32."""
+    entering every chunk, [b, H, nc, dk, dv] float32, and every grid step's
+    ``T`` (:func:`_t_shape`)."""
     b, T, _, dk = q.shape
     H, dv = v.shape[2:]
     s = _specs(b, T, _heads(H), _keys(q, v), dk, dv, reverse=False)
     out_shape = [jax.ShapeDtypeStruct((b, T, H * dv), jnp.float32)]
     out_specs = [s["v"]]
     if keep_states:
-        out_shape.append(jax.ShapeDtypeStruct((b, H, T // CHUNK, dk, dv),
-                                              jnp.float32))
-        out_specs.append(s["state"])
+        out_shape += [jax.ShapeDtypeStruct((b, H, T // CHUNK, dk, dv),
+                                           jnp.float32), _t_shape(b, T, H)]
+        out_specs += [s["state"], s["t"]]
     outs = _call(_gdn_fwd_kernel, q, v,
                  [s["k"], s["k"], s["v"], s["row"], s["row"]], out_specs,
                  out_shape)(*_operands(q, k, v, g, beta))
     o = outs[0].reshape(b, T, H, dv)
-    return (o, outs[1]) if keep_states else o
+    return (o, *outs[1:]) if keep_states else o
 
 
-def _backward(q, k, v, g, beta, states, do):
-    """The gradients of :func:`_forward`'s ``o`` by ``do``: ``q``, ``k``,
-    ``v`` are dead after it, so their buffers take ``dq``, ``dk``, ``dv``."""
+def _backward(q, k, v, g, beta, states, t, do):
+    """The gradients of :func:`_forward`'s ``o`` by ``do``, from its
+    entering states and ``T``: ``q``, ``k``, ``v`` are dead after it, so
+    their buffers take ``dq``, ``dk``, ``dv``."""
     b, T, _, dk = q.shape
     H, dv = v.shape[2:]
     heads, nc = _heads(H), T // CHUNK
@@ -442,11 +476,13 @@ def _backward(q, k, v, g, beta, states, do):
                                 jnp.float32)
     dq, dk_, dv_, dg, dbeta = _call(
         _gdn_bwd_kernel, q, v,
-        [s["k"], s["k"], s["v"], s["row"], s["row"], s["state"], s["v"]],
+        [s["k"], s["k"], s["v"], s["row"], s["row"], s["state"], s["v"],
+         s["t"]],
         [s["k"], s["k"], s["v"], s["row"], s["row"]],
         [jax.ShapeDtypeStruct((b, T, x.shape[2] * x.shape[3]), x.dtype)
          for x in (q, k, v)] + [rows, rows], aliases={0: 0, 1: 1, 2: 2})(
-            *_operands(q, k, v, g, beta), states, do.reshape(b, T, H * dv))
+            *_operands(q, k, v, g, beta), states, do.reshape(b, T, H * dv),
+            t)
 
     def back(x, like):  # [b, H / heads, nc, heads * CHUNK] -> [b, T, H]
         return x.reshape(b, H // heads, nc, heads, CHUNK).transpose(
@@ -462,8 +498,8 @@ def _rule(q, k, v, g, beta):
 
 
 def _rule_fwd(q, k, v, g, beta):
-    o, states = _forward(q, k, v, g, beta, keep_states=True)
-    return o, (q, k, v, g, beta, states)
+    o, states, t = _forward(q, k, v, g, beta, keep_states=True)
+    return o, (q, k, v, g, beta, states, t)
 
 
 def _rule_bwd(res, do):
@@ -510,12 +546,15 @@ def chunked_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array,
     keys = _keys(q, v)
     blocks = 4 * (2 * CHUNK * (2 * keys * dk + 2 * heads * dv)
                   + 2 * 2 * T * heads + heads * dk * dv)
+    t = _t_shape(b, T, H)
     logger.info(
         "chunked_delta_rule: Pallas kernels, %d x %d x %d heads x %d | %d "
         "(%d key heads), grid %d x %d x %d: %d heads a step in %d rows, "
         "%d key head(s) read; VMEM asked: the compiler's default (forward "
-        "blocks and state %.2f MiB)", b, T, H, dk, dv, q.shape[2], b,
-        H // heads, T // CHUNK, heads, heads * CHUNK, keys, blocks / 2 ** 20)
+        "blocks and state %.2f MiB); the backward reads the recompute's "
+        "inverses, %.1f MB a call", b, T, H, dk, dv, q.shape[2], b,
+        H // heads, T // CHUNK, heads, heads * CHUNK, keys, blocks / 2 ** 20,
+        math.prod(t.shape) * t.dtype.itemsize / 1e6)
     return _rule(q, k, v, g, beta)
 
 

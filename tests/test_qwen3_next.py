@@ -3,7 +3,8 @@ gated attention (heads with an output gate, q/k norms, RoPE on a quarter of
 their columns), each over softmax-routed experts with a gated shared expert,
 as one expert-parallel rank holds it — at a small size on the CPU: the
 chunked delta rule against the token recurrence (outputs and gradients, at
-chunk boundaries, in groups of heads), the program against the plain
+chunk boundaries, in groups of heads), the inverses its recompute writes for
+the backward kernel, which builds none, the program against the plain
 reference the benchmark keeps (``benchmark/reference/qwen3_next.py``, the
 same file the chip run is held to) on the loss and every gradient leaf and
 each mixer alone, the partial rotation, the four shares of an expert layer
@@ -131,6 +132,19 @@ RULE_CASES = {
 }
 
 
+def rule_inputs(case):
+    """``q, k, v, g, beta`` and a cotangent for ``o``: two rows of
+    ``RULE_CASES[case]``, four value heads of 8, key heads of 16."""
+    seq, decay, key_heads, shift = RULE_CASES[case]
+    ks = jax.random.split(jax.random.key(seq), 6)
+    q = gdn.l2norm(jax.random.normal(ks[0], (2, seq, key_heads, 16))) / 4.0
+    k = gdn.l2norm(jax.random.normal(ks[1], (2, seq, key_heads, 16)))
+    v = jax.random.normal(ks[2], (2, seq, 4, 8))
+    g = decay(jax.random.normal(ks[3], (2, seq, 4)))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (2, seq, 4)) + shift)
+    return q, k, v, g, beta, jax.random.normal(ks[5], (2, seq, 4, 8))
+
+
 @pytest.mark.parametrize("case", sorted(RULE_CASES))
 def test_chunked_rule_is_the_token_recurrence(ref, case):
     """Outputs and the gradients of every input: the kernel pair across
@@ -140,14 +154,8 @@ def test_chunked_rule_is_the_token_recurrence(ref, case):
     in the mixer (lengths that are no multiple of the chunk:
     ``test_each_mixer_alone_equals_the_reference``, whose mixer pads 80
     positions to 128)."""
-    seq, decay, key_heads, shift = RULE_CASES[case]
-    ks = jax.random.split(jax.random.key(seq), 6)
-    q = gdn.l2norm(jax.random.normal(ks[0], (2, seq, key_heads, 16))) / 4.0
-    k = gdn.l2norm(jax.random.normal(ks[1], (2, seq, key_heads, 16)))
-    v = jax.random.normal(ks[2], (2, seq, 4, 8))
-    g = decay(jax.random.normal(ks[3], (2, seq, 4)))
-    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (2, seq, 4)) + shift)
-    cot = jax.random.normal(ks[5], (2, seq, 4, 8))
+    key_heads = RULE_CASES[case][2]
+    q, k, v, g, beta, cot = rule_inputs(case)
 
     def chunked(*x):
         return jnp.vdot(gdn.chunked_delta_rule(*x), cot)
@@ -164,6 +172,42 @@ def test_chunked_rule_is_the_token_recurrence(ref, case):
     assert abs(float(got - want)) < 1e-5 * abs(float(want))
     for a, b in zip(g_got, g_want):
         assert float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b)) < 1e-5
+
+
+@pytest.mark.parametrize("case", sorted(RULE_CASES))
+def test_recompute_writes_each_chunks_inverse(case):
+    """The forward kernel called for the backward (``keep_states=True``)
+    writes each grid step's ``T``, block diagonal: every head's block
+    inverts ``I + A``, ``A`` the chunk's strictly lower ``beta_i k_i.k_j
+    e^{gamma_i - gamma_j}`` built here from the inputs in float64, to 1e-6,
+    and nothing lies between heads. Its ``o`` is the forward phase's call's,
+    which (``keep_states=False``) returns ``o`` alone."""
+    q, k, v, g, beta, _ = rule_inputs(case)
+    rows, seq, key_heads, _ = q.shape
+    heads, n, c = gdn._heads(4), gdn.CHUNK, seq // gdn.CHUNK
+    o = gdn._forward(q, k, v, g, beta, keep_states=False)
+    assert isinstance(o, jax.Array) and o.shape == (rows, seq, 4, 8)
+    o_kept, _, t = gdn._forward(q, k, v, g, beta, keep_states=True)
+    np.testing.assert_array_equal(o_kept, o)
+    assert t.shape == (rows, 4 // heads, c, heads * n, heads * n)
+    t = np.asarray(t, np.float64).reshape(rows, 4 // heads, c, heads, n,
+                                          heads, n)
+    diag = np.einsum("rscanbm,ab->rscanbm", t, np.eye(heads))
+    assert not np.any(t - diag), "T is not zero between heads"
+    blocks = np.einsum("rscanam->rsacnm", t).reshape(rows, 4, c, n, n)
+    # each value head's keys, chunks, cumulative log-decays and its A
+    kc = np.repeat(np.asarray(k, np.float64), 4 // key_heads, axis=2)
+    kc = kc.reshape(rows, c, n, 4, -1).transpose(0, 3, 1, 2, 4)
+    gamma = np.cumsum(np.asarray(g, np.float64).reshape(rows, c, n, 4),
+                      axis=2).transpose(0, 3, 1, 2)
+    b = np.asarray(beta, np.float64).reshape(rows, c, n, 4).transpose(
+        0, 3, 1, 2)
+    lower = np.tril(np.ones((n, n), bool), -1)
+    lag = np.where(lower, gamma[..., :, None] - gamma[..., None, :], -np.inf)
+    a = (b[..., :, None] * np.einsum("rhcid,rhcjd->rhcij", kc, kc)
+         * np.exp(lag))
+    err = np.abs((np.eye(n) + a) @ blocks - np.eye(n)).max()
+    assert err < 1e-6, err
 
 
 def test_program_equals_reference_loss_and_every_gradient(compiled):
@@ -343,6 +387,51 @@ def test_both_kernels_run_inside_the_scan_region_in_every_phase(
     assert {(p, r) for p, r in loops if r == "model/gdn_scan"} == {
         ("forward", "model/gdn_scan"), ("recompute", "model/gdn_scan"),
         ("backward", "model/gdn_scan")}
+
+
+def _dot_generals(jaxpr) -> int:
+    """``dot_general`` equations in ``jaxpr`` and the jaxprs inside it."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += eqn.primitive.name == "dot_general"
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (list, tuple)) else [param]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    n += _dot_generals(sub)
+    return n
+
+
+def test_backward_kernel_builds_no_inverse():
+    """The backward kernel reads each chunk's ``T`` from the recompute and
+    runs its second forward of the chunk only up to what the gradients
+    read. Its products, two heads a grid step, were 46 in the parent: the
+    chunk's forward 21 (``k k^T`` 1, the inverse by halves 10 — two a level
+    for the five levels past the first —, ``w``, ``u`` 2, ``q k^T`` 1, the
+    updates 2 — one a head —, ``o`` 3, the leaving states 2) and its
+    gradients 25. Now 46 - 10 (the inverse) - 3 (``o``) - 2 (the leaving
+    states) = 31; the forward kernel keeps its 21."""
+    q, k, v, g, beta, cot = rule_inputs("repeated-keys")
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda *x: jnp.vdot(gdn.chunked_delta_rule(*x), cot),
+        argnums=range(5)))(q, k, v, g, beta)
+    found = {}
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                body = eqn.params["jaxpr"]
+                found[body.debug_info.func_src_info.split()[0]] = (
+                    _dot_generals(body))
+            for param in eqn.params.values():
+                for sub in (param if isinstance(param, (list, tuple))
+                            else [param]):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        walk(sub)
+
+    walk(jaxpr.jaxpr)
+    assert found == {"_gdn_fwd_kernel": 21, "_gdn_bwd_kernel": 46 - 10 - 5}
 
 
 def test_new_knobs_are_checked_and_named(monkeypatch):
